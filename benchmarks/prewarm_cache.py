@@ -1,37 +1,35 @@
-"""Pre-warm the JAX persistent compilation cache shared by the test
-suite (tests/conftest.py points both the in-process tests and the slow
-tier's subprocess fixture at ``.pytest_cache/jax_persistent_cache``).
-
-CI restores that directory via ``actions/cache`` (keyed on JAX version +
-kernel-source hash) and runs this script on a cache miss, so the first
-test run of a fresh key already loads compiled executables from disk
-instead of paying cold XLA compiles:
+"""Pre-warm the JAX persistent compilation cache.
 
     PYTHONPATH=src python -m benchmarks.prewarm_cache [cache_dir]
+
+The cache is the one ``repro.launch.compile_cache.enable_compile_cache``
+selects: ``cache_dir`` when given (it becomes
+``JAX_COMPILATION_CACHE_DIR``), else ``JAX_COMPILATION_CACHE_DIR``, else
+``<checkout>/.jax_cache``.  CI passes the test suite's directory
+(``.pytest_cache/jax_persistent_cache``, which ``tests/conftest.py``
+uses unless ``JAX_COMPILATION_CACHE_DIR`` is set), restores it via
+``actions/cache`` (keyed on JAX version + kernel-source hash) and runs
+this script on a cache miss, so the first test run of a fresh key
+already loads compiled executables from disk.
 
 Compiles the batch-evaluator kernels the suite leans on hardest: the
 default paper topology plus every registered arch, on the common
 (ndims=3, bucket=16) signature, both uniform and structured density
 modes, broadcast and stacked variants, at the canonical padded batch
-shapes.  Best-effort everywhere: backends without persistent-cache
-support simply compile and discard.
+shapes.
 """
 from __future__ import annotations
 
 import os
 import sys
+from typing import Optional
 
-_DEFAULT_DIR = os.path.join(".pytest_cache", "jax_persistent_cache")
 
-
-def main(cache_dir: str = _DEFAULT_DIR) -> None:
-    os.makedirs(cache_dir, exist_ok=True)
-    # must land in the environment before jax initializes
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir)
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                          "0")
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES",
-                          "0")
+def main(cache_dir: Optional[str] = None) -> None:
+    if cache_dir:
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+    from repro.launch.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
 
     import numpy as np
 

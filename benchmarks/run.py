@@ -218,6 +218,8 @@ def bench_sweep_json(budget: int, out_path: str = SWEEP_JSON) -> dict:
 
 
 def main(argv=None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--budget", type=int, default=None)
     ap.add_argument("--quick", action="store_true",

@@ -26,7 +26,8 @@ GLB skips the whole corresponding compute iterations).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple, Union
+import math
+from typing import Dict, List, Tuple, Union
 
 from .accel import Platform
 from .arch import ArchSpec, as_arch
@@ -272,3 +273,61 @@ def evaluate(design: Design, platform: Union[str, Platform, ArchSpec]
         compute_cycles=compute_cycles, dram_cycles=dram_cycles,
         occupancy_bytes=occ,
     )
+
+
+# ------------------------------------------------- kernel agreement rule
+
+#: the float32 kernel's log10(EDP) may differ from this float64 oracle's
+#: by this fraction of max(|log10 EDP|, 1)
+LOG10_EDP_RTOL = 2e-3
+#: a validity verdict may differ only where some capacity-checked store's
+#: occupancy sits within this fraction of its capacity
+CAPACITY_MARGIN = 5e-3
+
+
+@dataclasses.dataclass
+class Agreement:
+    """Outcome of :func:`check_against_oracle` over a genome batch."""
+
+    checked: int = 0
+    both_valid: int = 0
+    #: largest |log10 EDP oracle - log10 EDP kernel| over both-valid rows
+    worst_log10_err: float = 0.0
+    #: one line per row that breaks the rule
+    disagreements: List[str] = dataclasses.field(default_factory=list)
+
+
+def check_against_oracle(spec, arch: Union[str, Platform, ArchSpec],
+                         genomes, kernel_out: Dict) -> Agreement:
+    """Hold a kernel's output for ``genomes`` (the ``valid`` and
+    ``log10_edp`` arrays of ``JaxCostModel``/``eval_stacked``) to this
+    oracle: validity must match, except at razor-thin float32-vs-float64
+    capacity margins (in either direction — the oracle reports
+    occupancies on a capacity rejection too), and on rows both call
+    valid log10(EDP) must agree within :data:`LOG10_EDP_RTOL`."""
+    arch = as_arch(arch)
+    res = Agreement()
+    for i, g in enumerate(genomes):
+        rep = evaluate(spec.decode(g), arch)
+        kv = bool(kernel_out["valid"][i])
+        res.checked += 1
+        if rep.valid != kv:
+            margins = [1.0] + [abs(rep.occupancy_bytes[name] - cap) / cap
+                               for _, name, cap in arch.capacity_stores
+                               if name in rep.occupancy_bytes]
+            if min(margins) >= CAPACITY_MARGIN:
+                res.disagreements.append(
+                    f"genome {i}: oracle valid={rep.valid} ({rep.reason}) "
+                    f"kernel valid={kv}")
+            continue
+        if rep.valid:
+            res.both_valid += 1
+            lg = math.log10(rep.edp)
+            err = abs(lg - float(kernel_out["log10_edp"][i]))
+            if not err <= res.worst_log10_err:
+                res.worst_log10_err = err
+            if not err <= LOG10_EDP_RTOL * max(abs(lg), 1.0):
+                res.disagreements.append(
+                    f"genome {i}: log10 EDP oracle={lg:.6f} kernel="
+                    f"{float(kernel_out['log10_edp'][i]):.6f}")
+    return res

@@ -195,11 +195,13 @@ def reset_dispatch_count() -> None:
 
 _AOT_FNS: Dict[Tuple, object] = {}
 _AOT_PENDING: Dict[Tuple, threading.Event] = {}
-_CA_ACTIVE = False              # a compile-ahead pass ran this epoch
-_CA_PREFIXES: set = set()       # (sig..., tag) families compile-ahead built
+_CA_PREFIXES: set = set()       # (sig..., tag) families the pass claims
 _CA_HITS = 0                    # dispatches served by an AOT executable
 _CA_MISSES = 0                  # fresh XLA traces while compile-ahead on
+_CA_ERRORS = 0                  # failed background compiles / AOT calls
+_CA_FIRST_ERROR: Optional[str] = None
 _CA_CANCEL = None               # cancel event of the latest worker
+_CA_THREAD: Optional[threading.Thread] = None   # the latest worker
 
 
 def compile_ahead_counts() -> Tuple[int, int]:
@@ -211,9 +213,27 @@ def compile_ahead_counts() -> Tuple[int, int]:
 
 
 def reset_compile_ahead_counts() -> None:
-    global _CA_HITS, _CA_MISSES
+    global _CA_HITS, _CA_MISSES, _CA_ERRORS, _CA_FIRST_ERROR
     with _LOCK:
-        _CA_HITS = _CA_MISSES = 0
+        _CA_HITS = _CA_MISSES = _CA_ERRORS = 0
+        _CA_FIRST_ERROR = None
+
+
+def compile_ahead_errors() -> Tuple[int, Optional[str]]:
+    """(count, first error) of compile-ahead failures since the last
+    reset: a background compile that raised (its dispatch then traces
+    through ``jit`` and meets the same error in the open) or an AOT
+    executable that raised when called (re-raised to the caller)."""
+    with _LOCK:
+        return _CA_ERRORS, _CA_FIRST_ERROR
+
+
+def _record_ca_error(key: Tuple, exc: BaseException) -> None:
+    global _CA_ERRORS, _CA_FIRST_ERROR
+    with _LOCK:
+        _CA_ERRORS += 1
+        if _CA_FIRST_ERROR is None:
+            _CA_FIRST_ERROR = f"{key}: {type(exc).__name__}: {exc}"
 
 
 def _aot_lookup(key: Tuple):
@@ -230,26 +250,27 @@ def _aot_lookup(key: Tuple):
 
 
 def _aot_call(key: Tuple, jit_fn, args: Tuple):
-    """Dispatch through the AOT registry when it covers ``key``; fall
-    back to the ordinary jit call.  A compile-ahead MISS is a dispatch
-    that had to trace a fresh XLA program even though compile-ahead
-    claimed its (signature, kernel-tag) family — shapes in families the
-    worker never touched (e.g. prologue probe batches when only
-    scan/stacked shapes were predicted) don't count."""
+    """Dispatch through the AOT registry when it covers ``key``; else
+    the ordinary jit call.  A compile-ahead MISS is a dispatch that had
+    to trace a fresh XLA program even though compile-ahead claimed its
+    (signature, kernel-tag) family — shapes in families the worker never
+    touched (e.g. prologue probe batches when only scan/stacked shapes
+    were predicted) don't count.  An AOT executable that raises is
+    counted as a compile-ahead error and re-raised: its donated inputs
+    may already be gone, so a second dispatch through jit is unsafe."""
     global _CA_HITS, _CA_MISSES
     cfn = _aot_lookup(key)
     if cfn is not None:
         try:
             out = cfn(*args)
-        except Exception:       # shape/dtype drift vs the predicted job
-            with _LOCK:
-                _CA_MISSES += 1
-            return jit_fn(*args)
+        except Exception as e:
+            _record_ca_error(key, e)
+            raise
         with _LOCK:
             _CA_HITS += 1
         return out
     with _LOCK:
-        armed = _CA_ACTIVE and key[:5] in _CA_PREFIXES
+        armed = key[:5] in _CA_PREFIXES
     if not armed:
         return jit_fn(*args)
     try:
@@ -270,9 +291,11 @@ def _aot_call(key: Tuple, jit_fn, args: Tuple):
 def compile_ahead(jobs: Sequence[Tuple[Tuple, object, Tuple]],
                   wait: bool = False) -> Optional[threading.Thread]:
     """Compile the given (key, jit_fn, arg_structs) jobs on a background
-    thread.  Returns the thread (already started); ``wait=True`` joins it
-    before returning (tests).  Marks compile-ahead active for the epoch,
-    which arms the miss counter on every later dispatch.
+    thread.  Returns the thread (already started), or None when nothing
+    was queued; ``wait=True`` joins it before returning (tests).  The
+    jobs' (signature, kernel-tag) families become the claimed ones,
+    which arms the miss counter on later dispatches in those families
+    (``compile_ahead([])`` claims none).
 
     Every queued key is claimed in ``_AOT_PENDING`` *before* the worker
     starts: a dispatch that races the worker finds its key pending and
@@ -285,11 +308,23 @@ def compile_ahead(jobs: Sequence[Tuple[Tuple, object, Tuple]],
     -compile at interpreter exit aborts the process from C++
     (``terminate called without an active exception``), so instead the
     fleet cancels leftover queue work when its run ends and interpreter
-    shutdown joins at most the one in-flight compile."""
-    global _CA_ACTIVE, _CA_CANCEL
+    shutdown joins at most the one in-flight compile.
+
+    A new pass first retires the previous worker (cancel, then join: at
+    most its in-flight compile).  Otherwise a key the previous, cancelled
+    worker still held pending would be skipped here and then never
+    compiled by it — its dispatch would trace inline as a miss."""
+    global _CA_CANCEL, _CA_THREAD
+    compile_ahead_quiesce()
+    with _LOCK:
+        prev = _CA_THREAD
+    if prev is not None and prev is not threading.current_thread():
+        prev.join()
     cancel = threading.Event()
     with _LOCK:
-        _CA_ACTIVE = True
+        # the families THIS pass claims: a fleet's misses are counted
+        # against its own predictions, never an earlier fleet's
+        _CA_PREFIXES.clear()
         _CA_PREFIXES.update(key[:5] for key, _, _ in jobs)
         queued = []
         for key, jit_fn, arg_structs in jobs:
@@ -309,8 +344,10 @@ def compile_ahead(jobs: Sequence[Tuple[Tuple, object, Tuple]],
                     compiled = jit_fn.lower(*arg_structs).compile()
                     with _LOCK:
                         _AOT_FNS[key] = compiled
-            except Exception:   # dispatch path falls back to tracing
-                pass
+            except Exception as e:
+                # counted, never silent: the dispatch of this key finds no
+                # executable and traces through jit in the caller's thread
+                _record_ca_error(key, e)
             finally:
                 ev.set()
                 with _LOCK:
@@ -320,6 +357,8 @@ def compile_ahead(jobs: Sequence[Tuple[Tuple, object, Tuple]],
     # thread, and the sweep server runs fleets on a daemon worker — the
     # non-daemon guarantee above must not silently vanish there
     th = threading.Thread(target=work, name="compile-ahead", daemon=False)
+    with _LOCK:
+        _CA_THREAD = th
     th.start()
     if wait:
         th.join()
@@ -350,7 +389,6 @@ except Exception:               # pragma: no cover - future-proofing
 
 def clear_compile_cache() -> None:
     """Drop all shared jitted evaluators (benchmarking hook)."""
-    global _CA_ACTIVE
     _jitted_eval.cache_clear()
     _build_eval_one.cache_clear()
     _scan_task_fn.cache_clear()
@@ -364,7 +402,6 @@ def clear_compile_cache() -> None:
         _AOT_FNS.clear()
         _AOT_PENDING.clear()
         _CA_PREFIXES.clear()
-        _CA_ACTIVE = False
     reset_stack_prep_counts()
     reset_dispatch_count()
     reset_compile_ahead_counts()
@@ -550,6 +587,31 @@ def _occ_structured(pr, e):
 # ---------------------------------------------------------------- kernel
 
 
+def _clog2(x):
+    """Metadata bits per index: ceil(log2 x), at least 1 — the kernel
+    twin of the oracle's ``sparse._clog2``.  Computed exactly from the
+    float32 exponent (x = m * 2^e with m in [0.5, 1), so ceil(log2 x) is
+    e - 1 when m == 0.5 and e otherwise): ``ceil(log2(x))`` is off by a
+    whole bit on the TPU, whose float32 log2 overshoots exact powers of
+    two (2^13 first), and on every backend just above large ones."""
+    m, e = jnp.frexp(jnp.maximum(x, 2.0))
+    bits = jnp.where(m == 0.5, e - 1, e).astype(jnp.float32)
+    return jnp.maximum(1.0, bits)
+
+
+def _varying_like(init, ref):
+    """Cast constant ``lax.scan`` carry inits to the varying manual mesh
+    axes of ``ref``.  Inside ``shard_map`` (which checks varying manual
+    axes) a carry that starts as a literal but is updated from sharded
+    inputs changes type across the body; outside it ``ref`` varies over
+    no axis and the inits pass through unchanged."""
+    axes = tuple(jax.typeof(ref).vma)
+    if not axes:
+        return init
+    return jax.tree.map(lambda x: jax.lax.pcast(x, axes, to="varying"),
+                        init)
+
+
 @lru_cache(maxsize=64)
 def _build_eval_one(d: int, n_primes_pad: int, topo: Topology,
                     dens_key: str = "u"):
@@ -633,9 +695,6 @@ def _build_eval_one(d: int, n_primes_pad: int, topo: Topology,
                            for s in range(NE)])            # (NE, 3)
 
         # ---- fiber-tree format accounting per tensor ----
-        def clog2(x):
-            return jnp.maximum(1.0, jnp.ceil(jnp.log2(jnp.maximum(x, 2.0))))
-
         def tensor_format(t):
             genes = fmt_genes[t]
             is_sub = rel_flat[t] & (bounds > 1.0)
@@ -664,9 +723,9 @@ def _build_eval_one(d: int, n_primes_pad: int, topo: Topology,
                 mb = jnp.select(
                     [f == FMT_B, f == FMT_RLE, f == FMT_CP, f == FMT_UOP],
                     [n_fibers * L,
-                     n_fibers * kp * clog2(L),
-                     n_fibers * kp * clog2(L),
-                     n_fibers * (L + 1.0) * clog2(jnp.maximum(full, 2.0))],
+                     n_fibers * kp * _clog2(L),
+                     n_fibers * kp * _clog2(L),
+                     n_fibers * (L + 1.0) * _clog2(jnp.maximum(full, 2.0))],
                     0.0)
                 meta_bits = meta_bits + jnp.where(sub > 0.5, mb, 0.0)
                 nf_next = jnp.where(f == FMT_U, n_fibers * L, n_fibers * kp)
@@ -674,7 +733,8 @@ def _build_eval_one(d: int, n_primes_pad: int, topo: Topology,
                 return (n_fibers, meta_bits), None
 
             (_, meta_bits), _ = jax.lax.scan(
-                body, (jnp.float32(1.0), jnp.float32(0.0)),
+                body, _varying_like((jnp.float32(1.0), jnp.float32(0.0)),
+                                    full),
                 (sub_bounds, fmt, kept, is_sub.astype(jnp.float32)))
             compressed = jnp.any(jnp.where(is_sub, fmt != FMT_U, False))
             data_b = jnp.where(compressed, full * dens * wb, full * wb)
@@ -1109,12 +1169,11 @@ def _sharded_scan_fn(d: int, n_pad: int, topo: Topology, dens_key: str,
     fn = _SHARD_FNS.get(key)
     if fn is None:
         from jax.sharding import PartitionSpec as P
-        from ..distributed.compat import shard_map
         vfn = _scan_task_fn(d, n_pad, topo, dens_key, n_parents, n_elite,
                             genes_per)
         ax = mesh.axis_names[0]
-        fn = jax.jit(shard_map(vfn, mesh=mesh, in_specs=(P(ax),) * 7,
-                               out_specs=P(ax)))
+        fn = jax.jit(jax.shard_map(vfn, mesh=mesh, in_specs=(P(ax),) * 7,
+                                   out_specs=P(ax)))
         with _LOCK:
             _SHARD_FNS[key] = fn
             _JIT_FNS[(d, n_pad, topo.fingerprint, dens_key,
@@ -1131,12 +1190,12 @@ def _sharded_stacked_fn(d: int, n_pad: int, topo: Topology,
     fn = _SHARD_FNS.get(key)
     if fn is None:
         from jax.sharding import PartitionSpec as P
-        from ..distributed.compat import shard_map
         eval_one = _build_eval_one(d, n_pad, topo, dens_key)
         vfn = jax.vmap(eval_one, in_axes=(0,) * 13)
         ax = mesh.axis_names[0]
-        fn = jax.jit(shard_map(vfn, mesh=mesh, in_specs=(P(ax),) * 13,
-                               out_specs=P(ax)))
+        fn = jax.jit(jax.shard_map(vfn, mesh=mesh,
+                                   in_specs=(P(ax),) * 13,
+                                   out_specs=P(ax)))
         with _LOCK:
             _SHARD_FNS[key] = fn
             _JIT_FNS[(d, n_pad, topo.fingerprint, dens_key,
@@ -1164,9 +1223,8 @@ def run_segments(models: Sequence["JaxCostModel"],
     the shared scan layout and, afterwards, slicing the per-generation
     outputs back per task (``_canonical``-recomputed like every other
     dispatch path).  With ``mesh`` given and the task count divisible by
-    the device count, tasks shard across devices via the
-    ``distributed.compat.shard_map`` shim; otherwise the single-device
-    program runs unchanged.
+    the device count, tasks shard across devices via ``jax.shard_map``;
+    otherwise the single-device program runs unchanged.
 
     Pipelining hooks: a segment carrying ``carry`` (the device-resident
     padded (pop, edp) of its previous SegmentResult) skips the host-side
@@ -1656,9 +1714,9 @@ def eval_stacked(models: Sequence["JaxCostModel"],
     keeps hitting an already-compiled mega-batch shape instead of tracing
     a new one (padding rows are zero genomes, sliced off).
 
-    ``mesh`` shards the padded rows across the mesh's devices via the
-    ``distributed.compat.shard_map`` shim (rows are further padded to a
-    device-count multiple — a no-op for the usual power-of-two shapes);
+    ``mesh`` shards the padded rows across the mesh's devices via
+    ``jax.shard_map`` (rows are further padded to a device-count
+    multiple — a no-op for the usual power-of-two shapes);
     with ``mesh=None`` (or one device) the single-device path runs
     unchanged, and per-row results are identical either way because both
     wrap the same per-row kernel.
@@ -1715,8 +1773,9 @@ def eval_stacked(models: Sequence["JaxCostModel"],
 # Builders for the (key, jit_fn, arg_structs) triples ``compile_ahead``
 # consumes.  Each mirrors EXACTLY the argument pytree its dispatch path
 # passes — the AOT registry key doubles as the contract: if the builder
-# and the dispatch ever disagree on shapes/dtypes the executable simply
-# isn't found (or fails its call and falls back), never a wrong answer.
+# and the dispatch ever disagree on shapes/dtypes the executable is
+# either not found or fails its call loudly (``compile_ahead_errors``),
+# never a wrong answer.
 
 
 def _row_structs(model: "JaxCostModel", padded: int) -> Tuple:

@@ -782,15 +782,16 @@ class MultiSearch:
                 method=task.method))
 
         self._ca0 = jax_cost.compile_ahead_counts()
+        self._ca_errors0 = jax_cost.compile_ahead_errors()[0]
         self._blocked0 = jax_cost.host_blocked_s()
-        if self.compile_ahead:
-            # AOT-compile the predicted round-1 + watermark + scan shapes
-            # on a background thread NOW — the compile spike overlaps the
-            # host-side HSHI/LHS/calibration prologue instead of
-            # serializing with the first dispatch of each shape
-            jobs = self._compile_ahead_jobs(infos)
-            if jobs:
-                jax_cost.compile_ahead(jobs)
+        # AOT-compile the predicted round-1 + watermark + scan shapes on a
+        # background thread NOW — the compile spike overlaps the host-side
+        # HSHI/LHS/calibration prologue instead of serializing with the
+        # first dispatch of each shape.  Called with no jobs too: every
+        # fleet retires the previous fleet's worker and claims its own
+        # families (none when compile-ahead is off)
+        jax_cost.compile_ahead(
+            self._compile_ahead_jobs(infos) if self.compile_ahead else [])
 
         # group same-signature tasks so they share warm compilations (and,
         # when stacking, one mega-batch); stable within a signature
@@ -1050,6 +1051,7 @@ class MultiSearch:
                   else 1.0)
         ca_hits, ca_misses = jax_cost.compile_ahead_counts()
         ca_hits0, ca_misses0 = self._ca0
+        ca_errors, ca_first_error = jax_cost.compile_ahead_errors()
         return dict(
             rounds=self._rounds,
             host_syncs=self._host_syncs,
@@ -1060,6 +1062,9 @@ class MultiSearch:
             compile_ahead=self.compile_ahead,
             compile_ahead_hits=ca_hits - ca_hits0,
             compile_ahead_misses=ca_misses - ca_misses0,
+            compile_ahead_errors=ca_errors - self._ca_errors0,
+            compile_ahead_first_error=(
+                ca_first_error if ca_errors > self._ca_errors0 else None),
             host_blocked_s=jax_cost.host_blocked_s() - self._blocked0,
             devices=jax_cost._mesh_ndev(self.mesh),
             dispatches=jax_cost.dispatch_count() - self._dispatch0,

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
+from jax import shard_map
 
 
 def pipeline_apply(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],

@@ -14,7 +14,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
+from jax import shard_map
 
 
 def test_pipeline():

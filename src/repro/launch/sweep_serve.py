@@ -42,7 +42,9 @@ import os
 import shutil
 import socket
 import socketserver
+import sys
 import threading
+import traceback
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
@@ -56,6 +58,7 @@ from repro.core.evolution import snapshot_tracker_hist
 from repro.core.search import (FleetConfig, MultiSearch, SearchTask,
                                SearchResult)
 from repro.core.sensitivity import SensitivityResult
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.fault_tolerance import Supervisor
 
 
@@ -259,7 +262,10 @@ class SweepServer:
         self._events_lock = threading.Lock()
         self._last_best: Dict[str, float] = {}
         self._stats = dict(queries=0, completed=0, rejected=0, epochs=0,
-                           restarts=0, warm_started=0)
+                           restarts=0, failed_epochs=0, warm_started=0)
+        # every exception an epoch caught (recovered by a restart or
+        # not), formatted; ``main`` exits non-zero when any occurred
+        self._errors: List[str] = []
         self._last_fleet_stats: Dict = {}
         self._last_groups: Dict[str, int] = {}
         self._epoch_groups: List[Dict[str, int]] = []
@@ -511,8 +517,13 @@ class SweepServer:
             # checkpoint (bit-identical resume) up to max_restarts times
             ms, report = sup.run_loop(make_state, step_fn, save_fn)
             self._stats["restarts"] += report["restarts"]
+            self._errors.extend(sup.errors)
         except Exception as e:          # noqa: BLE001 — surface to clients
+            traceback.print_exc(file=sys.stderr)
             self._stats["restarts"] += sup.restarts
+            self._stats["failed_epochs"] += 1
+            self._errors.extend(sup.errors)
+            self._errors.append(f"{type(e).__name__}: {e}")
             for name, p in by_name.items():
                 if p.events is not None:
                     p.events.append({"event": "failed", "id": name,
@@ -579,6 +590,7 @@ class SweepServer:
 
     def stats(self) -> Dict:
         out = dict(self._stats)
+        out["errors"] = list(self._errors)
         out["library"] = self.library.snapshot()
         out["compilations"] = jax_cost.compilation_count()
         with self._fleet_lock:
@@ -629,6 +641,10 @@ def submit(host: str, port: int, task: SearchTask,
 
 
 def main(argv=None) -> int:
+    """Run the server until shutdown.  Exits 1 when any epoch failed or
+    a worker crash was recovered by a restart: a caught device, compile
+    or dispatch failure is never reported as a clean run."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m repro.launch.serve sweep",
         description="Persistent sweep server: coalesces concurrent "
@@ -665,6 +681,12 @@ def main(argv=None) -> int:
         server.serve_forever()
     except KeyboardInterrupt:
         server.stop()
+    st = server.stats()
+    if st["failed_epochs"] or st["restarts"]:
+        print(f"sweep serve stopped after {st['failed_epochs']} failed "
+              f"epoch(s) and {st['restarts']} restart(s); first error: "
+              f"{st['errors'][0] if st['errors'] else '?'}", flush=True)
+        return 1
     print("sweep serve stopped", flush=True)
     return 0
 

@@ -1,47 +1,20 @@
-"""JAX version-compat shims for compiled-artifact introspection.
+"""Compiled-artifact introspection.
 
-``Compiled.cost_analysis()`` has changed return type across JAX releases:
-
-* some versions return a single ``dict`` of metric -> value,
-* JAX 0.4.x returns a ``list`` of per-program dicts (usually length 1),
-* backends without cost-analysis support return ``None`` (or raise).
-
-:func:`xla_cost_analysis` normalizes all three to one flat dict so
-callers can do ``xla_cost_analysis(compiled).get("flops", 0.0)``
-unconditionally.  See COMPAT.md for the repo-wide version policy.
+``Compiled.cost_analysis()`` returns a ``dict`` of metric -> value, or
+``None`` (or raises) on backends without cost-analysis support.
+:func:`xla_cost_analysis` turns all of these into one dict so callers
+can do ``xla_cost_analysis(compiled).get("flops", 0.0)`` unconditionally.
 """
 from __future__ import annotations
 
 from typing import Any, Dict
 
 
-def normalize_cost_analysis(ca: Any) -> Dict[str, float]:
-    """Normalize a raw ``cost_analysis()`` return value (dict /
-    list-of-dicts / None) to one dict.  Numeric values appearing in
-    several per-program dicts are summed (program costs are additive);
-    non-numeric values keep the first occurrence."""
-    if ca is None:
-        return {}
-    if isinstance(ca, dict):
-        return dict(ca)
-    out: Dict[str, float] = {}
-    for entry in ca:
-        if not isinstance(entry, dict):
-            continue
-        for k, v in entry.items():
-            if k in out and isinstance(v, (int, float)) \
-                    and isinstance(out[k], (int, float)):
-                out[k] += v
-            elif k not in out:
-                out[k] = v
-    return out
-
-
 def xla_cost_analysis(compiled: Any) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across JAX versions -> one flat dict.
-    Returns {} when the backend offers no cost analysis."""
+    """``compiled.cost_analysis()`` as a fresh dict; {} when the backend
+    offers no cost analysis."""
     try:
         ca = compiled.cost_analysis()
     except Exception:
         return {}
-    return normalize_cost_analysis(ca)
+    return dict(ca) if ca else {}

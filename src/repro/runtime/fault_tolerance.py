@@ -17,7 +17,9 @@ tests on a single host (failure injection via exceptions):
 from __future__ import annotations
 
 import dataclasses
+import sys
 import time
+import traceback
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from repro.checkpoint import checkpoint as ckpt_lib
@@ -88,6 +90,9 @@ class Supervisor:
         self.keep_last = keep_last
         self.monitor = StepMonitor()
         self.restarts = 0
+        # every exception a supervised loop caught, formatted — a
+        # recovered crash is still reported, never silent
+        self.errors: List[str] = []
 
     def run(self, state: Any, step_fn: Callable[[Any, int], Any],
             n_steps: int,
@@ -166,6 +171,8 @@ class Supervisor:
                 s += 1
             except Exception as e:  # noqa: BLE001 — supervised retry
                 self.restarts += 1
+                self.errors.append(f"{type(e).__name__}: {e}")
+                traceback.print_exc(file=sys.stderr)
                 if self.restarts > self.max_restarts:
                     raise RuntimeError(
                         f"giving up after {self.max_restarts} restarts"

@@ -16,7 +16,7 @@ from repro.configs.archs import (CLUSTER_CLOUD, DSTC_LIKE, EYERISS_LIKE,
                                  MAPLE_EDGE, QUANT_EDGE, SIGMA_LIKE,
                                  SYSTOLIC_MESH)
 from repro.core import accel
-from repro.core.cost_model import evaluate
+from repro.core.cost_model import check_against_oracle, evaluate
 from repro.core.encoding import GenomeSpec
 from repro.core.jax_cost import JaxCostModel
 from repro.core.workload import batched_spmm, spconv, spmm
@@ -69,36 +69,16 @@ def test_agreement(wl, plat):
 
 
 def _check_agreement(wl, arch, seed, n=64, require_valid=0):
-    """Numpy-oracle vs JAX-kernel agreement on one (workload, arch)."""
+    """Numpy-oracle vs JAX-kernel agreement on one (workload, arch),
+    under the shared rule of ``cost_model.check_against_oracle``."""
     spec = GenomeSpec(wl, arch=arch)
     jm = JaxCostModel(spec, arch)
     rng = np.random.default_rng(seed)
     G = spec.random_genomes(rng, n)
-    out = jm(G)
-    n_valid = 0
-    for i, g in enumerate(G):
-        rep = evaluate(spec.decode(g), arch)
-        jv = bool(out["valid"][i])
-        if rep.valid != jv:
-            # tolerate razor-thin float32-vs-float64 capacity margins, in
-            # BOTH directions (the oracle reports occupancies on a
-            # capacity rejection too)
-            margins = [1.0]
-            for _, sname, cap in arch.capacity_stores:
-                if sname in rep.occupancy_bytes:
-                    margins.append(
-                        abs(rep.occupancy_bytes[sname] - cap) / cap)
-            assert min(margins) < 5e-3, (
-                f"genome {i}: oracle valid={rep.valid} ({rep.reason}) "
-                f"jax valid={jv}")
-            continue
-        if rep.valid:
-            n_valid += 1
-            lg = np.log10(rep.edp)
-            assert abs(lg - out["log10_edp"][i]) <= 2e-3 * max(abs(lg), 1), \
-                f"genome {i}: edp oracle={rep.edp:.4e} jax log mismatch"
-    assert n_valid >= require_valid
-    return n_valid
+    agr = check_against_oracle(spec, arch, G, jm(G))
+    assert not agr.disagreements, agr.disagreements[:5]
+    assert agr.both_valid >= require_valid
+    return agr.both_valid
 
 
 @st.composite

@@ -18,7 +18,7 @@ except ImportError:
     from _hypothesis_shim import given, settings, strategies as st
 
 from repro.core import jax_cost, search
-from repro.core.cost_model import evaluate
+from repro.core.cost_model import check_against_oracle, evaluate
 from repro.core.density import (Banded, BlockNM, DensityModel, Uniform,
                                 as_density, param_row, param_width)
 from repro.core.encoding import GenomeSpec
@@ -249,25 +249,8 @@ def test_structured_agreement_numpy_vs_jax(wl, seed):
     assert jm.signature[3].startswith("s:")
     rng = np.random.default_rng(seed)
     G = spec.random_genomes(rng, 64)
-    out = jm(G)
-    for i, g in enumerate(G):
-        rep = evaluate(spec.decode(g), "cloud")
-        jv = bool(out["valid"][i])
-        if rep.valid != jv:
-            # tolerate razor-thin float32-vs-float64 capacity margins
-            margins = [1.0]
-            for _, sname, cap in spec.arch.capacity_stores:
-                if sname in rep.occupancy_bytes:
-                    margins.append(
-                        abs(rep.occupancy_bytes[sname] - cap) / cap)
-            assert min(margins) < 5e-3, (
-                f"genome {i}: oracle valid={rep.valid} ({rep.reason}) "
-                f"jax valid={jv}")
-            continue
-        if rep.valid:
-            lg = np.log10(rep.edp)
-            assert abs(lg - out["log10_edp"][i]) <= \
-                2e-3 * max(abs(lg), 1), f"genome {i}"
+    agr = check_against_oracle(spec, "cloud", G, jm(G))
+    assert not agr.disagreements, agr.disagreements[:5]
 
 
 # ---------------------------------------- compilation sharing / promotion

@@ -39,13 +39,11 @@ def test_dryrun_single_cell(subprocess_env):
 
 def test_train_driver_with_crash_recovery(subprocess_env):
     with tempfile.TemporaryDirectory() as d:
-        # cache=False: the restart path loading cached executables
-        # segfaults on 0.4.x CPU (see conftest.subprocess_env)
         r = run_cmd(["-m", "repro.launch.train", "--arch", "xlstm-350m",
                      "--smoke", "--steps", "12", "--batch", "2",
                      "--seq", "32", "--ckpt-dir", d, "--ckpt-every", "4",
                      "--inject-failure-at", "6", "--log-every", "4"],
-                    subprocess_env(cache=False))
+                    subprocess_env())
         assert r.returncode == 0, r.stdout + r.stderr[-2000:]
         assert '"restarts": 1' in r.stdout
         # checkpoints exist

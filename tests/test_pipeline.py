@@ -202,11 +202,86 @@ def test_unclaimed_families_never_count_misses():
     assert jax_cost.compile_ahead_counts() == (0, 0)
 
 
+def test_compile_ahead_failures_are_counted_not_swallowed():
+    """A background compile that raises and an AOT executable that
+    raises when called are both counted as compile-ahead errors (the
+    first one kept); the failing call reaches its caller."""
+    import jax
+    jax_cost.clear_compile_cache()
+
+    def broken(x):
+        raise ValueError("forced lowering failure")
+
+    key = (3, 16, "forced", "u", "bcast", 4)
+    job = (key, jax.jit(broken), (jax.ShapeDtypeStruct((4,), np.float32),))
+    jax_cost.compile_ahead([job], wait=True)
+    n, first = jax_cost.compile_ahead_errors()
+    assert n == 1 and "forced lowering failure" in first
+
+    def raising_executable(*args):
+        raise RuntimeError("forced execution failure")
+
+    with jax_cost._LOCK:
+        jax_cost._AOT_FNS[key] = raising_executable
+    with pytest.raises(RuntimeError, match="forced execution failure"):
+        jax_cost._aot_call(key, jax.jit(lambda x: x), (np.zeros(4),))
+    n, first = jax_cost.compile_ahead_errors()
+    assert n == 2 and "forced lowering failure" in first
+    assert jax_cost.compile_ahead_counts() == (0, 0)
+    jax_cost.clear_compile_cache()
+    assert jax_cost.compile_ahead_errors() == (0, None)
+
+
+def test_compile_ahead_claims_are_per_pass():
+    """A fleet's misses are counted against its own predictions: a
+    family only an EARLIER pass claimed does not arm the counter."""
+    wl = by_name("mm3")
+    jax_cost.clear_compile_cache()
+    search._CACHE.clear()
+    spec, ev = search.get_evaluator(wl, "cloud")
+    jax_cost.compile_ahead([jax_cost.bcast_compile_job(ev, 64)], wait=True)
+    jax_cost.compile_ahead([jax_cost.stacked_compile_job(ev, 256)],
+                           wait=True)
+    jax_cost.reset_compile_ahead_counts()
+    ev(spec.random_genomes(np.random.default_rng(0), 100))  # bcast, 128
+    assert jax_cost.compile_ahead_counts() == (0, 0)
+
+
+def test_new_compile_ahead_pass_requeues_what_a_cancelled_pass_held():
+    """A fleet that ends cancels its compile-ahead queue.  A key that
+    queue still held pending when the next fleet's pass starts must be
+    compiled by the new pass, not skipped as pending and then dropped
+    by the cancelled worker (its dispatch would trace inline: a miss)."""
+    import jax
+    jax_cost.clear_compile_cache()
+    gate = threading.Event()
+
+    def slow(x):
+        gate.wait(10.0)                 # holds the first worker in-flight
+        return x + 1.0
+
+    S = (jax.ShapeDtypeStruct((4,), np.float32),)
+    k_slow = (1, 16, "requeue", "u", "slow", 4)
+    k_fast = (1, 16, "requeue", "u", "fast", 4)
+    fast = jax.jit(lambda x: x * 2.0)
+    jax_cost.compile_ahead([(k_slow, jax.jit(slow), S), (k_fast, fast, S)])
+    jax_cost.compile_ahead_quiesce()    # the first fleet ends
+    threading.Timer(0.3, gate.set).start()
+    jax_cost.compile_ahead([(k_fast, fast, S)], wait=True)
+    out = jax_cost._aot_call(k_fast, fast, (np.ones(4, np.float32),))
+    np.testing.assert_array_equal(np.asarray(out), 2.0)
+    assert jax_cost.compile_ahead_counts() == (1, 0)
+    assert jax_cost.compile_ahead_errors() == (0, None)
+    jax_cost.clear_compile_cache()
+
+
 def test_fleet_stats_record_compile_ahead_and_host_blocked():
     stats = {}
     _sweep(pipeline=True, stats=stats)
     assert stats["compile_ahead_hits"] >= 1
     assert stats["compile_ahead_misses"] >= 0
+    assert stats["compile_ahead_errors"] == 0
+    assert stats["compile_ahead_first_error"] is None
     assert stats["host_blocked_s"] >= 0.0
     assert stats["device_rounds_source"] == "explicit"
 

@@ -1,11 +1,10 @@
-"""Unit tests for the JAX version-compat shims (COMPAT.md): the
-cost_analysis normalizer (dict / list-of-dicts / None returns) and the
-shard_map compat import."""
+"""Unit tests for the compiled-artifact introspection helper (dict /
+None / raising ``cost_analysis()``) and the ``jax.shard_map`` surface
+the sharded kernels call."""
 import numpy as np
 import pytest
 
-from repro.launch.xla_compat import normalize_cost_analysis, \
-    xla_cost_analysis
+from repro.launch.xla_compat import xla_cost_analysis
 
 
 class _FakeCompiled:
@@ -25,25 +24,10 @@ def test_dict_return_passes_through():
     assert out is not ca                       # defensive copy
 
 
-def test_list_of_dicts_is_flattened():
-    out = xla_cost_analysis(_FakeCompiled([{"flops": 10.0}]))
-    assert out.get("flops") == 10.0
-
-
-def test_list_of_dicts_sums_numeric_keys():
-    out = normalize_cost_analysis(
-        [{"flops": 10.0, "backend": "cpu"},
-         {"flops": 5.0, "bytes accessed": 2.0, "backend": "cpu2"}])
-    assert out["flops"] == 15.0
-    assert out["bytes accessed"] == 2.0
-    assert out["backend"] == "cpu"             # first occurrence kept
-
-
 def test_none_and_errors_give_empty_dict():
     assert xla_cost_analysis(_FakeCompiled(None)) == {}
     assert xla_cost_analysis(
         _FakeCompiled(RuntimeError("unsupported"))) == {}
-    assert normalize_cost_analysis([None, {"flops": 1.0}]) == {"flops": 1.0}
 
 
 def test_real_compiled_artifact():
@@ -61,11 +45,9 @@ def test_shard_map_compat_runs():
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
-
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("x",))
     with mesh:
-        fn = shard_map(lambda a: a * 2.0, mesh=mesh,
+        fn = jax.shard_map(lambda a: a * 2.0, mesh=mesh,
                        in_specs=P(), out_specs=P(), check_vma=False)
         y = fn(jnp.arange(4.0))
     np.testing.assert_allclose(np.asarray(y), [0.0, 2.0, 4.0, 6.0])

@@ -1,0 +1,17 @@
+"""Front end: share of the window the sweep server's worker spent
+building update and done events and waking the client threads
+(``serve.emit``), clipped to the window (``repro.core.trace``)."""
+from stats import union_length
+
+
+def read(ctx):
+    try:
+        from repro.core import trace
+    except ImportError:             # a program without the recorder
+        return None
+    lo, hi = ctx["t_open"], ctx["t_close"]
+    evs = trace.events(lo, hi, {"serve.emit"})
+    if evs is None or ctx["window_s"] <= 0:
+        return None
+    return union_length(((e.t0, e.t1) for e in evs), lo, hi) / \
+        ctx["window_s"]

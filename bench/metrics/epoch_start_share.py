@@ -1,0 +1,18 @@
+"""Front end: share of the window the sweep server's worker spent
+building fleets (``fleet.start``: evaluators, compile-ahead job
+prediction, priming every query's search), once per epoch, clipped to
+the window (``repro.core.trace``)."""
+from stats import union_length
+
+
+def read(ctx):
+    try:
+        from repro.core import trace
+    except ImportError:             # a program without the recorder
+        return None
+    lo, hi = ctx["t_open"], ctx["t_close"]
+    evs = trace.events(lo, hi, {"fleet.start"})
+    if evs is None or ctx["window_s"] <= 0:
+        return None
+    return union_length(((e.t0, e.t1) for e in evs), lo, hi) / \
+        ctx["window_s"]

@@ -1,12 +1,13 @@
-"""R4 — jax_cost module counters/registries mutate only under ``_LOCK``.
+"""R4 — shared module state mutates only under the module's ``_LOCK``.
 
-The compile-ahead worker mutates the module-level counters and
-registries from its background thread while the search thread
-dispatches (jax_cost header comment), so every mutation — assignment,
-augmented increment, subscript store, or mutating method call — must
-sit lexically inside a ``with _LOCK:`` block.  Module-level
-initializers (outside any function) are exempt; reads are not
-restricted.
+Two modules hold state that several threads mutate at once: jax_cost's
+registries (the compile-ahead worker fills them from its background
+thread while the search thread dispatches) and the trace recorder's
+ring and totals in ``core/trace.py`` (every thread that records a span
+or a count).  Every mutation of that state — assignment, augmented
+increment, subscript store, or mutating method call — must sit
+lexically inside a ``with _LOCK:`` block.  Module-level initializers
+(outside any function) are exempt; reads are not restricted.
 """
 from __future__ import annotations
 
@@ -16,33 +17,37 @@ from typing import List
 
 from ..lint import Rule, Violation, names_in
 
-#: the lock-guarded module globals (jax_cost header comment)
-COUNTER_RE = re.compile(
-    r"^_(DISPATCHES|HOST_BLOCKED_S|CA_HITS|CA_MISSES|CA_ACTIVE|"
-    r"CA_PREFIXES|CA_CANCEL|STACK_PREP_HITS|STACK_PREP_MISSES|"
-    r"JIT_FNS|SHARD_FNS|STACK_CONSTS|AOT_FNS|AOT_PENDING)$")
+#: the lock-guarded module globals of each file (their header comments)
+GUARDED = {
+    "repro/core/jax_cost.py": re.compile(
+        r"^_(RESET_AT|CA_PREFIXES|CA_CANCEL|CA_THREAD|CA_FIRST_ERROR|"
+        r"JIT_FNS|SHARD_FNS|STACK_CONSTS|AOT_FNS|AOT_PENDING)$"),
+    "repro/core/trace.py": re.compile(r"^_(RING|TOTALS|DROPPED_T1)$"),
+}
 
 MUTATORS = {"clear", "update", "pop", "popitem", "setdefault", "add",
             "append", "extend", "remove", "discard", "insert"}
 
-FILES = ("repro/core/jax_cost.py",)
-
-
-def _is_counter(name: str) -> bool:
-    return bool(COUNTER_RE.match(name))
+FILES = tuple(GUARDED)
 
 
 class CounterLockRule(Rule):
     rule_id = "R4"
-    title = "jax_cost counter/registry mutations must hold _LOCK"
+    title = "shared module state (jax_cost, trace) mutates under _LOCK"
 
     def applies(self, path: str) -> bool:
         return any(path.endswith(f) for f in FILES)
 
     def check(self, tree: ast.AST, src: str, path: str) -> List[Violation]:
+        # a file outside FILES (a forced fixture) is held to every set
+        self._pats = [p for f, p in GUARDED.items()
+                      if path.endswith(f)] or list(GUARDED.values())
         out: List[Violation] = []
         self._visit(tree, path, fn_depth=0, lock_depth=0, out=out)
         return out
+
+    def _is_counter(self, name: str) -> bool:
+        return any(p.match(name) for p in self._pats)
 
     def _visit(self, node: ast.AST, path: str, fn_depth: int,
                lock_depth: int, out: List[Violation]) -> None:
@@ -66,26 +71,26 @@ class CounterLockRule(Rule):
             targets = node.targets if isinstance(node, ast.Assign) \
                 else [node.target]
             for t in targets:
-                if isinstance(t, ast.Name) and _is_counter(t.id):
+                if isinstance(t, ast.Name) and self._is_counter(t.id):
                     hits.append(t.id)
                 elif isinstance(t, ast.Subscript) and \
                         isinstance(t.value, ast.Name) and \
-                        _is_counter(t.value.id):
+                        self._is_counter(t.value.id):
                     hits.append(t.value.id)
                 elif isinstance(t, ast.Tuple):
                     for el in t.elts:
                         if isinstance(el, ast.Name) and \
-                                _is_counter(el.id):
+                                self._is_counter(el.id):
                             hits.append(el.id)
         elif isinstance(node, ast.Call) and \
                 isinstance(node.func, ast.Attribute) and \
                 node.func.attr in MUTATORS and \
                 isinstance(node.func.value, ast.Name) and \
-                _is_counter(node.func.value.id):
+                self._is_counter(node.func.value.id):
             hits.append(f"{node.func.value.id}.{node.func.attr}()")
         for h in hits:
             out.append(Violation(
                 self.rule_id, path, node.lineno,
                 f"mutation of {h} outside `with _LOCK:` races the "
-                f"compile-ahead worker thread — guard every module "
-                f"counter/registry mutation with the lock"))
+                f"other threads that mutate it — guard every mutation "
+                f"of the module's shared state with the lock"))

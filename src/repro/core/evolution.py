@@ -96,10 +96,14 @@ class SearchResult:
 
 
 class _Budget:
-    """Tracks best-so-far vs evaluation count across batched evals."""
+    """Tracks best-so-far vs evaluation count across batched evals, and
+    the search's phase (``"calibration"``, ``"init"``, ``"main"``; empty
+    for engines that do not report one), which the fleet driver marks in
+    the trace."""
 
     def __init__(self, budget: int):
         self.budget = budget
+        self.phase = ""
         self.evals = 0
         self.valid = 0
         self.best = np.inf
@@ -395,6 +399,7 @@ def evolve_requests(spec: GenomeSpec, cfg: ESConfig, tracker: _Budget,
         # time; we shrink the per-gene sampling to respect that at small
         # CI budgets.
         if (cfg.use_hshi or cfg.use_custom_ops) and sens is None:
+            tracker.phase = "calibration"
             n_ctx, n_smp = calib_plan(spec.length, cfg)
             probes, gene_idx, sampled_vals = build_probes(
                 spec, rng, n_contexts=n_ctx, n_samples=n_smp)
@@ -405,6 +410,7 @@ def evolve_requests(spec: GenomeSpec, cfg: ESConfig, tracker: _Budget,
             tracker.hist.extend([tracker.best] * sens.evals_used)
 
         # ---- initialization ----
+        tracker.phase = "init"
         if cfg.use_hshi and sens is not None:
             n_cubes = cfg.n_cubes or cfg.pop_size
             cube_budget = min(
@@ -424,6 +430,7 @@ def evolve_requests(spec: GenomeSpec, cfg: ESConfig, tracker: _Budget,
         last_best = tracker.best
         total_gens = max(1, (cfg.budget - tracker.evals) // cfg.pop_size)
 
+    tracker.phase = "main"
     op_sens = sens if cfg.use_custom_ops else None
     n_parents = max(2, int(cfg.pop_size * cfg.parent_frac))
     n_elite = max(1, int(cfg.pop_size * cfg.elite_frac))

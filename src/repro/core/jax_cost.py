@@ -37,7 +37,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from functools import lru_cache
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -46,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import density as density_lib
+from . import trace
 from .accel import Platform
 from .arch import ARCH_SPARSEMAP, ArchSpec, Topology, as_arch
 from .encoding import GenomeSpec, all_permutations
@@ -96,52 +96,70 @@ def _bucket(n: int, size: int = 16) -> int:
 # one compilation.
 _JIT_FNS: Dict[Tuple[int, int, str, str, str], object] = {}
 
-# Device dispatches issued through JaxCostModel / eval_stacked since the
-# last reset — the per-round dispatch-count benchmark hook.
-_DISPATCHES = 0
-
-# One reentrant lock guards every module-level counter and registry
-# (_JIT_FNS/_SHARD_FNS/_STACK_CONSTS/_AOT_*): the compile-ahead worker
-# mutates them from its background thread while the search thread
-# dispatches, so bare ``+= 1`` increments are no longer safe.
+# One reentrant lock guards every module-level registry
+# (_JIT_FNS/_SHARD_FNS/_STACK_CONSTS/_AOT_*) and the reset baselines:
+# the compile-ahead worker mutates them from its background thread while
+# the search thread dispatches, so bare stores are not safe.
 _LOCK = threading.RLock()
 
-# Wall-clock seconds the host spent BLOCKED converting device results to
-# numpy (np.asarray on a jax Array waits for the computation) since the
-# last reset.  The pipelined drivers exist to shrink this number; the
-# benchmark suite records it per fleet.
-_HOST_BLOCKED_S = 0.0
+# The module's counters live in the trace recorder (``core/trace.py``),
+# whose totals are cumulative since process start; each getter below
+# reads the total minus its value at the getter's last reset.
+_RESET_AT: Dict[str, float] = {}
+
+#: recorder names of the counters and spans this module feeds
+DISPATCHES = "fleet.dispatches"     # device dispatches
+BLOCK = "fleet.block"               # host blocked on device->host copies
+CA_HITS = "compile_ahead.hits"
+CA_MISSES = "compile_ahead.misses"
+CA_ERRORS = "compile_ahead.errors"
+PREP_HITS = "fleet.stack_prep.hits"
+PREP_MISSES = "fleet.stack_prep.misses"
+ROWS = "fleet.rows"                 # genome rows sent to the device
+ROWS_PADDED = "fleet.rows_padded"   # padding rows sent beside them
+
+#: the names of the kernel programs, which XLA's module names carry
+#: (``jit_<name>``) and device traces are read by
+EVAL_PROGRAM = "eval_one"       # the row evaluator, broadcast or stacked
+SCAN_PROGRAM = "one_task"       # the segment scans, canonical and direct
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    fn.__name__ = name
+    return fn
+
+
+def _since_reset(name: str):
+    with _LOCK:
+        base = _RESET_AT.get(name, 0)
+    return trace.total(name) - base
+
+
+def _reset(*names: str) -> None:
+    with _LOCK:
+        for name in names:
+            _RESET_AT[name] = trace.total(name)
 
 
 def _count_dispatch() -> None:
-    global _DISPATCHES
-    with _LOCK:
-        _DISPATCHES += 1
+    trace.count(DISPATCHES)
 
 
 def _time_block(fn: Callable):
-    """Run a blocking device->host conversion thunk, charging its wall
-    clock to the host-blocked accumulator."""
-    global _HOST_BLOCKED_S
-    t0 = time.perf_counter()
-    out = fn()
-    dt = time.perf_counter() - t0
-    with _LOCK:
-        _HOST_BLOCKED_S += dt
-    return out
+    """Run a blocking device->host conversion thunk inside a
+    ``fleet.block`` span, whose seconds are :func:`host_blocked_s`."""
+    with trace.span(BLOCK):
+        return fn()
 
 
 def host_blocked_s() -> float:
     """Seconds the host spent blocked on device->numpy conversions since
     the last reset."""
-    with _LOCK:
-        return _HOST_BLOCKED_S
+    return float(_since_reset(BLOCK))
 
 
 def reset_host_blocked_s() -> None:
-    global _HOST_BLOCKED_S
-    with _LOCK:
-        _HOST_BLOCKED_S = 0.0
+    _reset(BLOCK)
 
 
 def compilation_count() -> int:
@@ -172,14 +190,11 @@ def compile_signatures() -> Tuple[Tuple[int, int, str, str], ...]:
 def dispatch_count() -> int:
     """Device dispatches issued since the last reset (each batched
     evaluator call — per-task or mega-batch — is one dispatch)."""
-    with _LOCK:
-        return _DISPATCHES
+    return int(_since_reset(DISPATCHES))
 
 
 def reset_dispatch_count() -> None:
-    global _DISPATCHES
-    with _LOCK:
-        _DISPATCHES = 0
+    _reset(DISPATCHES)
 
 
 # ------------------------------------------------- AOT compile-ahead
@@ -196,9 +211,9 @@ def reset_dispatch_count() -> None:
 _AOT_FNS: Dict[Tuple, object] = {}
 _AOT_PENDING: Dict[Tuple, threading.Event] = {}
 _CA_PREFIXES: set = set()       # (sig..., tag) families the pass claims
-_CA_HITS = 0                    # dispatches served by an AOT executable
-_CA_MISSES = 0                  # fresh XLA traces while compile-ahead on
-_CA_ERRORS = 0                  # failed background compiles / AOT calls
+# counted in the recorder: CA_HITS, dispatches served by an AOT
+# executable; CA_MISSES, fresh XLA traces while compile-ahead is on;
+# CA_ERRORS, failed background compiles / AOT calls
 _CA_FIRST_ERROR: Optional[str] = None
 _CA_CANCEL = None               # cancel event of the latest worker
 _CA_THREAD: Optional[threading.Thread] = None   # the latest worker
@@ -208,14 +223,13 @@ def compile_ahead_counts() -> Tuple[int, int]:
     """(hits, misses) of the AOT compile-ahead registry: a hit is a
     dispatch served by a pre-built executable, a miss a dispatch that had
     to trace a fresh XLA program even though compile-ahead ran."""
-    with _LOCK:
-        return _CA_HITS, _CA_MISSES
+    return int(_since_reset(CA_HITS)), int(_since_reset(CA_MISSES))
 
 
 def reset_compile_ahead_counts() -> None:
-    global _CA_HITS, _CA_MISSES, _CA_ERRORS, _CA_FIRST_ERROR
+    global _CA_FIRST_ERROR
     with _LOCK:
-        _CA_HITS = _CA_MISSES = _CA_ERRORS = 0
+        _reset(CA_HITS, CA_MISSES, CA_ERRORS)
         _CA_FIRST_ERROR = None
 
 
@@ -225,13 +239,13 @@ def compile_ahead_errors() -> Tuple[int, Optional[str]]:
     through ``jit`` and meets the same error in the open) or an AOT
     executable that raised when called (re-raised to the caller)."""
     with _LOCK:
-        return _CA_ERRORS, _CA_FIRST_ERROR
+        return int(_since_reset(CA_ERRORS)), _CA_FIRST_ERROR
 
 
 def _record_ca_error(key: Tuple, exc: BaseException) -> None:
-    global _CA_ERRORS, _CA_FIRST_ERROR
+    global _CA_FIRST_ERROR
+    trace.count(CA_ERRORS)
     with _LOCK:
-        _CA_ERRORS += 1
         if _CA_FIRST_ERROR is None:
             _CA_FIRST_ERROR = f"{key}: {type(exc).__name__}: {exc}"
 
@@ -258,7 +272,6 @@ def _aot_call(key: Tuple, jit_fn, args: Tuple):
     were predicted) don't count.  An AOT executable that raises is
     counted as a compile-ahead error and re-raised: its donated inputs
     may already be gone, so a second dispatch through jit is unsafe."""
-    global _CA_HITS, _CA_MISSES
     cfn = _aot_lookup(key)
     if cfn is not None:
         try:
@@ -266,8 +279,7 @@ def _aot_call(key: Tuple, jit_fn, args: Tuple):
         except Exception as e:
             _record_ca_error(key, e)
             raise
-        with _LOCK:
-            _CA_HITS += 1
+        trace.count(CA_HITS)
         return out
     with _LOCK:
         armed = key[:5] in _CA_PREFIXES
@@ -283,8 +295,7 @@ def _aot_call(key: Tuple, jit_fn, args: Tuple):
     except Exception:
         traced = True
     if traced:
-        with _LOCK:
-            _CA_MISSES += 1
+        trace.count(CA_MISSES)
     return out
 
 
@@ -338,6 +349,9 @@ def compile_ahead(jobs: Sequence[Tuple[Tuple, object, Tuple]],
         return None
 
     def work():
+        # not the name it inherits from its creator (the sweep server's
+        # worker), whose spans keep a profiler trace line of their own
+        trace.name_os_thread("compile-ahead")
         for key, jit_fn, arg_structs, ev in queued:
             try:
                 if not cancel.is_set():
@@ -866,7 +880,7 @@ def _build_eval_one(d: int, n_primes_pad: int, topo: Topology,
                     edp=jnp.where(valid, edp, big),
                     log10_edp=jnp.where(valid, log10_edp, big))
 
-    return eval_one
+    return _named(eval_one, EVAL_PROGRAM)
 
 
 @lru_cache(maxsize=32)
@@ -1024,7 +1038,8 @@ def _scan_task_fn(d: int, n_pad: int, topo: Topology, dens_key: str,
         (pop, edp), ys = jax.lax.scan(step, (pop, edp), draws)
         return pop, edp, ys
 
-    return jax.vmap(one_task, in_axes=(0, 0, 0, 0, 0, 0, 0))
+    return jax.vmap(_named(one_task, SCAN_PROGRAM),
+                    in_axes=(0, 0, 0, 0, 0, 0, 0))
 
 
 def _donate_args() -> Tuple[int, ...]:
@@ -1144,7 +1159,8 @@ def _direct_scan_task_fn(d: int, n_pad: int, topo: Topology,
         (pop, edp), ys = jax.lax.scan(step, (pop, edp), draws)
         return pop, edp, ys
 
-    return jax.vmap(one_task, in_axes=(0, 0, 0, 0, 0, 0))
+    return jax.vmap(_named(one_task, SCAN_PROGRAM),
+                    in_axes=(0, 0, 0, 0, 0, 0))
 
 
 @lru_cache(maxsize=32)
@@ -1254,65 +1270,70 @@ def run_segments(models: Sequence["JaxCostModel"],
     if kind == "direct":
         return _run_direct_segments(models, segs, defer=defer)
 
-    pops, edps, ubs, fmasks, fvals, draw_list = [], [], [], [], [], []
-    n_children = 0
-    for m, s in zip(models, segs):
-        lay = _padded_layout(m)
-        if s.carry is not None:
-            pops.append(jnp.asarray(s.carry[0]))
-            edps.append(jnp.asarray(s.carry[1]))
-        else:
-            pops.append(jnp.asarray(
-                lay.pad_rows(np.asarray(s.pop, dtype=np.int32))))
-            edps.append(jnp.asarray(np.asarray(s.edp, dtype=np.float32)))
-        ubs.append(lay.pad_vector(m.spec.gene_ub.astype(np.int32), 1))
-        fm = np.zeros(lay.Lp, dtype=bool)
-        fv = np.zeros(lay.Lp, dtype=np.int32)
-        if s.fixed_genes:
-            idx = lay.pad_index(
-                np.asarray(list(s.fixed_genes), dtype=np.int64))
-            fm[idx] = True
-            fv[idx] = np.asarray(list(s.fixed_genes.values()),
-                                 dtype=np.int32)
-        fmasks.append(fm)
-        fvals.append(fv)
-        dr = dict(s.draws)
-        dr["gene"] = lay.pad_index(dr["gene"]).astype(np.int32)
-        dr["cuts"] = lay.pad_cut(dr["cuts"]).astype(np.int32)
-        if restart:
-            fr = np.asarray(dr["fresh"], dtype=np.int32)
-            gk, gc = fr.shape[0], fr.shape[1]
-            dr["fresh"] = lay.pad_rows(
-                fr.reshape(gk * gc, -1)).reshape(gk, gc, -1)
-            dr["best0"] = np.asarray([s.state[0]], dtype=np.float32)
-            dr["since0"] = np.asarray([s.state[1]], dtype=np.int32)
-        n_children = dr["ab"].shape[1]
-        draw_list.append(dr)
-    draws = {kk: jnp.asarray(np.stack([d[kk] for d in draw_list]))
-             for kk in draw_list[0]}
-    consts = tuple(
-        jnp.asarray(np.stack([np.asarray(m._np_consts[j])
-                              for m in models]))
-        for j in range(len(models[0]._np_consts)))
+    with trace.span("fleet.dispatch", sig=sig, kind="scan"):
+        pops, edps, ubs, fmasks, fvals, draw_list = [], [], [], [], [], []
+        n_children = rows = 0
+        for m, s in zip(models, segs):
+            lay = _padded_layout(m)
+            if s.carry is not None:
+                pops.append(jnp.asarray(s.carry[0]))
+                edps.append(jnp.asarray(s.carry[1]))
+            else:
+                pops.append(jnp.asarray(
+                    lay.pad_rows(np.asarray(s.pop, dtype=np.int32))))
+                edps.append(jnp.asarray(
+                    np.asarray(s.edp, dtype=np.float32)))
+            ubs.append(lay.pad_vector(m.spec.gene_ub.astype(np.int32), 1))
+            fm = np.zeros(lay.Lp, dtype=bool)
+            fv = np.zeros(lay.Lp, dtype=np.int32)
+            if s.fixed_genes:
+                idx = lay.pad_index(
+                    np.asarray(list(s.fixed_genes), dtype=np.int64))
+                fm[idx] = True
+                fv[idx] = np.asarray(list(s.fixed_genes.values()),
+                                     dtype=np.int32)
+            fmasks.append(fm)
+            fvals.append(fv)
+            dr = dict(s.draws)
+            dr["gene"] = lay.pad_index(dr["gene"]).astype(np.int32)
+            dr["cuts"] = lay.pad_cut(dr["cuts"]).astype(np.int32)
+            if restart:
+                fr = np.asarray(dr["fresh"], dtype=np.int32)
+                gk, gc = fr.shape[0], fr.shape[1]
+                dr["fresh"] = lay.pad_rows(
+                    fr.reshape(gk * gc, -1)).reshape(gk, gc, -1)
+                dr["best0"] = np.asarray([s.state[0]], dtype=np.float32)
+                dr["since0"] = np.asarray([s.state[1]], dtype=np.int32)
+                rows += k * gc
+            n_children = dr["ab"].shape[1]
+            rows += k * n_children
+            draw_list.append(dr)
+        draws = {kk: jnp.asarray(np.stack([d[kk] for d in draw_list]))
+                 for kk in draw_list[0]}
+        consts = tuple(
+            jnp.asarray(np.stack([np.asarray(m._np_consts[j])
+                                  for m in models]))
+            for j in range(len(models[0]._np_consts)))
 
-    T = len(segs)
-    topo = models[0].arch.topology
-    args = (jnp.stack(pops), jnp.stack(edps),
-            jnp.asarray(np.stack(ubs)), jnp.asarray(np.stack(fmasks)),
-            jnp.asarray(np.stack(fvals)), draws, consts)
-    _count_dispatch()
-    if mesh is not None and _mesh_ndev(mesh) > 1 and \
-            T % _mesh_ndev(mesh) == 0 and not restart:
-        fn = _sharded_scan_fn(sig[0], sig[1], topo, sig[3], n_parents,
-                              n_elite, genes_per, mesh)
-        pop_f, edp_f, ys = fn(*args)
-    else:
-        fn = _scan_fn(sig[0], sig[1], topo, sig[3], n_parents, n_elite,
-                      genes_per, restart)
-        tag = f"scan:p{n_parents}e{n_elite}g{genes_per}" + (
-            f"r{restart}" if restart else "")
-        key = sig + (tag, T, B, k, n_children)
-        pop_f, edp_f, ys = _aot_call(key, fn, args)
+        T = len(segs)
+        topo = models[0].arch.topology
+        args = (jnp.stack(pops), jnp.stack(edps),
+                jnp.asarray(np.stack(ubs)), jnp.asarray(np.stack(fmasks)),
+                jnp.asarray(np.stack(fvals)), draws, consts)
+        _count_dispatch()
+        trace.count(ROWS, rows, sig=sig)
+        if mesh is not None and _mesh_ndev(mesh) > 1 and \
+                T % _mesh_ndev(mesh) == 0 and not restart:
+            fn = _sharded_scan_fn(sig[0], sig[1], topo, sig[3], n_parents,
+                                  n_elite, genes_per, mesh)
+            pop_f, edp_f, ys = fn(*args)
+        else:
+            fn = _scan_fn(sig[0], sig[1], topo, sig[3], n_parents, n_elite,
+                          genes_per, restart)
+            tag = f"scan:p{n_parents}e{n_elite}g{genes_per}" + (
+                f"r{restart}" if restart else "")
+            key = sig + (tag, T, B, k, n_children)
+            pop_f, edp_f, ys = _aot_call(key, fn, args)
 
     host = {}
 
@@ -1375,38 +1396,40 @@ def _run_direct_segments(models: Sequence["JaxCostModel"],
     if restart:
         raise ValueError("direct segments do not support in-scan restart")
 
-    pops, edps, scrs, dims, draw_list = [], [], [], [], []
-    n_children = 0
-    for m, s in zip(models, segs):
-        if s.carry is not None:
-            pops.append(jnp.asarray(s.carry[0]))
-            edps.append(jnp.asarray(s.carry[1]))
-        else:
-            pops.append(jnp.asarray(np.asarray(s.pop, dtype=np.int32)))
-            edps.append(jnp.asarray(np.asarray(s.edp, dtype=np.float32)))
-        scrs.append(np.asarray(s.aux["scramble"], dtype=np.int32))
-        dims.append(np.asarray(s.aux["dim_sizes"], dtype=np.float32))
-        dr = {kk: np.asarray(v) for kk, v in s.draws.items()}
-        n_children = dr["ab"].shape[1]
-        draw_list.append(dr)
-    draws = {kk: jnp.asarray(np.stack([d[kk] for d in draw_list]))
-             for kk in draw_list[0]}
-    consts = tuple(
-        jnp.asarray(np.stack([np.asarray(m._np_consts[j])
-                              for m in models]))
-        for j in range(len(models[0]._np_consts)))
+    with trace.span("fleet.dispatch", sig=sig, kind="dscan"):
+        pops, edps, scrs, dims, draw_list = [], [], [], [], []
+        n_children = 0
+        for m, s in zip(models, segs):
+            if s.carry is not None:
+                pops.append(jnp.asarray(s.carry[0]))
+                edps.append(jnp.asarray(s.carry[1]))
+            else:
+                pops.append(jnp.asarray(np.asarray(s.pop, dtype=np.int32)))
+                edps.append(jnp.asarray(np.asarray(s.edp, dtype=np.float32)))
+            scrs.append(np.asarray(s.aux["scramble"], dtype=np.int32))
+            dims.append(np.asarray(s.aux["dim_sizes"], dtype=np.float32))
+            dr = {kk: np.asarray(v) for kk, v in s.draws.items()}
+            n_children = dr["ab"].shape[1]
+            draw_list.append(dr)
+        draws = {kk: jnp.asarray(np.stack([d[kk] for d in draw_list]))
+                 for kk in draw_list[0]}
+        consts = tuple(
+            jnp.asarray(np.stack([np.asarray(m._np_consts[j])
+                                  for m in models]))
+            for j in range(len(models[0]._np_consts)))
 
-    T = len(segs)
-    topo = models[0].arch.topology
-    fn = _direct_scan_fn(sig[0], sig[1], topo, sig[3], n_parents,
-                         n_elite, genes_per)
-    key = sig + (f"dscan:p{n_parents}e{n_elite}g{genes_per}",
-                 T, B, k, n_children)
-    _count_dispatch()
-    pop_f, edp_f, ys = _aot_call(
-        key, fn, (jnp.stack(pops), jnp.stack(edps),
-                  jnp.asarray(np.stack(scrs)), jnp.asarray(np.stack(dims)),
-                  draws, consts))
+        T = len(segs)
+        topo = models[0].arch.topology
+        fn = _direct_scan_fn(sig[0], sig[1], topo, sig[3], n_parents,
+                             n_elite, genes_per)
+        key = sig + (f"dscan:p{n_parents}e{n_elite}g{genes_per}",
+                     T, B, k, n_children)
+        _count_dispatch()
+        trace.count(ROWS, T * k * n_children, sig=sig)
+        pop_f, edp_f, ys = _aot_call(
+            key, fn, (jnp.stack(pops), jnp.stack(edps),
+                      jnp.asarray(np.stack(scrs)), jnp.asarray(np.stack(dims)),
+                      draws, consts))
 
     host = {}
 
@@ -1560,20 +1583,25 @@ class JaxCostModel:
         the next power of two and the prime axis to its bucket."""
         n = len(genomes)
         padded = _pad_batch(n)
-        perm, til, fmt, sg = self._prepare(genomes)
-        if padded != n:
-            perm, til, fmt, sg = (
-                np.concatenate(
-                    [a, np.zeros((padded - n,) + a.shape[1:], np.int32)],
-                    axis=0) for a in (perm, til, fmt, sg))
-        _count_dispatch()
-        out = _aot_call(
-            self.signature + ("bcast", padded), self._fn,
-            (jnp.asarray(perm), jnp.asarray(til),
-             jnp.asarray(fmt), jnp.asarray(sg),
-             self._primes, self._prime_dim, self._relevance,
-             self._densities, self._full_elems, self._total_macs,
-             self._z_onehot, self._plat, self._dens_params))
+        sig = self.signature
+        with trace.span("fleet.dispatch", sig=sig, kind="bcast"):
+            perm, til, fmt, sg = self._prepare(genomes)
+            if padded != n:
+                perm, til, fmt, sg = (
+                    np.concatenate(
+                        [a, np.zeros((padded - n,) + a.shape[1:],
+                                     np.int32)],
+                        axis=0) for a in (perm, til, fmt, sg))
+            _count_dispatch()
+            trace.count(ROWS, n, sig=sig)
+            trace.count(ROWS_PADDED, padded - n, sig=sig)
+            out = _aot_call(
+                sig + ("bcast", padded), self._fn,
+                (jnp.asarray(perm), jnp.asarray(til),
+                 jnp.asarray(fmt), jnp.asarray(sg),
+                 self._primes, self._prime_dim, self._relevance,
+                 self._densities, self._full_elems, self._total_macs,
+                 self._z_onehot, self._plat, self._dens_params))
         return _canonical(_time_block(
             lambda: {k: np.asarray(v)[:n] for k, v in out.items()}))
 
@@ -1620,36 +1648,28 @@ def _canonical(out: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
 # arch per model), never id(), so a recycled object can't alias a stale
 # entry and no strong model refs need pinning.
 _STACK_CONSTS: Dict[Tuple[int, int, str, str], Tuple[Tuple, List]] = {}
-_STACK_PREP_HITS = 0
-_STACK_PREP_MISSES = 0
 
 
 def stack_prep_counts() -> Tuple[int, int]:
     """(cache hits, cache misses) of the stacked-constants prep cache."""
-    with _LOCK:
-        return _STACK_PREP_HITS, _STACK_PREP_MISSES
+    return int(_since_reset(PREP_HITS)), int(_since_reset(PREP_MISSES))
 
 
 def reset_stack_prep_counts() -> None:
-    global _STACK_PREP_HITS, _STACK_PREP_MISSES
-    with _LOCK:
-        _STACK_PREP_HITS = _STACK_PREP_MISSES = 0
+    _reset(PREP_HITS, PREP_MISSES)
 
 
 def _stacked_consts(models: Sequence["JaxCostModel"],
                     sizes: Sequence[int], padded: int) -> List[np.ndarray]:
-    global _STACK_PREP_HITS, _STACK_PREP_MISSES
     sig = models[0].signature
     key = (tuple((m.spec.workload.cache_key(), m.arch) for m in models),
            tuple(sizes), padded)
     with _LOCK:
         hit = _STACK_CONSTS.get(sig)
     if hit is not None and hit[0] == key:
-        with _LOCK:
-            _STACK_PREP_HITS += 1
+        trace.count(PREP_HITS)
         return hit[1]
-    with _LOCK:
-        _STACK_PREP_MISSES += 1
+    trace.count(PREP_MISSES)
     consts: List[np.ndarray] = []
     for j in range(len(models[0]._np_consts)):
         rows = [np.broadcast_to(m._np_consts[j],
@@ -1741,28 +1761,31 @@ def eval_stacked(models: Sequence["JaxCostModel"],
     ndev = _mesh_ndev(mesh) if mesh is not None else 1
     if ndev > 1 and padded % ndev:
         padded = -(-padded // ndev) * ndev
-    preps = [m._prepare(b) for m, b in zip(models, batches)]
-    ins = []
-    for cols in zip(*preps):
-        arr = np.concatenate(cols, axis=0)
-        if padded != total:
-            arr = np.concatenate(
-                [arr, np.zeros((padded - total,) + arr.shape[1:],
-                               np.int32)], axis=0)
-        ins.append(arr)
-    consts = _stacked_consts(models, sizes, padded)
-    _count_dispatch()
-    args = tuple(jnp.asarray(a) for a in ins) + \
-        tuple(jnp.asarray(c) for c in consts)
-    if ndev > 1:
-        fn = _sharded_stacked_fn(sig[0], sig[1],
-                                 models[0].arch.topology, sig[3], mesh)
-        out = fn(*args)
-    else:
-        fn = _jitted_eval(sig[0], sig[1], models[0].arch.topology,
-                          sig[3], stacked=True)
-        out = _aot_call(sig + ("stacked", padded), fn, args)
-    pending = StackedPending(out, sizes)
+    with trace.span("fleet.dispatch", sig=sig, kind="stacked"):
+        preps = [m._prepare(b) for m, b in zip(models, batches)]
+        ins = []
+        for cols in zip(*preps):
+            arr = np.concatenate(cols, axis=0)
+            if padded != total:
+                arr = np.concatenate(
+                    [arr, np.zeros((padded - total,) + arr.shape[1:],
+                                   np.int32)], axis=0)
+            ins.append(arr)
+        consts = _stacked_consts(models, sizes, padded)
+        _count_dispatch()
+        trace.count(ROWS, total, sig=sig)
+        trace.count(ROWS_PADDED, padded - total, sig=sig)
+        args = tuple(jnp.asarray(a) for a in ins) + \
+            tuple(jnp.asarray(c) for c in consts)
+        if ndev > 1:
+            fn = _sharded_stacked_fn(sig[0], sig[1],
+                                     models[0].arch.topology, sig[3], mesh)
+            out = fn(*args)
+        else:
+            fn = _jitted_eval(sig[0], sig[1], models[0].arch.topology,
+                              sig[3], stacked=True)
+            out = _aot_call(sig + ("stacked", padded), fn, args)
+        pending = StackedPending(out, sizes)
     if defer:
         return pending
     return pending.finalize()

@@ -32,13 +32,14 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import time
 import warnings
 from collections import Counter
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from . import accel, es_ops, jax_cost
+from . import accel, es_ops, jax_cost, trace
 from .arch import ArchSpec, as_arch
 from .baselines import (METHODS, REQUEST_METHODS, SEGMENT_METHODS,
                         make_requests)
@@ -439,6 +440,7 @@ class _TaskState:
     method: str
     req: Optional[np.ndarray] = None
     extras: Optional[Dict] = None
+    phase: str = ""                  # the tracker's phase last seen
 
     @property
     def signature(self) -> Tuple[int, int, str]:
@@ -563,6 +565,9 @@ class MultiSearch:
         self.compile_ahead = config.compile_ahead
         self.final_names: List[str] = self._resolve_names(norm)
         self.stats: Dict = {}
+        # ids the caller gives task names (the sweep server's query ids);
+        # each task's ``es.phase`` trace marks carry its id
+        self.trace_ids: Dict[str, object] = {}
         self._started = False
 
     @staticmethod
@@ -702,15 +707,23 @@ class MultiSearch:
                         restart=plan["restart"]))
         return jobs + late
 
-    @staticmethod
-    def _advance(st: _TaskState, out: Dict) -> bool:
-        """Send an evaluation to a task's generator; False when done."""
+    def _advance(self, st: _TaskState, out: Dict) -> bool:
+        """Send an evaluation to a task's generator; False when done.
+        A change of the search's phase (``_Budget.phase``: calibration,
+        init, main) is marked in the trace as ``es.phase``."""
         try:
             st.req = st.gen.send(out)
-            return True
+            alive = True
         except StopIteration as stop:
             st.extras = stop.value or {}
-            return False
+            alive = False
+        phase = st.tracker.phase
+        if phase != st.phase:
+            st.phase = phase
+            t = time.perf_counter()
+            trace.record("es.phase", t, t, task=st.name, phase=phase,
+                         query=self.trace_ids.get(st.name))
+        return alive
 
     def _task_infos(self) -> List[Tuple]:
         """One signature-aligned (task, method_kw, spec, evaluator)
@@ -806,6 +819,7 @@ class MultiSearch:
             except StopIteration as stop:
                 st.extras = stop.value or {}
                 self._done.append(st.name)
+            st.phase = st.tracker.phase
         self._pad_hwm: Dict[Tuple[int, int, str], int] = {}
         self._pad_recent: Dict[Tuple[int, int, str],
                                List[Tuple[int, int]]] = {}
@@ -868,6 +882,7 @@ class MultiSearch:
         except StopIteration as stop:
             st.extras = stop.value or {}
             self._done.append(st.name)
+        st.phase = st.tracker.phase
         return resolved
 
     @property
@@ -948,16 +963,21 @@ class MultiSearch:
                 segres = jax_cost.run_segments(
                     [s.ev for s in grp], [s.req for s in grp],
                     mesh=self.mesh, defer=self.pipeline)
-                for st, res in zip(grp, segres):
-                    if self._advance(st, res):
-                        pending.append(st)
+                # the generators resolve the previous segment's harvest
+                # in here: its fleet.block span nests in this one
+                with trace.span("fleet.advance", step=self._host_syncs,
+                                sig=key[:4]):
+                    for st, res in zip(grp, segres):
+                        if self._advance(st, res):
+                            pending.append(st)
         elif seg_states:
             # host-loop reference path: the generator replays the
             # identical pre-drawn plan per-round (its next yield is a
             # plain batch, so the task rejoins the per-round path)
-            for st in seg_states:
-                if self._advance(st, None):
-                    pending.append(st)
+            with trace.span("fleet.advance", step=self._host_syncs):
+                for st in seg_states:
+                    if self._advance(st, None):
+                        pending.append(st)
         if seg_states and self.device_execute:
             self._seg_syncs += 1
             self._seg_rounds += iter_weight
@@ -1004,15 +1024,22 @@ class MultiSearch:
                     hist.clear()
                 wm_hist.setdefault(sig, []).append(pad_hwm[sig])
             for grp, outs in dispatched:
-                if isinstance(outs, jax_cost.StackedPending):
-                    outs = outs.finalize()
-                for st, out in zip(grp, outs):
-                    if self._advance(st, out):
-                        pending.append(st)
+                # from the group's results to its next batches: the
+                # finalize's fleet.block span nests in this one
+                with trace.span("fleet.advance", step=self._host_syncs,
+                                sig=grp[0].signature):
+                    if isinstance(outs, jax_cost.StackedPending):
+                        outs = outs.finalize()
+                    for st, out in zip(grp, outs):
+                        if self._advance(st, out):
+                            pending.append(st)
         else:
             for st in plain:
-                if self._advance(st, st.ev(st.req)):
-                    pending.append(st)
+                out = st.ev(st.req)
+                with trace.span("fleet.advance", step=self._host_syncs,
+                                sig=st.signature):
+                    if self._advance(st, out):
+                        pending.append(st)
         live = {id(st) for st in pending}
         for st in alive:
             if id(st) not in live:

@@ -44,6 +44,7 @@ import socket
 import socketserver
 import sys
 import threading
+import time
 import traceback
 from collections import deque
 from typing import Dict, List, Optional, Tuple
@@ -51,7 +52,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.checkpoint import checkpoint as ckpt_lib
-from repro.core import jax_cost
+from repro.core import jax_cost, trace
 from repro.core.arch import UnknownArchError, as_arch
 from repro.core.baselines import RESUMABLE_METHODS, WARM_START_METHODS
 from repro.core.evolution import snapshot_tracker_hist
@@ -227,6 +228,8 @@ class _Pending:
         self.task = task
         self.events = events
         self.name: Optional[str] = None
+        self.qid: Optional[int] = None          # the server's query id
+        self.t_arrive: Optional[float] = None   # perf_counter at submit
 
 
 class SweepServer:
@@ -358,10 +361,12 @@ class SweepServer:
             return
         events: deque = deque()
         pend = _Pending(task, events)
+        pend.t_arrive = time.perf_counter()
         ready = threading.Event()
         pend.ready = ready
         with self._cond:
             self._stats["queries"] += 1
+            pend.qid = self._stats["queries"]
             self._pending.append(pend)
             self._cond.notify_all()
         ready.wait(timeout=300)
@@ -388,6 +393,8 @@ class SweepServer:
     # --------------------------------------------------------------- worker
 
     def _worker_loop(self) -> None:
+        # the fleet's spans keep a line of their own in a profiler trace
+        trace.name_os_thread(self._worker.name)
         # orphan recovery: a fresh server process pointed at the
         # checkpoint dir of a crashed one resumes its in-flight fleet
         # (results feed the library; the dead clients' streams are gone)
@@ -395,7 +402,7 @@ class SweepServer:
                 ckpt_lib.latest_step(self.ckpt_dir) is not None:
             self._run_epoch([])
         while not self._shutdown.is_set():
-            with self._cond:
+            with trace.span("serve.wait"), self._cond:
                 while not self._pending and not self._shutdown.is_set():
                     self._cond.wait(timeout=0.1)
                 if self._shutdown.is_set():
@@ -443,7 +450,13 @@ class SweepServer:
         sup = Supervisor(self.ckpt_dir or "", ckpt_every=self.ckpt_every,
                          max_restarts=self.max_restarts)
 
-        def wire(p: _Pending, name: str) -> None:
+        def wire(ms: MultiSearch, p: _Pending, name: str) -> None:
+            if p.name is None and p.t_arrive is not None:
+                # arrival to admission, once per query (a crash
+                # re-admission wires it again)
+                trace.record("serve.queue", p.t_arrive,
+                             time.perf_counter(), query=p.qid, task=name)
+            ms.trace_ids[name] = p.qid
             p.name = name
             by_name[name] = p
             self._last_best.setdefault(name, float("inf"))
@@ -460,23 +473,27 @@ class SweepServer:
                 # resolve at construction, so every client learns its id
                 # BEFORE start()'s calibration compiles (minutes on a
                 # cold process)
-                tasks = [self._prepare(p) for p in pends]
-                ms = MultiSearch(tasks, self.config)
-                for p, name in zip(pends, ms.final_names):
-                    wire(p, name)
-                ms.start()
-            elif ms is not None:
-                for p in pends:
-                    wire(p, ms.admit(self._prepare(p)))
+                with trace.span("fleet.start", queries=len(pends)):
+                    tasks = [self._prepare(p) for p in pends]
+                    ms = MultiSearch(tasks, self.config)
+                    for p, name in zip(pends, ms.final_names):
+                        wire(ms, p, name)
+                    ms.start()
+            elif ms is not None and pends:
+                with trace.span("serve.admit", step=ms._host_syncs,
+                                queries=len(pends)):
+                    for p in pends:
+                        wire(ms, p, ms.admit(self._prepare(p)))
             return ms
 
         def make_state(step: Optional[int]) -> MultiSearch:
             ms = None
             if step is not None and self.ckpt_dir is not None:
-                arrays, meta = ckpt_lib.load_flat(self.ckpt_dir, step)
-                ms = restore_fleet(arrays, meta)
-                if ms is not None:
-                    ms.start()
+                with trace.span("fleet.start", restored=step):
+                    arrays, meta = ckpt_lib.load_flat(self.ckpt_dir, step)
+                    ms = restore_fleet(arrays, meta)
+                    if ms is not None:
+                        ms.start()
             with self._fleet_lock:
                 # re-admit every epoch query the checkpoint doesn't
                 # carry: on first build that is all of them; after a
@@ -545,38 +562,39 @@ class SweepServer:
             self._ms = None
 
     def _emit_updates(self, ms: MultiSearch) -> None:
-        for name, res in ms.pop_done():
-            self._stats["completed"] += 1
-            task = dict(zip(ms.final_names, ms.tasks))[name]
-            self.library.record(task, res)
-            with self._events_lock:
-                q = self._events.pop(name, None)
-            if q is not None:
-                q.append({
-                    "event": "done", "id": name,
-                    "best_edp": float(res.best_edp),
-                    "best_genome": None if res.best_genome is None
-                    else np.asarray(res.best_genome).tolist(),
-                    "evals": int(res.evals),
-                    "valid_evals": int(res.valid_evals),
-                    "round": int(ms._rounds)})
-        for st in ms._alive:
-            best = float(st.tracker.best)
-            if best < self._last_best.get(st.name, float("inf")):
-                self._last_best[st.name] = best
+        with trace.span("serve.emit", step=ms._host_syncs):
+            for name, res in ms.pop_done():
+                self._stats["completed"] += 1
+                task = dict(zip(ms.final_names, ms.tasks))[name]
+                self.library.record(task, res)
                 with self._events_lock:
-                    q = self._events.get(st.name)
+                    q = self._events.pop(name, None)
                 if q is not None:
-                    bg = st.tracker.best_genome
                     q.append({
-                        "event": "update", "id": st.name,
-                        "best_edp": best,
-                        "best_genome": None if bg is None
-                        else np.asarray(bg).tolist(),
-                        "evals": int(st.tracker.evals),
+                        "event": "done", "id": name,
+                        "best_edp": float(res.best_edp),
+                        "best_genome": None if res.best_genome is None
+                        else np.asarray(res.best_genome).tolist(),
+                        "evals": int(res.evals),
+                        "valid_evals": int(res.valid_evals),
                         "round": int(ms._rounds)})
-        with self._cond:
-            self._cond.notify_all()
+            for st in ms._alive:
+                best = float(st.tracker.best)
+                if best < self._last_best.get(st.name, float("inf")):
+                    self._last_best[st.name] = best
+                    with self._events_lock:
+                        q = self._events.get(st.name)
+                    if q is not None:
+                        bg = st.tracker.best_genome
+                        q.append({
+                            "event": "update", "id": st.name,
+                            "best_edp": best,
+                            "best_genome": None if bg is None
+                            else np.asarray(bg).tolist(),
+                            "evals": int(st.tracker.evals),
+                            "round": int(ms._rounds)})
+            with self._cond:
+                self._cond.notify_all()
 
     # ---------------------------------------------------------------- stats
 
@@ -605,6 +623,9 @@ class SweepServer:
                 out["signature_groups"] = dict(self._last_groups)
         out["epoch_signature_groups"] = [dict(g)
                                          for g in self._epoch_groups]
+        # the process's cumulative spans and counters (core/trace.py):
+        # unlike "fleet", these survive epochs
+        out["trace"] = trace.totals()
         fleet = out.get("fleet")
         if fleet and fleet.get("rounds"):
             out["dispatches_per_round"] = \

@@ -269,7 +269,10 @@ def zoo_validation_report() -> Dict[str, Dict[str, float]]:
 # arch name.  Every topology measured so far shows the same shape — a
 # round-1 calibration/chunk spike that decays once and never re-grows —
 # so ``search.derive_pad_policy`` tunes them all to the faster
-# ``decay_rounds=2`` instead of the conservative CPU default.  When a
+# ``decay_rounds=2`` instead of the conservative CPU default.  The
+# derived ``decay_ratio`` guards only shapes a fleet has not run yet: a
+# fleet decays to a shape it has already dispatched whatever the ratio,
+# so these CPU trajectories matter only for cold shapes.  When a
 # regenerated baseline changes a trajectory, update the table; the
 # ``benchmarks/compare_sweep.py`` staleness check warns when a fresh
 # run's trajectory disagrees with the policy registered here.

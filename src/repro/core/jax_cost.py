@@ -1712,6 +1712,17 @@ class StackedPending:
         return self._sliced
 
 
+def stacked_rows(total: int, pad_floor: int = 0, mesh=None) -> int:
+    """The rows :func:`eval_stacked` dispatches for ``total`` genome
+    rows: the power-of-two rule raised to ``pad_floor``, then to a
+    multiple of the mesh's device count."""
+    padded = max(_pad_batch(total), int(pad_floor))
+    ndev = _mesh_ndev(mesh)
+    if ndev > 1 and padded % ndev:
+        padded = -(-padded // ndev) * ndev
+    return padded
+
+
 def eval_stacked(models: Sequence["JaxCostModel"],
                  batches: Sequence[np.ndarray],
                  pad_floor: int = 0,
@@ -1757,10 +1768,8 @@ def eval_stacked(models: Sequence["JaxCostModel"],
             f"{sorted({m.signature for m in models})}")
     sizes = [len(b) for b in batches]
     total = sum(sizes)
-    padded = max(_pad_batch(total), int(pad_floor))
-    ndev = _mesh_ndev(mesh) if mesh is not None else 1
-    if ndev > 1 and padded % ndev:
-        padded = -(-padded // ndev) * ndev
+    padded = stacked_rows(total, pad_floor, mesh)
+    ndev = _mesh_ndev(mesh)
     with trace.span("fleet.dispatch", sig=sig, kind="stacked"):
         preps = [m._prepare(b) for m, b in zip(models, batches)]
         ins = []

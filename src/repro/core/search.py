@@ -135,14 +135,19 @@ def report_best(workload: Workload, platform: PlatformLike,
 class PadPolicy:
     """Mega-batch pad-watermark grow/decay constants for ONE topology.
 
-    The watermark grows to the largest padded round immediately;
-    it decays after ``decay_rounds`` consecutive rounds each needing at
-    most ``decay_ratio`` of the current shape.  The defaults are
-    CPU-tuned; each registered topology compiles its own kernel family,
-    so the retrace-vs-padded-compute sweet spot is a per-topology number
-    — register a measured policy with :func:`set_pad_policy` (keyed by
-    ``Topology.fingerprint``) or pass ``pad_policies`` to
-    :class:`MultiSearch` for a one-off override.
+    The watermark grows to the largest padded round immediately; after
+    ``decay_rounds`` quiet rounds it decays to their largest shape.  A
+    shape the fleet has already dispatched costs no trace, so the decay
+    to it is taken at once; ``decay_ratio`` guards only shapes the fleet
+    has not run yet, which it decays to only when every quiet round
+    needs at most ``decay_ratio`` of the watermark as growth and such
+    cold decays set it (warm decays leave that reference alone).  The
+    defaults are CPU-tuned; each registered topology compiles its own
+    kernel family, so the retrace-vs-padded-compute sweet spot for a
+    cold shape is a per-topology number — register a measured policy
+    with :func:`set_pad_policy` (keyed by ``Topology.fingerprint``) or
+    pass ``pad_policies`` to :class:`MultiSearch` for a one-off
+    override.
 
     ``source`` records where the constants came from: ``"default"`` (the
     CPU-tuned fallback), ``"measured"`` (derived from a committed
@@ -156,6 +161,10 @@ class PadPolicy:
     decay_ratio: float = 0.5
     source: str = "default"
 
+
+#: recorder counter of watermark decays; ``warm=True`` marks one the
+#: ``decay_ratio`` test refused, taken because the fleet had run the shape
+PAD_DECAYS = "fleet.pad_decays"
 
 #: The explicit policy :func:`pad_policy_for` returns for topologies with
 #: no registered entry: the conservative CPU-tuned constants.
@@ -176,12 +185,15 @@ def derive_pad_policy(trajectory: Sequence[int],
     (``decay_rounds=2``) — one fewer round of mostly-padding kernel
     compute — with ``decay_ratio`` tightened to the observed post-spike
     plateau, so the earlier decay does NOT buy extra re-traces later
-    (marginal follow-up decays, e.g. 256 -> 128, stay suppressed).  A
-    trajectory that re-grows after decaying (oscillating fleet demand)
-    keeps the conservative default, where an extra quiet round must pass
-    before paying the re-trace.  ``benchmarks/compare_sweep.py`` mirrors
-    the decay_rounds rule (stdlib-only) to warn when a fresh trajectory
-    disagrees with the registered policy."""
+    (marginal follow-up decays to a shape not yet run, e.g. 256 -> 128,
+    stay suppressed).  A trajectory that re-grows after decaying
+    (oscillating fleet demand) keeps the conservative default, where an
+    extra quiet round must pass before paying the re-trace.
+    ``decay_ratio`` guards only shapes the fleet has not run yet: the
+    fleet decays to a shape it has already dispatched whatever the
+    ratio (:meth:`MultiSearch.step`).  ``benchmarks/compare_sweep.py``
+    mirrors the decay_rounds rule (stdlib-only) to warn when a fresh
+    trajectory disagrees with the registered policy."""
     traj = list(trajectory)
     peak = max(traj, default=0)
     if peak <= 0 or traj[-1] >= peak:
@@ -823,6 +835,11 @@ class MultiSearch:
         self._pad_hwm: Dict[Tuple[int, int, str], int] = {}
         self._pad_recent: Dict[Tuple[int, int, str],
                                List[Tuple[int, int]]] = {}
+        # padded mega-batch rows this fleet has dispatched, per signature
+        self._pad_run: Dict[Tuple[int, int, str], set] = {}
+        # the watermark as growth and ratio decays alone would hold it
+        self._pad_ref: Dict[Tuple[int, int, str], int] = {}
+        self._pad_decays = {"warm": 0, "cold": 0}
         self._wm_hist: Dict[Tuple[int, int, str], List[int]] = {}
         self._rounds = 0     # weighted generation clock (k per segment)
         self._host_syncs = 0   # driver loop iterations (host roundtrips)
@@ -917,12 +934,21 @@ class MultiSearch:
         The pad floor (mega-batch watermark) grows to the largest padded
         round immediately (shrinking fleets keep hitting the warm
         shape), and decays to the recent maximum after ``decay_rounds``
-        consecutive rounds each needing at most ``decay_ratio`` of the
-        current shape — one extra XLA trace instead of paying
+        quiet rounds.  When this fleet has already dispatched that
+        shape (it keeps the padded rows of each of its mega-batches per
+        signature) the decay costs no trace and is taken at once, so a
+        spike from an admitted query's calibration batch does not hold
+        later rounds at its shape.  A shape not yet run is decayed to
+        only when each quiet round needs at most ``decay_ratio`` of the
+        watermark as growth and these cold decays set it (``_pad_ref``;
+        a warm decay to a middle shape must not shut out a smaller cold
+        one the ratio allows) — one extra XLA trace instead of paying
         mostly-padding kernel compute every round after a one-off spike
         (e.g. round-1 calibration probes + random_mapper's 512-row
-        chunks).  The grow/decay constants are a per-TOPOLOGY
-        :class:`PadPolicy`; the per-round watermark trajectory lands in
+        chunks).  Each decay counts in the recorder's
+        :data:`PAD_DECAYS` and in ``stats["pad_decays"]``.  The
+        grow/decay constants are a per-TOPOLOGY :class:`PadPolicy`; the
+        per-round watermark trajectory lands in
         ``stats["pad_watermarks"]`` for cross-PR tracking.  The
         ``pad_recent`` observations are (target, weight) pairs; weight =
         search rounds the fleet clock advanced at that observation, so
@@ -936,6 +962,8 @@ class MultiSearch:
             return False
         pad_hwm = self._pad_hwm
         pad_recent = self._pad_recent
+        pad_run = self._pad_run
+        pad_ref = self._pad_ref
         wm_hist = self._wm_hist
         pending: List[_TaskState] = []
         seg_states = [st for st in alive
@@ -1007,21 +1035,39 @@ class MultiSearch:
                     pad_floor=hwm, mesh=self.mesh,
                     defer=self.pipeline)
                 dispatched.append((grp, outs))
-                target = jax_cost._pad_batch(
-                    sum(len(s.req) for s in grp))
+                total = sum(len(s.req) for s in grp)
+                target = jax_cost._pad_batch(total)
+                ran = pad_run.setdefault(sig, set())
+                ran.add(jax_cost.stacked_rows(total, hwm, self.mesh))
                 hist = pad_recent.setdefault(sig, [])
                 hist.append((target, max(iter_weight, 1)))
                 wtot = sum(w for _, w in hist)
                 while hist and wtot - hist[0][1] >= pol.decay_rounds:
                     wtot -= hist.pop(0)[1]
+                ref = pad_ref.get(sig, 0)
                 if target > hwm:
                     pad_hwm[sig] = target
+                    pad_ref[sig] = max(ref, target)
                     hist.clear()
-                elif wtot >= pol.decay_rounds and \
-                        all(t <= hwm * pol.decay_ratio
-                            for t, _ in hist):
-                    pad_hwm[sig] = max(t for t, _ in hist)
-                    hist.clear()
+                elif wtot >= pol.decay_rounds:
+                    peak = max(t for t, _ in hist)
+                    # the ratio is taken against the watermark warm
+                    # decays leave alone, so that a warm decay to a
+                    # middle shape never shuts out a smaller cold one
+                    cold = all(t <= ref * pol.decay_ratio
+                               for t, _ in hist)
+                    warm = peak < hwm and jax_cost.stacked_rows(
+                        peak, mesh=self.mesh) in ran
+                    if cold or warm:
+                        pad_hwm[sig] = peak
+                        if cold:
+                            pad_ref[sig] = peak
+                        hist.clear()
+                        if peak < hwm:
+                            self._pad_decays["cold" if cold
+                                             else "warm"] += 1
+                            trace.count(PAD_DECAYS, sig=sig,
+                                        warm=not cold)
                 wm_hist.setdefault(sig, []).append(pad_hwm[sig])
             for grp, outs in dispatched:
                 # from the group's results to its next batches: the
@@ -1105,7 +1151,10 @@ class MultiSearch:
                 for sig, hist in self._wm_hist.items()},
             pad_policies={
                 sig[2]: dataclasses.asdict(self._pad_policy(sig[2]))
-                for sig in self._wm_hist})
+                for sig in self._wm_hist},
+            # watermark decays to a shape the fleet had run (warm) and
+            # by the decay_ratio test (cold)
+            pad_decays=dict(self._pad_decays))
 
     def finish(self) -> Dict[str, SearchResult]:
         """Stop background compile-ahead work, freeze ``stats``, and

@@ -1,14 +1,17 @@
 """Per-topology pad-watermark policies (MultiSearch) and the CI
 BENCH_sweep.json regression gate (benchmarks/compare_sweep.py)."""
 import copy
+import time
 
 import numpy as np
+import pytest
 
 from benchmarks.compare_sweep import compare, stale_policy_warnings
-from repro.core import search
+from repro.core import jax_cost, search, trace
 from repro.core.arch import ARCH_SPARSEMAP
-from repro.core.search import (MultiSearch, PadPolicy, SearchTask,
-                               pad_policy_for, set_pad_policy)
+from repro.core.search import (PAD_DECAYS, FleetConfig, MultiSearch,
+                               PadPolicy, SearchTask, pad_policy_for,
+                               set_pad_policy)
 from repro.core.workload import spmm
 
 WL_A = spmm("pad_a", 32, 64, 48, 0.2, 0.5)
@@ -68,6 +71,106 @@ def test_pad_policy_override_and_registry():
         assert pad_policy_for("not_registered") == PadPolicy()
     finally:
         search._PAD_POLICIES.pop("deadbeef", None)
+
+
+def _admitting_fleet(pin_floor: int = 0):
+    """One search whose first round pads to 512, two more admitted at
+    step 5 (a 2,048-row spike, then 512 until the first retires, then
+    256), a fourth at step 40 (512 again until it retires); at the end
+    one search is left on 128.  Returns the fleet, its results and one
+    ``(rows, floor, dispatched, compiles)`` record per mega-batch;
+    ``pin_floor`` raises every dispatch's ``pad_floor`` to it."""
+    log = []
+    dispatch = jax_cost.eval_stacked
+
+    def logged(models, batches, pad_floor=0, mesh=None, defer=False):
+        floor = max(pad_floor, pin_floor)
+        rows = sum(len(b) for b in batches)
+        c0 = jax_cost.compilation_count()
+        out = dispatch(models, batches, pad_floor=floor, mesh=mesh,
+                       defer=defer)
+        log.append((rows, pad_floor, jax_cost.stacked_rows(rows, floor),
+                    jax_cost.compilation_count() - c0))
+        return out
+
+    admit = {5: [SearchTask(WL_A, "cloud", budget=10000, seed=0),
+                 SearchTask(WL_B, "cloud", budget=6000, seed=1)],
+             40: [SearchTask(WL_B, "cloud", budget=3000, seed=3)]}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax_cost, "eval_stacked", logged)
+        ms = MultiSearch([SearchTask(WL_B, "cloud", budget=3000, seed=2)],
+                         FleetConfig(stack_batches=True))
+        steps = 0
+        while ms.step():
+            steps += 1
+            for task in admit.get(steps, []):
+                ms.admit(task)
+        res = ms.finish()
+    return ms, res, log
+
+
+@pytest.fixture(scope="module")
+def admitted_spikes():
+    t0 = time.perf_counter()
+    ms, res, log = _admitting_fleet()
+    decays = trace.events(t0, time.perf_counter(), {PAD_DECAYS})
+    _, pinned, pinned_log = _admitting_fleet(pin_floor=2048)
+    return dict(ms=ms, res=res, log=log, decays=decays, pinned=pinned,
+                pinned_log=pinned_log)
+
+
+@pytest.mark.parametrize("case", ["warm_return", "cold_after_warm",
+                                  "cold_waits",
+                                  "results_match_pinned_floor"])
+def test_watermark_falls_back_to_shapes_the_fleet_has_run(admitted_spikes,
+                                                          case):
+    ms, log = admitted_spikes["ms"], admitted_spikes["log"]
+    fp = ARCH_SPARSEMAP.topology.fingerprint
+    assert ms.stats["pad_policies"][fp] == \
+        {"decay_rounds": 2, "decay_ratio": 0.125, "source": "measured"}
+    (wm,) = ms.stats["pad_watermarks"].values()
+    targets = [jax_cost._pad_batch(rows) for rows, _, _, _ in log]
+    spike = targets.index(2048)
+    first_256 = targets.index(256)
+    again = targets.index(512, first_256)       # the step-40 admission
+    back = targets.index(256, again)            # ... has retired
+    if case == "warm_return":
+        # 512 (ran in round 1) and 256 cannot pass the ratio test
+        # against 2,048 and 512 (0.125 of each is 256 and 64), but both
+        # have run, so two quiet rounds after each spike the floor
+        # returns to them, with no new trace
+        assert wm[spike + 1] == 2048 and wm[spike + 2] == 512
+        assert wm[back] == 512 and wm[back + 1] == 256
+        assert all(d == 256 for _, _, d, _ in log[back + 2:])
+        assert all(c == 0 for _, _, _, c in log[back + 1:])
+        assert [e.attrs["warm"] for e in admitted_spikes["decays"]] == \
+            [True, False, True]
+        assert ms.stats["pad_decays"] == {"warm": 2, "cold": 1}
+    elif case == "cold_after_warm":
+        # the warm fall to 512 leaves the ratio test measured against
+        # the 2,048 peak, so 256, never run, still follows by it
+        assert wm[first_256] == 512 and wm[first_256 + 1] == 256
+        assert 256 not in {d for _, _, d, _ in log[:first_256 + 2]}
+    elif case == "cold_waits":
+        # 128 rows' shape never ran and fails the ratio test against
+        # 512, so the floor holds at 256 and nothing is traced
+        low = targets.index(128, back)
+        assert low > back + 2 and len(targets) - low >= 3
+        assert wm[low:] == [256] * (len(wm) - low)
+        assert 128 not in {d for _, _, d, _ in log}
+        assert all(c == 0 for _, _, _, c in log[low:])
+    else:
+        # padding rows are inert: a floor pinned at the peak changes
+        # the dispatched shapes, never a searched statistic
+        pinned = admitted_spikes["pinned"]
+        assert {d for _, _, d, _ in admitted_spikes["pinned_log"]} == \
+            {2048}
+        assert sorted(pinned) == sorted(admitted_spikes["res"])
+        for name, r in admitted_spikes["res"].items():
+            p = pinned[name]
+            assert (r.best_edp, r.evals, r.valid_evals) == \
+                (p.best_edp, p.evals, p.valid_evals)
+            assert np.array_equal(r.history, p.history)
 
 
 # ------------------------------------------------- compare_sweep gate

@@ -221,12 +221,14 @@ def _trace_scan(model, restart: int = 0):
     ``run_segments`` fold), un-jitted, one task, tiny shapes."""
     import jax
 
-    from repro.core.jax_cost import _scan_task_fn, scan_compile_job
+    from repro.core.jax_cost import (_per_task_carry, _scan_task_fn,
+                                     scan_compile_job)
     _, _, structs = scan_compile_job(model, B=_TRACE_B, k=2, n_parents=2,
                                      n_elite=1, genes_per=2, T=1,
                                      restart=restart)
-    fn = _scan_task_fn(model.d, model.n_pad, model.arch.topology,
-                       model.dens_key, 2, 1, 2, restart)
+    fn = _per_task_carry(_scan_task_fn(model.d, model.n_pad,
+                                       model.arch.topology, model.dens_key,
+                                       2, 1, 2, restart))
     return jax.make_jaxpr(fn)(*_zeros_like_structs(structs))
 
 
@@ -236,13 +238,14 @@ def _trace_direct_scan(model):
 
     from repro.core.direct_encoding import DirectValueSpec
     from repro.core.jax_cost import (_direct_scan_task_fn,
+                                     _per_task_carry,
                                      direct_scan_compile_job)
     dspec = DirectValueSpec(model.spec)
     _, _, structs = direct_scan_compile_job(
         model, B=_TRACE_B, k=2, n_parents=2, n_elite=1, genes_per=2,
         T=1, direct_len=dspec.length, n_perm_codes=dspec.n_perm_codes)
-    fn = _direct_scan_task_fn(model.d, model.n_pad, model.arch.topology,
-                              model.dens_key, 2, 1, 2)
+    fn = _per_task_carry(_direct_scan_task_fn(
+        model.d, model.n_pad, model.arch.topology, model.dens_key, 2, 1, 2))
     return jax.make_jaxpr(fn)(*_zeros_like_structs(structs))
 
 
@@ -397,10 +400,10 @@ def check_aot_job(key: Tuple, fn, arg_structs) -> List[Violation]:
                 f"n_children)")
             return out
         T, B, k, n_children = key[5], key[6], key[7], key[8]
-        pop = leaves[0]
-        if pop.shape[0] != T or pop.shape[1] != B:
-            bad(f"{tag} population struct {pop.shape} != (T={T}, B={B}, "
-                f"...) in the key")
+        pops = arg_structs[0]
+        if len(pops) != T or pops[0].shape[0] != B:
+            bad(f"{tag} population structs {len(pops)} x {pops[0].shape} "
+                f"!= (T={T}) x (B={B}, ...) in the key")
         draws = arg_structs[5] if tag.startswith("scan:") else \
             arg_structs[4]
         if not isinstance(draws, dict) or "ab" not in draws:

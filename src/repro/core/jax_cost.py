@@ -117,6 +117,8 @@ PREP_HITS = "fleet.stack_prep.hits"
 PREP_MISSES = "fleet.stack_prep.misses"
 ROWS = "fleet.rows"                 # genome rows sent to the device
 ROWS_PADDED = "fleet.rows_padded"   # padding rows sent beside them
+SCAN_TASKS = "fleet.scan_tasks"     # real tasks of a scan dispatch
+SCAN_BUILDS = "fleet.scan_builds"   # scan programs built in the process
 
 #: the names of the kernel programs, which XLA's module names carry
 #: (``jit_<name>``) and device traces are read by
@@ -217,6 +219,7 @@ _CA_PREFIXES: set = set()       # (sig..., tag) families the pass claims
 _CA_FIRST_ERROR: Optional[str] = None
 _CA_CANCEL = None               # cancel event of the latest worker
 _CA_THREAD: Optional[threading.Thread] = None   # the latest worker
+_SCAN_BUILT: set = set()        # scan program keys built in the process
 
 
 def compile_ahead_counts() -> Tuple[int, int]:
@@ -248,6 +251,17 @@ def _record_ca_error(key: Tuple, exc: BaseException) -> None:
     with _LOCK:
         if _CA_FIRST_ERROR is None:
             _CA_FIRST_ERROR = f"{key}: {type(exc).__name__}: {exc}"
+
+
+def _note_scan_build(key: Tuple, source: str) -> None:
+    """Count the first build in the process of a scan program key in
+    :data:`SCAN_BUILDS`: ``source`` is ``ahead`` (the compile-ahead
+    worker) or ``inline`` (traced, compiled or loaded at its dispatch)."""
+    with _LOCK:
+        if key in _SCAN_BUILT:
+            return
+        _SCAN_BUILT.add(key)
+    trace.count(SCAN_BUILDS, sig=key[:4], slots=key[5], source=source)
 
 
 def _aot_lookup(key: Tuple):
@@ -358,6 +372,8 @@ def compile_ahead(jobs: Sequence[Tuple[Tuple, object, Tuple]],
                     compiled = jit_fn.lower(*arg_structs).compile()
                     with _LOCK:
                         _AOT_FNS[key] = compiled
+                    if str(key[4]).startswith(("scan:", "dscan:")):
+                        _note_scan_build(key, "ahead")
             except Exception as e:
                 # counted, never silent: the dispatch of this key finds no
                 # executable and traces through jit in the caller's thread
@@ -416,6 +432,7 @@ def clear_compile_cache() -> None:
         _AOT_FNS.clear()
         _AOT_PENDING.clear()
         _CA_PREFIXES.clear()
+        _SCAN_BUILT.clear()
     reset_stack_prep_counts()
     reset_dispatch_count()
     reset_compile_ahead_counts()
@@ -1042,6 +1059,21 @@ def _scan_task_fn(d: int, n_pad: int, topo: Topology, dens_key: str,
                     in_axes=(0, 0, 0, 0, 0, 0, 0))
 
 
+def _per_task_carry(vfn: Callable) -> Callable:
+    """The scan program as dispatched: the carry (pop, edp) enters and
+    leaves as one array per task, stacked and split inside the program.
+    Stacking or slicing it outside would run eager XLA ops, whose
+    programs compile anew for every task count and task index.  The
+    stacked final (pop, edp) comes back too: a pipelined driver reads it
+    after the next dispatch has taken (donated) the per-task carries."""
+    def program(pops, edps, *rest):
+        pop_f, edp_f, ys = vfn(jnp.stack(pops), jnp.stack(edps), *rest)
+        n = len(pops)
+        return (pop_f, edp_f, ys, tuple(pop_f[i] for i in range(n)),
+                tuple(edp_f[i] for i in range(n)))
+    return _named(program, SCAN_PROGRAM)
+
+
 def _donate_args() -> Tuple[int, ...]:
     """Donate the scan carry buffers (pop, edp) on accelerators so a
     pipelined fleet's device-resident populations update in place;
@@ -1053,8 +1085,8 @@ def _donate_args() -> Tuple[int, ...]:
 def _scan_fn(d: int, n_pad: int, topo: Topology, dens_key: str,
              n_parents: int, n_elite: int, genes_per: int,
              restart: int = 0):
-    fn = jax.jit(_scan_task_fn(d, n_pad, topo, dens_key, n_parents,
-                               n_elite, genes_per, restart),
+    fn = jax.jit(_per_task_carry(_scan_task_fn(
+        d, n_pad, topo, dens_key, n_parents, n_elite, genes_per, restart)),
                  donate_argnums=_donate_args())
     tag = f"scan:p{n_parents}e{n_elite}g{genes_per}" + (
         f"r{restart}" if restart else "")
@@ -1166,8 +1198,8 @@ def _direct_scan_task_fn(d: int, n_pad: int, topo: Topology,
 @lru_cache(maxsize=32)
 def _direct_scan_fn(d: int, n_pad: int, topo: Topology, dens_key: str,
                     n_parents: int, n_elite: int, genes_per: int):
-    fn = jax.jit(_direct_scan_task_fn(d, n_pad, topo, dens_key,
-                                      n_parents, n_elite, genes_per),
+    fn = jax.jit(_per_task_carry(_direct_scan_task_fn(
+        d, n_pad, topo, dens_key, n_parents, n_elite, genes_per)),
                  donate_argnums=_donate_args())
     with _LOCK:
         _JIT_FNS[(d, n_pad, topo.fingerprint, dens_key,
@@ -1188,8 +1220,8 @@ def _sharded_scan_fn(d: int, n_pad: int, topo: Topology, dens_key: str,
         vfn = _scan_task_fn(d, n_pad, topo, dens_key, n_parents, n_elite,
                             genes_per)
         ax = mesh.axis_names[0]
-        fn = jax.jit(jax.shard_map(vfn, mesh=mesh, in_specs=(P(ax),) * 7,
-                                   out_specs=P(ax)))
+        fn = jax.jit(_per_task_carry(jax.shard_map(
+            vfn, mesh=mesh, in_specs=(P(ax),) * 7, out_specs=P(ax))))
         with _LOCK:
             _SHARD_FNS[key] = fn
             _JIT_FNS[(d, n_pad, topo.fingerprint, dens_key,
@@ -1227,18 +1259,106 @@ def _padded_layout(model: "JaxCostModel") -> PaddedLayout:
     return lay
 
 
+def scan_slots(n: int, cap: Optional[int] = None) -> int:
+    """The task slots a scan dispatch of ``n`` same-shape tasks runs
+    with: the next power of two, or ``cap`` — the most tasks the group
+    can hold, where the caller knows it — when that is smaller.  A
+    served fleet's task count moves with every admission and retirement;
+    the buckets hold the scan programs of one (signature, segment shape)
+    to a few, whatever the traffic."""
+    slots = 1 << max(0, n - 1).bit_length()
+    return cap if cap is not None and n <= cap < slots else slots
+
+
+def _scan_call(sig: Tuple, tag: str, kind: str, fn, tasks: List[Tuple],
+               filler: Callable[[], Tuple], task_rows: int,
+               shape: Tuple[int, int, int], cap: Optional[int] = None,
+               sharded: Optional[Callable] = None, mesh=None) -> Tuple:
+    """Dispatch one scan over ``tasks`` (each task's program inputs:
+    pop, edp, then the rest), its task axis padded to
+    :func:`scan_slots`.  The spare slots take ``filler()``: a real
+    task's inputs with its host-side population, so valid genomes in
+    fresh buffers (the per-task carries are donated); their outputs are
+    never read.  ``shape`` is the AOT key's (B, k, n_children);
+    ``sharded`` builds the mesh program, used when the slots divide
+    over the mesh's devices."""
+    T = len(tasks)
+    slots = scan_slots(T, cap)
+    if slots > T:
+        tasks = tasks + [filler() for _ in range(slots - T)]
+    args = (tuple(jnp.asarray(t[0]) for t in tasks),
+            tuple(jnp.asarray(t[1]) for t in tasks)) + tuple(
+        jax.tree_util.tree_map(lambda *xs: jnp.asarray(np.stack(xs)),
+                               *[t[2:] for t in tasks]))
+    _count_dispatch()
+    trace.count(ROWS, T * task_rows, sig=sig, kind=kind)
+    trace.count(ROWS_PADDED, (slots - T) * task_rows, sig=sig, kind=kind)
+    trace.count(SCAN_TASKS, T, sig=sig, slots=slots)
+    key = sig + (tag, slots) + shape
+    ndev = _mesh_ndev(mesh)
+    if sharded is not None and ndev > 1 and slots % ndev == 0:
+        out = sharded()(*args)
+        _note_scan_build(sig + (f"{tag}@{ndev}", slots) + shape, "inline")
+        return out
+    out = _aot_call(key, fn, args)
+    _note_scan_build(key, "inline")
+    return out
+
+
+def _segment_results(models: Sequence["JaxCostModel"], out: Tuple,
+                     gens_of: Callable, defer: bool,
+                     restart: int = 0) -> List[SegmentResult]:
+    """One SegmentResult per real task of a scan dispatch: each carries
+    its own (pop, edp) output; its ``gens_of(t, model, pop, edp, ys)``
+    reads its slot of the stacked outputs, converted to numpy once for
+    the whole dispatch on the first resolve."""
+    pop_f, edp_f, ys, pops, edps = out
+    host = {}
+
+    def materialize():
+        if "ys" not in host:
+            def conv():
+                return (np.asarray(pop_f), np.asarray(edp_f),
+                        {kk: np.asarray(v) for kk, v in ys.items()})
+            host["pf"], host["ef"], host["ys"] = _time_block(conv)
+        return host["pf"], host["ef"], host["ys"]
+
+    def make_harvest(t, m):
+        return lambda: gens_of(t, m, *materialize())
+
+    results: List[SegmentResult] = []
+    for t, m in enumerate(models):
+        r = SegmentResult(gens=None, final_pop=None, final_edp=None,
+                          carry=(pops[t], edps[t]),
+                          harvest=make_harvest(t, m))
+        if not defer:
+            r.resolve()
+        if restart:
+            _, _, ys_h = materialize()
+            r.state = (float(ys_h["best"][t, 0]),
+                       int(ys_h["since"][t, 0]))
+        results.append(r)
+    return results
+
+
 def run_segments(models: Sequence["JaxCostModel"],
                  segs: Sequence[DeviceSegment],
-                 mesh=None, defer: bool = False) -> List[SegmentResult]:
+                 mesh=None, defer: bool = False,
+                 cap: Optional[int] = None) -> List[SegmentResult]:
     """Execute one DeviceSegment per model as a SINGLE device dispatch:
     all segments (which must share the models' compilation signature and
     the segment shape key) stack along a task axis, and a jitted
     vmap-of-lax.scan advances every task ``k`` generations on-device.
 
+    The task axis is padded to :func:`scan_slots` (``cap``: the most
+    tasks this group can hold, where the caller knows it), so a fleet
+    whose task count changes runs a few programs, not one per count;
+    the spare slots repeat a real task's inputs and are dropped.
+
     Host work per call is limited to padding genomes/plan arrays into
     the shared scan layout and, afterwards, slicing the per-generation
     outputs back per task (``_canonical``-recomputed like every other
-    dispatch path).  With ``mesh`` given and the task count divisible by
+    dispatch path).  With ``mesh`` given and the task slots divisible by
     the device count, tasks shard across devices via ``jax.shard_map``;
     otherwise the single-device program runs unchanged.
 
@@ -1268,121 +1388,79 @@ def run_segments(models: Sequence["JaxCostModel"],
         raise ValueError("run_segments needs one shared segment shape")
     B, k, n_parents, n_elite, genes_per, kind, restart = shape_key
     if kind == "direct":
-        return _run_direct_segments(models, segs, defer=defer)
+        return _run_direct_segments(models, segs, defer=defer, cap=cap)
 
-    with trace.span("fleet.dispatch", sig=sig, kind="scan"):
-        pops, edps, ubs, fmasks, fvals, draw_list = [], [], [], [], [], []
-        n_children = rows = 0
-        for m, s in zip(models, segs):
-            lay = _padded_layout(m)
-            if s.carry is not None:
-                pops.append(jnp.asarray(s.carry[0]))
-                edps.append(jnp.asarray(s.carry[1]))
-            else:
-                pops.append(jnp.asarray(
-                    lay.pad_rows(np.asarray(s.pop, dtype=np.int32))))
-                edps.append(jnp.asarray(
-                    np.asarray(s.edp, dtype=np.float32)))
-            ubs.append(lay.pad_vector(m.spec.gene_ub.astype(np.int32), 1))
-            fm = np.zeros(lay.Lp, dtype=bool)
-            fv = np.zeros(lay.Lp, dtype=np.int32)
-            if s.fixed_genes:
-                idx = lay.pad_index(
-                    np.asarray(list(s.fixed_genes), dtype=np.int64))
-                fm[idx] = True
-                fv[idx] = np.asarray(list(s.fixed_genes.values()),
-                                     dtype=np.int32)
-            fmasks.append(fm)
-            fvals.append(fv)
-            dr = dict(s.draws)
-            dr["gene"] = lay.pad_index(dr["gene"]).astype(np.int32)
-            dr["cuts"] = lay.pad_cut(dr["cuts"]).astype(np.int32)
-            if restart:
-                fr = np.asarray(dr["fresh"], dtype=np.int32)
-                gk, gc = fr.shape[0], fr.shape[1]
-                dr["fresh"] = lay.pad_rows(
-                    fr.reshape(gk * gc, -1)).reshape(gk, gc, -1)
-                dr["best0"] = np.asarray([s.state[0]], dtype=np.float32)
-                dr["since0"] = np.asarray([s.state[1]], dtype=np.int32)
-                rows += k * gc
-            n_children = dr["ab"].shape[1]
-            rows += k * n_children
-            draw_list.append(dr)
-        draws = {kk: jnp.asarray(np.stack([d[kk] for d in draw_list]))
-                 for kk in draw_list[0]}
-        consts = tuple(
-            jnp.asarray(np.stack([np.asarray(m._np_consts[j])
-                                  for m in models]))
-            for j in range(len(models[0]._np_consts)))
-
-        T = len(segs)
-        topo = models[0].arch.topology
-        args = (jnp.stack(pops), jnp.stack(edps),
-                jnp.asarray(np.stack(ubs)), jnp.asarray(np.stack(fmasks)),
-                jnp.asarray(np.stack(fvals)), draws, consts)
-        _count_dispatch()
-        trace.count(ROWS, rows, sig=sig)
-        if mesh is not None and _mesh_ndev(mesh) > 1 and \
-                T % _mesh_ndev(mesh) == 0 and not restart:
-            fn = _sharded_scan_fn(sig[0], sig[1], topo, sig[3], n_parents,
-                                  n_elite, genes_per, mesh)
-            pop_f, edp_f, ys = fn(*args)
+    def inputs(m, s, carry=True):
+        lay = _padded_layout(m)
+        if carry and s.carry is not None:
+            pop, edp = s.carry
         else:
-            fn = _scan_fn(sig[0], sig[1], topo, sig[3], n_parents, n_elite,
-                          genes_per, restart)
-            tag = f"scan:p{n_parents}e{n_elite}g{genes_per}" + (
-                f"r{restart}" if restart else "")
-            key = sig + (tag, T, B, k, n_children)
-            pop_f, edp_f, ys = _aot_call(key, fn, args)
-
-    host = {}
-
-    def materialize():
-        if "ys" not in host:
-            def conv():
-                return (np.asarray(pop_f), np.asarray(edp_f),
-                        {kk: np.asarray(v) for kk, v in ys.items()})
-            host["pf"], host["ef"], host["ys"] = _time_block(conv)
-        return host["pf"], host["ef"], host["ys"]
-
-    def make_harvest(t, m):
-        def harvest():
-            pf, ef, ys_h = materialize()
-            lay = _padded_layout(m)
-            gens = []
-            for g in range(k):
-                kids = lay.unpad_rows(ys_h["kids"][t, g]).astype(np.int64)
-                out = _canonical(dict(valid=ys_h["valid"][t, g],
-                                      energy_pj=ys_h["energy_pj"][t, g],
-                                      cycles=ys_h["cycles"][t, g]))
-                if restart:
-                    out["fresh"] = _canonical(dict(
-                        valid=ys_h["f_valid"][t, g],
-                        energy_pj=ys_h["f_energy_pj"][t, g],
-                        cycles=ys_h["f_cycles"][t, g]))
-                    out["restarted"] = bool(ys_h["restarted"][t, g])
-                gens.append((kids, out))
-            return (gens, lay.unpad_rows(pf[t]).astype(np.int64), ef[t])
-        return harvest
-
-    results: List[SegmentResult] = []
-    for t, m in enumerate(models):
-        r = SegmentResult(gens=None, final_pop=None, final_edp=None,
-                          carry=(pop_f[t], edp_f[t]),
-                          harvest=make_harvest(t, m))
-        if not defer:
-            r.resolve()
+            pop = lay.pad_rows(np.asarray(s.pop, dtype=np.int32))
+            edp = np.asarray(s.edp, dtype=np.float32)
+        fm = np.zeros(lay.Lp, dtype=bool)
+        fv = np.zeros(lay.Lp, dtype=np.int32)
+        if s.fixed_genes:
+            idx = lay.pad_index(
+                np.asarray(list(s.fixed_genes), dtype=np.int64))
+            fm[idx] = True
+            fv[idx] = np.asarray(list(s.fixed_genes.values()),
+                                 dtype=np.int32)
+        dr = dict(s.draws)
+        dr["gene"] = lay.pad_index(dr["gene"]).astype(np.int32)
+        dr["cuts"] = lay.pad_cut(dr["cuts"]).astype(np.int32)
         if restart:
-            _, _, ys_h = materialize()
-            r.state = (float(ys_h["best"][t, 0]),
-                       int(ys_h["since"][t, 0]))
-        results.append(r)
-    return results
+            fr = np.asarray(dr["fresh"], dtype=np.int32)
+            gk, gc = fr.shape[0], fr.shape[1]
+            dr["fresh"] = lay.pad_rows(
+                fr.reshape(gk * gc, -1)).reshape(gk, gc, -1)
+            dr["best0"] = np.asarray([s.state[0]], dtype=np.float32)
+            dr["since0"] = np.asarray([s.state[1]], dtype=np.int32)
+        return (pop, edp, lay.pad_vector(m.spec.gene_ub.astype(np.int32), 1),
+                fm, fv, dr, m._np_consts)
+
+    n_children = segs[0].draws["ab"].shape[1]
+    task_rows = k * n_children
+    if restart:
+        task_rows += k * np.shape(segs[0].draws["fresh"])[1]
+    tag = f"scan:p{n_parents}e{n_elite}g{genes_per}" + (
+        f"r{restart}" if restart else "")
+    topo = models[0].arch.topology
+    with trace.span("fleet.dispatch", sig=sig, kind="scan"):
+        out = _scan_call(
+            sig, tag, "scan",
+            _scan_fn(sig[0], sig[1], topo, sig[3], n_parents, n_elite,
+                     genes_per, restart),
+            [inputs(m, s) for m, s in zip(models, segs)],
+            lambda: inputs(models[0], segs[0], carry=False), task_rows,
+            (B, k, n_children), cap,
+            sharded=None if restart else lambda: _sharded_scan_fn(
+                sig[0], sig[1], topo, sig[3], n_parents, n_elite,
+                genes_per, mesh), mesh=mesh)
+
+    def gens_of(t, m, pf, ef, ys_h):
+        lay = _padded_layout(m)
+        gens = []
+        for g in range(k):
+            kids = lay.unpad_rows(ys_h["kids"][t, g]).astype(np.int64)
+            kout = _canonical(dict(valid=ys_h["valid"][t, g],
+                                   energy_pj=ys_h["energy_pj"][t, g],
+                                   cycles=ys_h["cycles"][t, g]))
+            if restart:
+                kout["fresh"] = _canonical(dict(
+                    valid=ys_h["f_valid"][t, g],
+                    energy_pj=ys_h["f_energy_pj"][t, g],
+                    cycles=ys_h["f_cycles"][t, g]))
+                kout["restarted"] = bool(ys_h["restarted"][t, g])
+            gens.append((kids, kout))
+        return gens, lay.unpad_rows(pf[t]).astype(np.int64), ef[t]
+
+    return _segment_results(models, out, gens_of, defer, restart)
 
 
 def _run_direct_segments(models: Sequence["JaxCostModel"],
                          segs: Sequence[DeviceSegment],
-                         defer: bool = False) -> List[SegmentResult]:
+                         defer: bool = False,
+                         cap: Optional[int] = None) -> List[SegmentResult]:
     """:func:`run_segments` for ``kind == "direct"`` segments: the carry
     population lives in DIRECT value coordinates and the in-scan
     translation (see ``_direct_scan_task_fn``) produces the canonical
@@ -1396,75 +1474,40 @@ def _run_direct_segments(models: Sequence["JaxCostModel"],
     if restart:
         raise ValueError("direct segments do not support in-scan restart")
 
+    def inputs(m, s, carry=True):
+        if carry and s.carry is not None:
+            pop, edp = s.carry
+        else:
+            pop = np.asarray(s.pop, dtype=np.int32)
+            edp = np.asarray(s.edp, dtype=np.float32)
+        return (pop, edp, np.asarray(s.aux["scramble"], dtype=np.int32),
+                np.asarray(s.aux["dim_sizes"], dtype=np.float32),
+                {kk: np.asarray(v) for kk, v in s.draws.items()},
+                m._np_consts)
+
+    n_children = segs[0].draws["ab"].shape[1]
+    topo = models[0].arch.topology
     with trace.span("fleet.dispatch", sig=sig, kind="dscan"):
-        pops, edps, scrs, dims, draw_list = [], [], [], [], []
-        n_children = 0
-        for m, s in zip(models, segs):
-            if s.carry is not None:
-                pops.append(jnp.asarray(s.carry[0]))
-                edps.append(jnp.asarray(s.carry[1]))
-            else:
-                pops.append(jnp.asarray(np.asarray(s.pop, dtype=np.int32)))
-                edps.append(jnp.asarray(np.asarray(s.edp, dtype=np.float32)))
-            scrs.append(np.asarray(s.aux["scramble"], dtype=np.int32))
-            dims.append(np.asarray(s.aux["dim_sizes"], dtype=np.float32))
-            dr = {kk: np.asarray(v) for kk, v in s.draws.items()}
-            n_children = dr["ab"].shape[1]
-            draw_list.append(dr)
-        draws = {kk: jnp.asarray(np.stack([d[kk] for d in draw_list]))
-                 for kk in draw_list[0]}
-        consts = tuple(
-            jnp.asarray(np.stack([np.asarray(m._np_consts[j])
-                                  for m in models]))
-            for j in range(len(models[0]._np_consts)))
+        out = _scan_call(
+            sig, f"dscan:p{n_parents}e{n_elite}g{genes_per}", "dscan",
+            _direct_scan_fn(sig[0], sig[1], topo, sig[3], n_parents,
+                            n_elite, genes_per),
+            [inputs(m, s) for m, s in zip(models, segs)],
+            lambda: inputs(models[0], segs[0], carry=False),
+            k * n_children, (B, k, n_children), cap)
 
-        T = len(segs)
-        topo = models[0].arch.topology
-        fn = _direct_scan_fn(sig[0], sig[1], topo, sig[3], n_parents,
-                             n_elite, genes_per)
-        key = sig + (f"dscan:p{n_parents}e{n_elite}g{genes_per}",
-                     T, B, k, n_children)
-        _count_dispatch()
-        trace.count(ROWS, T * k * n_children, sig=sig)
-        pop_f, edp_f, ys = _aot_call(
-            key, fn, (jnp.stack(pops), jnp.stack(edps),
-                      jnp.asarray(np.stack(scrs)), jnp.asarray(np.stack(dims)),
-                      draws, consts))
+    def gens_of(t, m, pf, ef, ys_h):
+        lay = _padded_layout(m)
+        gens = []
+        for g in range(k):
+            kids = lay.unpad_rows(ys_h["canon"][t, g]).astype(np.int64)
+            kout = _canonical(dict(valid=ys_h["valid"][t, g],
+                                   energy_pj=ys_h["energy_pj"][t, g],
+                                   cycles=ys_h["cycles"][t, g]))
+            gens.append((kids, kout))
+        return gens, pf[t].astype(np.int64), ef[t]
 
-    host = {}
-
-    def materialize():
-        if "ys" not in host:
-            def conv():
-                return (np.asarray(pop_f), np.asarray(edp_f),
-                        {kk: np.asarray(v) for kk, v in ys.items()})
-            host["pf"], host["ef"], host["ys"] = _time_block(conv)
-        return host["pf"], host["ef"], host["ys"]
-
-    def make_harvest(t, m):
-        def harvest():
-            pf, ef, ys_h = materialize()
-            lay = _padded_layout(m)
-            gens = []
-            for g in range(k):
-                kids = lay.unpad_rows(
-                    ys_h["canon"][t, g]).astype(np.int64)
-                out = _canonical(dict(valid=ys_h["valid"][t, g],
-                                      energy_pj=ys_h["energy_pj"][t, g],
-                                      cycles=ys_h["cycles"][t, g]))
-                gens.append((kids, out))
-            return gens, pf[t].astype(np.int64), ef[t]
-        return harvest
-
-    results: List[SegmentResult] = []
-    for t, m in enumerate(models):
-        r = SegmentResult(gens=None, final_pop=None, final_edp=None,
-                          carry=(pop_f[t], edp_f[t]),
-                          harvest=make_harvest(t, m))
-        if not defer:
-            r.resolve()
-        results.append(r)
-    return results
+    return _segment_results(models, out, gens_of, defer)
 
 
 # ---------------------------------------------------------------- wrapper
@@ -1593,8 +1636,8 @@ class JaxCostModel:
                                      np.int32)],
                         axis=0) for a in (perm, til, fmt, sg))
             _count_dispatch()
-            trace.count(ROWS, n, sig=sig)
-            trace.count(ROWS_PADDED, padded - n, sig=sig)
+            trace.count(ROWS, n, sig=sig, kind="bcast")
+            trace.count(ROWS_PADDED, padded - n, sig=sig, kind="bcast")
             out = _aot_call(
                 sig + ("bcast", padded), self._fn,
                 (jnp.asarray(perm), jnp.asarray(til),
@@ -1782,8 +1825,8 @@ def eval_stacked(models: Sequence["JaxCostModel"],
             ins.append(arr)
         consts = _stacked_consts(models, sizes, padded)
         _count_dispatch()
-        trace.count(ROWS, total, sig=sig)
-        trace.count(ROWS_PADDED, padded - total, sig=sig)
+        trace.count(ROWS, total, sig=sig, kind="stacked")
+        trace.count(ROWS_PADDED, padded - total, sig=sig, kind="stacked")
         args = tuple(jnp.asarray(a) for a in ins) + \
             tuple(jnp.asarray(c) for c in consts)
         if ndev > 1:
@@ -1856,11 +1899,18 @@ def _seg_consts_structs(model: "JaxCostModel", T: int) -> Tuple:
                  for c in model._np_consts)
 
 
+def _carry_structs(T: int, B: int, width: int) -> Tuple:
+    S = jax.ShapeDtypeStruct
+    return (tuple(S((B, width), np.int32) for _ in range(T)),
+            tuple(S((B,), np.float32) for _ in range(T)))
+
+
 def scan_compile_job(model: "JaxCostModel", B: int, k: int,
                      n_parents: int, n_elite: int, genes_per: int,
                      T: int, restart: int = 0) -> Tuple:
-    """AOT job for one ``run_segments`` ES-scan shape (``T`` same-shape
-    tasks of ``B`` genomes advanced ``k`` generations)."""
+    """AOT job for one ``run_segments`` ES-scan shape (``T`` task slots,
+    a :func:`scan_slots` bucket, of ``B`` genomes advanced ``k``
+    generations)."""
     sig = model.signature
     fn = _scan_fn(sig[0], sig[1], model.arch.topology, sig[3],
                   n_parents, n_elite, genes_per, restart)
@@ -1874,10 +1924,10 @@ def scan_compile_job(model: "JaxCostModel", B: int, k: int,
         draws["since0"] = S((T, 1), np.int32)
     tag = f"scan:p{n_parents}e{n_elite}g{genes_per}" + (
         f"r{restart}" if restart else "")
-    args = (S((T, B, lay.Lp), np.int32), S((T, B), np.float32),
-            S((T, lay.Lp), np.int32), S((T, lay.Lp), np.bool_),
-            S((T, lay.Lp), np.int32), draws,
-            _seg_consts_structs(model, T))
+    args = _carry_structs(T, B, lay.Lp) + (
+        S((T, lay.Lp), np.int32), S((T, lay.Lp), np.bool_),
+        S((T, lay.Lp), np.int32), draws,
+        _seg_consts_structs(model, T))
     return sig + (tag, T, B, k, n_children), fn, args
 
 
@@ -1892,9 +1942,9 @@ def direct_scan_compile_job(model: "JaxCostModel", B: int, k: int,
                          n_parents, n_elite, genes_per)
     n_children = B - n_elite
     S = jax.ShapeDtypeStruct
-    args = (S((T, B, direct_len), np.int32), S((T, B), np.float32),
-            S((T, n_perm_codes), np.int32), S((T, model.d), np.float32),
-            _draw_structs(T, k, n_children, genes_per),
-            _seg_consts_structs(model, T))
+    args = _carry_structs(T, B, direct_len) + (
+        S((T, n_perm_codes), np.int32), S((T, model.d), np.float32),
+        _draw_structs(T, k, n_children, genes_per),
+        _seg_consts_structs(model, T))
     return (sig + (f"dscan:p{n_parents}e{n_elite}g{genes_per}",
                    T, B, k, n_children), fn, args)

@@ -453,6 +453,8 @@ class _TaskState:
     req: Optional[np.ndarray] = None
     extras: Optional[Dict] = None
     phase: str = ""                  # the tracker's phase last seen
+    # signature + segment shape of the scans it will run (None: none)
+    scan_key: Optional[Tuple] = None
 
     @property
     def signature(self) -> Tuple[int, int, str]:
@@ -581,6 +583,9 @@ class MultiSearch:
         # each task's ``es.phase`` trace marks carry its id
         self.trace_ids: Dict[str, object] = {}
         self._started = False
+        # set by run(): no task joins later, so a scan group's size at
+        # the start is the most tasks its dispatches can hold
+        self._fixed_tasks = False
 
     @staticmethod
     def _as_task(t) -> SearchTask:
@@ -620,11 +625,14 @@ class MultiSearch:
         signature group, or per-task broadcast), the registered
         pad-watermark shapes of each topology (the steady-state
         mega-batch sizes a committed baseline measured), and the scan /
-        direct-scan programs of segment-foldable tasks.  Predictions are
+        direct-scan programs of segment-foldable tasks at every task-slot
+        bucket (``jax_cost.scan_slots``) up to each group's size; a
+        running fleet queues more as admissions grow a group
+        (:meth:`admit`).  Predictions are
         conservative: a signature group whose round-1 rows cannot all be
         predicted contributes NO job (its family stays unclaimed, so jit
         fallbacks there never count as compile-ahead misses)."""
-        from .baselines import round1_rows, segment_plan, steady_rows
+        from .baselines import round1_rows, steady_rows
         # the worker compiles in list order and a racing dispatch WAITS
         # for its queued key, so order jobs by when the fleet needs
         # them: round-1 shapes first, segment scans next (needed right
@@ -692,32 +700,49 @@ class MultiSearch:
                 if r is not None:
                     add(jax_cost.bcast_compile_job(
                         ev, jax_cost._pad_batch(r)))
-        if self.device_execute:
-            seg_groups: Dict[Tuple, List[Tuple]] = {}
-            for task, kw, spec, ev in infos:
-                plan = segment_plan(task.method, spec, task.budget,
-                                    task.seed, **kw)
-                if plan is not None:
-                    key = ev.signature + tuple(sorted(plan.items()))
-                    seg_groups.setdefault(key, []).append(
-                        (plan, spec, ev))
-            for key in sorted(seg_groups, key=repr):
-                grp = seg_groups[key]
-                plan, spec, ev = grp[0]
-                T = len(grp)
-                if plan["kind"] == "direct":
-                    from .direct_encoding import DirectValueSpec
-                    dspec = DirectValueSpec(spec)
-                    add(jax_cost.direct_scan_compile_job(
-                        ev, plan["B"], plan["rounds"], plan["n_parents"],
-                        plan["n_elite"], plan["genes_per"], T,
-                        dspec.length, dspec.n_perm_codes))
-                else:
-                    add(jax_cost.scan_compile_job(
-                        ev, plan["B"], plan["rounds"], plan["n_parents"],
-                        plan["n_elite"], plan["genes_per"], T,
-                        restart=plan["restart"]))
+        # every task-slot bucket the group's scans can run at, so that
+        # no count of tasks the traffic leaves compiles at dispatch
+        seg_groups: Dict[Tuple, List[Tuple]] = {}
+        for task, kw, spec, ev in infos:
+            key = self._scan_key(task, kw, spec, ev)
+            if key is not None:
+                seg_groups.setdefault(key, []).append(ev)
+        for key in sorted(seg_groups, key=repr):
+            n = len(seg_groups[key])
+            cap = n if self._fixed_tasks else None
+            for slots in sorted({jax_cost.scan_slots(t, cap)
+                                 for t in range(1, n + 1)}):
+                add(self._scan_job(key, seg_groups[key][0], slots))
         return jobs + late
+
+    def _scan_key(self, task: SearchTask, kw: Dict, spec,
+                  ev) -> Optional[Tuple]:
+        """The signature + segment shape (``es_ops.segment_shape_key``'s
+        fields) of the scans a task will dispatch, or None when it will
+        dispatch none."""
+        from .baselines import segment_plan
+        if not self.device_execute:
+            return None
+        plan = segment_plan(task.method, spec, task.budget, task.seed,
+                            **kw)
+        if plan is None:
+            return None
+        return ev.signature + tuple(plan[f] for f in (
+            "B", "rounds", "n_parents", "n_elite", "genes_per", "kind",
+            "restart"))
+
+    @staticmethod
+    def _scan_job(key: Tuple, ev, slots: int) -> Tuple:
+        """The AOT job of one scan group's program at ``slots`` tasks."""
+        B, k, n_parents, n_elite, genes_per, kind, restart = key[4:]
+        if kind == "direct":
+            from .direct_encoding import DirectValueSpec
+            dspec = DirectValueSpec(ev.spec)
+            return jax_cost.direct_scan_compile_job(
+                ev, B, k, n_parents, n_elite, genes_per, slots,
+                dspec.length, dspec.n_perm_codes)
+        return jax_cost.scan_compile_job(ev, B, k, n_parents, n_elite,
+                                         genes_per, slots, restart=restart)
 
     def _advance(self, st: _TaskState, out: Dict) -> bool:
         """Send an evaluation to a task's generator; False when done.
@@ -804,7 +829,8 @@ class MultiSearch:
                 natural=(task.workload.ndims,
                          _bucket(max(len(task.workload.prime_factors),
                                      1))),
-                method=task.method))
+                method=task.method,
+                scan_key=self._scan_key(task, kw, spec, ev)))
 
         self._ca0 = jax_cost.compile_ahead_counts()
         self._ca_errors0 = jax_cost.compile_ahead_errors()[0]
@@ -815,8 +841,12 @@ class MultiSearch:
         # first dispatch of each shape.  Called with no jobs too: every
         # fleet retires the previous fleet's worker and claims its own
         # families (none when compile-ahead is off)
-        jax_cost.compile_ahead(
-            self._compile_ahead_jobs(infos) if self.compile_ahead else [])
+        self._ca_jobs = self._compile_ahead_jobs(infos) \
+            if self.compile_ahead else []
+        jax_cost.compile_ahead(self._ca_jobs)
+        self._scan_caps = Counter(st.scan_key for st in states
+                                  if st.scan_key is not None) \
+            if self._fixed_tasks else {}
 
         # group same-signature tasks so they share warm compilations (and,
         # when stacking, one mega-batch); stable within a signature
@@ -855,9 +885,10 @@ class MultiSearch:
         density mode — incumbents are never re-padded, so their warm
         compilations survive — and joins the group's mega-batch on the
         next :meth:`step`.  Returns the resolved (collision-suffixed)
-        task name.  Compile-ahead prediction covers only the starting
-        fleet; an admitted task with a novel signature jit-compiles on
-        first dispatch."""
+        task name.  Compile-ahead prediction covers the starting fleet,
+        and the scan programs at each task-slot bucket an admission lets
+        a segment group reach; an admitted task with a novel signature
+        jit-compiles its mega-batch shapes on first dispatch."""
         task = self._as_task(task)
         self.start()
         wl = task.workload
@@ -889,7 +920,8 @@ class MultiSearch:
                                      task.budget, task.seed,
                                      **{**kw, **task.runtime_kw})
         st = _TaskState(name=resolved, gen=gen, tracker=tracker, ev=ev,
-                        natural=(d, bucket), method=task.method)
+                        natural=(d, bucket), method=task.method,
+                        scan_key=self._scan_key(task, kw, spec, ev))
         self.tasks.append(task)
         self.final_names.append(resolved)
         self._states.append(st)
@@ -900,7 +932,26 @@ class MultiSearch:
             st.extras = stop.value or {}
             self._done.append(st.name)
         st.phase = st.tracker.phase
+        if self.compile_ahead and st.scan_key is not None:
+            self._compile_scan_slots(st)
         return resolved
+
+    def _compile_scan_slots(self, st: _TaskState) -> None:
+        """Queue the scan programs an admission lets the newcomer's
+        group reach (every bucket up to its live task count) that the
+        fleet has not queued yet, beside the jobs still owed."""
+        live = sum(1 for s in self._alive if s.scan_key == st.scan_key)
+        have = {job[0] for job in self._ca_jobs}
+        new = [job for job in (
+            self._scan_job(st.scan_key, st.ev, slots)
+            for slots in sorted({jax_cost.scan_slots(t)
+                                 for t in range(1, live + 1)}))
+            if job[0] not in have]
+        if new:
+            # first in the queue: the newcomer needs them after its
+            # prologue, sooner than any steady-state shape still owed
+            self._ca_jobs = new + self._ca_jobs
+            jax_cost.compile_ahead(self._ca_jobs)
 
     @property
     def done(self) -> bool:
@@ -973,44 +1024,16 @@ class MultiSearch:
         # one iteration advances segmented tasks by k generations and
         # per-round tasks by 1; the fleet's round clock moves by the
         # largest stride taken this iteration
-        iter_weight = 0
-        if seg_states and self.device_execute:
-            seg_groups: Dict[Tuple, List[_TaskState]] = {}
+        seg_groups: Dict[Tuple, List[_TaskState]] = {}
+        if self.device_execute:
             for st in seg_states:
                 key = st.signature + es_ops.segment_shape_key(st.req)
                 seg_groups.setdefault(key, []).append(st)
-            for key in sorted(seg_groups):
-                grp = seg_groups[key]
-                iter_weight = max(iter_weight, grp[0].req.rounds)
-                # with pipeline=True the SegmentResults come back
-                # unresolved (defer): the generators stash them, yield
-                # the NEXT segment from the device-resident carry, and
-                # only then resolve round N — the blocking conversion
-                # overlaps round N+1's device execution (COMPAT.md
-                # "Pipelined dispatch contract")
-                segres = jax_cost.run_segments(
-                    [s.ev for s in grp], [s.req for s in grp],
-                    mesh=self.mesh, defer=self.pipeline)
-                # the generators resolve the previous segment's harvest
-                # in here: its fleet.block span nests in this one
-                with trace.span("fleet.advance", step=self._host_syncs,
-                                sig=key[:4]):
-                    for st, res in zip(grp, segres):
-                        if self._advance(st, res):
-                            pending.append(st)
-        elif seg_states:
-            # host-loop reference path: the generator replays the
-            # identical pre-drawn plan per-round (its next yield is a
-            # plain batch, so the task rejoins the per-round path)
-            with trace.span("fleet.advance", step=self._host_syncs):
-                for st in seg_states:
-                    if self._advance(st, None):
-                        pending.append(st)
-        if seg_states and self.device_execute:
-            self._seg_syncs += 1
-            self._seg_rounds += iter_weight
+        iter_weight = max((grp[0].req.rounds
+                           for grp in seg_groups.values()), default=0)
         if plain:
             iter_weight = max(iter_weight, 1)
+        dispatched: List[Tuple[List[_TaskState], object]] = []
         if self.stack_batches:
             groups: Dict[Tuple[int, int, str],
                          List[_TaskState]] = {}
@@ -1025,7 +1048,10 @@ class MultiSearch:
             # watermark bookkeeping is value-independent (row counts
             # are known at dispatch), so it stays in dispatch order
             # and pipeline on/off cannot change any padded shape.
-            dispatched: List[Tuple[List[_TaskState], object]] = []
+            # The mega-batches go to the device ahead of this step's
+            # scans: the step waits for them (finalize below), and a
+            # scan queued in front would hold them back by its whole
+            # run, where the next step harvests the scan's results
             for sig in sorted(groups):
                 grp = groups[sig]
                 pol = self._pad_policy(sig[2])
@@ -1069,17 +1095,47 @@ class MultiSearch:
                             trace.count(PAD_DECAYS, sig=sig,
                                         warm=not cold)
                 wm_hist.setdefault(sig, []).append(pad_hwm[sig])
-            for grp, outs in dispatched:
-                # from the group's results to its next batches: the
-                # finalize's fleet.block span nests in this one
-                with trace.span("fleet.advance", step=self._host_syncs,
-                                sig=grp[0].signature):
-                    if isinstance(outs, jax_cost.StackedPending):
-                        outs = outs.finalize()
-                    for st, out in zip(grp, outs):
-                        if self._advance(st, out):
-                            pending.append(st)
-        else:
+        for key in sorted(seg_groups):
+            grp = seg_groups[key]
+            # with pipeline=True the SegmentResults come back
+            # unresolved (defer): the generators stash them, yield
+            # the NEXT segment from the device-resident carry, and
+            # only then resolve round N — the blocking conversion
+            # overlaps round N+1's device execution (COMPAT.md
+            # "Pipelined dispatch contract")
+            segres = jax_cost.run_segments(
+                [s.ev for s in grp], [s.req for s in grp],
+                mesh=self.mesh, defer=self.pipeline,
+                cap=self._scan_caps.get(key))
+            # the generators resolve the previous segment's harvest
+            # in here: its fleet.block span nests in this one
+            with trace.span("fleet.advance", step=self._host_syncs,
+                            sig=key[:4]):
+                for st, res in zip(grp, segres):
+                    if self._advance(st, res):
+                        pending.append(st)
+        if seg_states and not self.device_execute:
+            # host-loop reference path: the generator replays the
+            # identical pre-drawn plan per-round (its next yield is a
+            # plain batch, so the task rejoins the per-round path)
+            with trace.span("fleet.advance", step=self._host_syncs):
+                for st in seg_states:
+                    if self._advance(st, None):
+                        pending.append(st)
+        if seg_groups:
+            self._seg_syncs += 1
+            self._seg_rounds += iter_weight
+        for grp, outs in dispatched:
+            # from the group's results to its next batches: the
+            # finalize's fleet.block span nests in this one
+            with trace.span("fleet.advance", step=self._host_syncs,
+                            sig=grp[0].signature):
+                if isinstance(outs, jax_cost.StackedPending):
+                    outs = outs.finalize()
+                for st, out in zip(grp, outs):
+                    if self._advance(st, out):
+                        pending.append(st)
+        if not self.stack_batches:
             for st in plain:
                 out = st.ev(st.req)
                 with trace.span("fleet.advance", step=self._host_syncs,
@@ -1166,6 +1222,8 @@ class MultiSearch:
         return {st.name: self._result_for(st) for st in self._states}
 
     def run(self) -> Dict[str, SearchResult]:
+        if not self._started:
+            self._fixed_tasks = True
         self.start()
         while self.step():
             pass

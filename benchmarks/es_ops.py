@@ -133,12 +133,12 @@ def bench_operators(pop_size: int = 100, workload_name: str = "mm3"
 def bench_stacked_prep(n_tasks: int = 6, rows_per_task: int = 64,
                        rounds: int = 50) -> Dict[str, float]:
     """Dispatch-prep micro-benchmark for the mega-batch path: time per
-    ``eval_stacked`` prep with the per-(fleet, signature)-epoch constants
-    cache vs rebuilding the tiled constants every round (the pre-cache
-    behavior: np.broadcast_to + concat per model per round)."""
+    ``eval_stacked`` constants prep when the signature's device-resident
+    per-task table is reused vs when it is built and uploaded again (what
+    an admission or a retirement costs)."""
     from repro.configs.paper_workloads import by_name
     from repro.core import jax_cost, search
-    from repro.core.jax_cost import _pad_batch, _stacked_consts
+    from repro.core.jax_cost import _stack_table
 
     wls = [by_name(n) for n in ("mm1", "mm3")]
     models, batches = [], []
@@ -147,21 +147,20 @@ def bench_stacked_prep(n_tasks: int = 6, rows_per_task: int = 64,
         spec, ev = search.get_evaluator(wls[i % len(wls)], "cloud")
         models.append(ev)
         batches.append(spec.random_genomes(rng, rows_per_task))
-    sizes = [len(b) for b in batches]
-    padded = _pad_batch(sum(sizes))
 
     def cached():
-        return _stacked_consts(models, sizes, padded)
+        return _stack_table(models)
 
     def uncached():
-        jax_cost._STACK_CONSTS.clear()
-        return _stacked_consts(models, sizes, padded)
+        jax_cost._STACK_TABLES.clear()
+        return _stack_table(models)
 
-    cached()                                    # warm the epoch entry
+    cached()                                    # upload the table
     cached_cps = _time(cached)
     uncached_cps = _time(uncached)
 
     # end-to-end: full eval_stacked rounds on a steady fleet
+    jax_cost.reset_stack_prep_counts()
     t0 = time.perf_counter()
     for _ in range(rounds):
         jax_cost.eval_stacked(models, batches)
@@ -307,7 +306,8 @@ def main() -> None:
           f"({sp['cached_preps_per_s']:.3g} vs "
           f"{sp['uncached_preps_per_s']:.3g} preps/s), steady round "
           f"{sp['eval_round_seconds'] * 1e3:.2f}ms, "
-          f"hits/misses {sp['prep_hits']}/{sp['prep_misses']}")
+          f"constants table reuses/uploads "
+          f"{sp['prep_hits']}/{sp['prep_misses']}")
     ms = bench_multisearch()
     print(f"multisearch: compiles {ms['multi_compiles']} vs sequential "
           f"{ms['seq_compiles']}, signatures {ms['signatures']} vs "

@@ -355,9 +355,9 @@ def sharded(ndev: int) -> None:
     models = [ev] * len(batches)
     plain = jax_cost.eval_stacked(models, batches)
     pending = jax_cost.eval_stacked(models, batches, mesh=mesh, defer=True)
-    placed = pending._out["cycles"]          # the device arrays, unharvested
+    placed = pending._out    # the (3, rows) device result, unharvested
     shard_devs = [s.device for s in placed.addressable_shards]
-    rows = [s.data.shape[0] for s in placed.addressable_shards]
+    rows = [s.data.shape[1] for s in placed.addressable_shards]
     log(f"sharded eval_stacked: {len(set(shard_devs))} devices, "
         f"rows per device {rows}")
     check(len(set(shard_devs)) == ndev and min(rows) > 0,

@@ -368,7 +368,19 @@ def check_aot_job(key: Tuple, fn, arg_structs) -> List[Violation]:
     if not callable(fn):
         bad("job fn is not callable")
 
-    if tag in ("stacked", "bcast"):
+    if tag == "stacked":
+        if len(key) != 7 or len(leaves) != 2:
+            bad(f"stacked job {key!r} must be sig + (tag, rows, slots) "
+                f"over (row buffer, table), got {len(leaves)} arg leaves")
+            return out
+        rows, table = leaves
+        if rows.shape[0] != key[5] or rows.dtype != np.int32:
+            bad(f"stacked row buffer {rows.shape} {rows.dtype} is not "
+                f"({key[5]}, W) int32 as the key says")
+        if table.shape[0] != key[6] or table.dtype != np.float32:
+            bad(f"stacked table {table.shape} {table.dtype} is not "
+                f"({key[6]}, width) float32 as the key says")
+    elif tag == "bcast":
         padded = key[5]
         if len(leaves) != 13:
             bad(f"{tag} job has {len(leaves)} arg leaves, kernel "
@@ -383,15 +395,11 @@ def check_aot_job(key: Tuple, fn, arg_structs) -> List[Violation]:
         if leaves[1].shape[-1] != n_pad:
             bad(f"{tag} tiling arg width {leaves[1].shape[-1]} != "
                 f"prime bucket {n_pad} in the key")
-        if tag == "stacked":
-            if any(lv.shape[0] != padded for lv in leaves[4:]):
-                bad("stacked consts are not batched to the padded "
-                    "batch in the key")
-        elif any(lv.shape[:1] == (padded,) and lv.ndim > 0
-                 for lv in leaves[4:6]):
+        if any(lv.shape[:1] == (padded,) and lv.ndim > 0
+               for lv in leaves[4:6]):
             # bcast primes/prime_dim are (n_pad,); a padded leading dim
-            # means stacked consts were paired with a bcast key
-            bad("bcast consts look batched — stacked structs under a "
+            # means batched consts were paired with a bcast key
+            bad("bcast consts look batched — per-row structs under a "
                 "bcast key")
     elif isinstance(tag, str) and (tag.startswith("scan:")
                                    or tag.startswith("dscan:")):

@@ -38,7 +38,8 @@ from __future__ import annotations
 import dataclasses
 import threading
 from functools import lru_cache
-from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple, Union)
 
 import jax
 import jax.numpy as jnp
@@ -86,7 +87,8 @@ def _bucket(n: int, size: int = 16) -> int:
 # Registry of live jitted evaluators, keyed by compilation signature
 # (ndims, padded prime count, topology fingerprint, density key, kind)
 # where kind is "bcast" (workload constants broadcast over the batch) or
-# "stacked" (per-row constants, the mega-batch kernel) — used to count
+# "stacked" (each row's constants gathered from a per-task table, the
+# mega-batch kernel) — used to count
 # actual XLA compilations (one per distinct traced argument-shape set per
 # signature).  The density key is "u" for all-uniform workloads (the
 # literal pre-density-model kernel, bit-identical to the goldens) or
@@ -97,7 +99,7 @@ def _bucket(n: int, size: int = 16) -> int:
 _JIT_FNS: Dict[Tuple[int, int, str, str, str], object] = {}
 
 # One reentrant lock guards every module-level registry
-# (_JIT_FNS/_SHARD_FNS/_STACK_CONSTS/_AOT_*) and the reset baselines:
+# (_JIT_FNS/_SHARD_FNS/_STACK_TABLES/_AOT_*) and the reset baselines:
 # the compile-ahead worker mutates them from its background thread while
 # the search thread dispatches, so bare stores are not safe.
 _LOCK = threading.RLock()
@@ -113,8 +115,9 @@ BLOCK = "fleet.block"               # host blocked on device->host copies
 CA_HITS = "compile_ahead.hits"
 CA_MISSES = "compile_ahead.misses"
 CA_ERRORS = "compile_ahead.errors"
-PREP_HITS = "fleet.stack_prep.hits"
-PREP_MISSES = "fleet.stack_prep.misses"
+PREP_HITS = "fleet.stack_prep.hits"       # constants tables reused
+PREP_MISSES = "fleet.stack_prep.misses"   # constants tables uploaded
+TRANSFERS = "fleet.transfers"   # host<->device copies of the stacked path
 ROWS = "fleet.rows"                 # genome rows sent to the device
 ROWS_PADDED = "fleet.rows_padded"   # padding rows sent beside them
 SCAN_TASKS = "fleet.scan_tasks"     # real tasks of a scan dispatch
@@ -420,6 +423,8 @@ except Exception:               # pragma: no cover - future-proofing
 def clear_compile_cache() -> None:
     """Drop all shared jitted evaluators (benchmarking hook)."""
     _jitted_eval.cache_clear()
+    _stacked_program.cache_clear()
+    _stacked_fn.cache_clear()
     _build_eval_one.cache_clear()
     _scan_task_fn.cache_clear()
     _scan_fn.cache_clear()
@@ -428,7 +433,7 @@ def clear_compile_cache() -> None:
     with _LOCK:
         _JIT_FNS.clear()
         _SHARD_FNS.clear()
-        _STACK_CONSTS.clear()
+        _STACK_TABLES.clear()
         _AOT_FNS.clear()
         _AOT_PENDING.clear()
         _CA_PREFIXES.clear()
@@ -902,22 +907,72 @@ def _build_eval_one(d: int, n_primes_pad: int, topo: Topology,
 
 @lru_cache(maxsize=32)
 def _jitted_eval(d: int, n_primes_pad: int, topo: Topology,
-                 dens_key: str = "u", stacked: bool = False):
+                 dens_key: str = "u"):
     """The jitted batch evaluator for (ndims=d, padded prime count,
     topology, density mode): :func:`_build_eval_one` vmapped over the
-    batch axis.
-
-    With ``stacked=False`` the workload/platform quantities are broadcast
-    over the batch (one workload per call); with ``stacked=True`` they are
-    batched per row, so rows belonging to *different* workloads and
-    platforms can be concatenated into one mega-batch and evaluated in a
-    single device dispatch (``eval_stacked``)."""
+    batch axis, the workload/platform quantities broadcast over the batch
+    (one workload per call)."""
     eval_one = _build_eval_one(d, n_primes_pad, topo, dens_key)
-    in_axes = (0,) * 13 if stacked else (0, 0, 0, 0) + (None,) * 9
-    fn = jax.jit(jax.vmap(eval_one, in_axes=in_axes))
+    fn = jax.jit(jax.vmap(eval_one, in_axes=(0, 0, 0, 0) + (None,) * 9))
     with _LOCK:
         _JIT_FNS[(d, n_primes_pad, topo.fingerprint, dens_key,
-                  "stacked" if stacked else "bcast")] = fn
+                  "bcast")] = fn
+    return fn
+
+
+def _split_rows(rows, nl: int, n_pad: int) -> Tuple:
+    """The kernel's (perm, tiling, fmt, sg) inputs as column slices of a
+    packed (B, W) int32 row buffer (:meth:`JaxCostModel._pack`), numpy or
+    jax alike; the last column, the row's task index, is left out."""
+    f = nl + n_pad
+    s = f + 3 * MAX_FMT_GENES
+    return (rows[:, :nl], rows[:, nl:f],
+            rows[:, f:s].reshape(-1, 3, MAX_FMT_GENES), rows[:, s:-1])
+
+
+def _unpack_consts(rows, layout: Tuple) -> List:
+    """The nine per-row kernel constants from the float32 rows of a
+    per-task table (``JaxCostModel._const_row``): each (shape, dtype) of
+    ``layout`` in turn, cast back exactly (small integers and 0/1 flags
+    are exact in float32)."""
+    out, off = [], 0
+    for shape, dtype in layout:
+        size = int(np.prod(shape))
+        out.append(rows[:, off:off + size].reshape((-1,) + shape)
+                   .astype(np.dtype(dtype)))
+        off += size
+    return out
+
+
+@lru_cache(maxsize=32)
+def _stacked_program(d: int, n_pad: int, topo: Topology, dens_key: str,
+                     layout: Tuple) -> Callable:
+    """The un-jitted mega-batch program: ``(rows, table) -> (3, B)``.
+    ``rows`` is the packed int32 row buffer, its last column each row's
+    index into ``table``, the (slots, width) float32 per-task constants
+    table; each row gathers its constants and runs the same vmapped
+    per-row kernel as the broadcast path.  The result rows are ``valid``
+    (0/1), ``energy_pj`` and ``cycles``; ``edp`` and ``log10_edp`` are
+    derived on the host (:func:`_canonical`)."""
+    veval = jax.vmap(_build_eval_one(d, n_pad, topo, dens_key))
+    nl = _topo_tables(topo).n_levels
+
+    def program(rows, table):
+        consts = _unpack_consts(table[rows[:, -1]], layout)
+        out = veval(*_split_rows(rows, nl, n_pad), *consts)
+        return jnp.stack([out["valid"].astype(jnp.float32),
+                          out["energy_pj"], out["cycles"]])
+
+    return _named(program, EVAL_PROGRAM)
+
+
+@lru_cache(maxsize=32)
+def _stacked_fn(d: int, n_pad: int, topo: Topology, dens_key: str,
+                layout: Tuple):
+    """The jitted :func:`_stacked_program` (``eval_stacked``'s kernel)."""
+    fn = jax.jit(_stacked_program(d, n_pad, topo, dens_key, layout))
+    with _LOCK:
+        _JIT_FNS[(d, n_pad, topo.fingerprint, dens_key, "stacked")] = fn
     return fn
 
 
@@ -1231,19 +1286,18 @@ def _sharded_scan_fn(d: int, n_pad: int, topo: Topology, dens_key: str,
 
 
 def _sharded_stacked_fn(d: int, n_pad: int, topo: Topology,
-                        dens_key: str, mesh):
-    """The stacked mega-batch kernel shard_map-ed over batch rows."""
+                        dens_key: str, layout: Tuple, mesh):
+    """The stacked mega-batch program shard_map-ed over batch rows, the
+    per-task table replicated on every device."""
     key = (d, n_pad, topo.fingerprint, dens_key, "stacked",
            _mesh_key(mesh))
     fn = _SHARD_FNS.get(key)
     if fn is None:
         from jax.sharding import PartitionSpec as P
-        eval_one = _build_eval_one(d, n_pad, topo, dens_key)
-        vfn = jax.vmap(eval_one, in_axes=(0,) * 13)
         ax = mesh.axis_names[0]
-        fn = jax.jit(jax.shard_map(vfn, mesh=mesh,
-                                   in_specs=(P(ax),) * 13,
-                                   out_specs=P(ax)))
+        fn = jax.jit(jax.shard_map(
+            _stacked_program(d, n_pad, topo, dens_key, layout), mesh=mesh,
+            in_specs=(P(ax), P()), out_specs=P(None, ax)))
         with _LOCK:
             _SHARD_FNS[key] = fn
             _JIT_FNS[(d, n_pad, topo.fingerprint, dens_key,
@@ -1565,8 +1619,9 @@ class JaxCostModel:
         for i, (dd, p) in enumerate(spec.primes):
             primes[i] = p
             prime_dim[i] = dim_idx[dd]
-        # numpy copies kept for eval_stacked (per-row tiling across a
-        # heterogeneous mega-batch); jnp copies feed the broadcast kernel
+        # numpy copies feed the scans and the AOT structs, jnp copies the
+        # broadcast kernel, and one float32 row of them all the per-task
+        # table of eval_stacked
         self._np_consts = (
             primes,
             prime_dim,
@@ -1586,15 +1641,26 @@ class JaxCostModel:
         (self._primes, self._prime_dim, self._relevance, self._densities,
          self._full_elems, self._total_macs, self._z_onehot, self._plat,
          self._dens_params) = [jnp.asarray(c) for c in self._np_consts]
+        self._const_layout = tuple((np.shape(c), np.asarray(c).dtype.str)
+                                   for c in self._np_consts)
+        self._const_row = np.concatenate(
+            [np.asarray(c, np.float32).reshape(-1)
+             for c in self._np_consts])
+        # the row of eval_stacked's table this model's rows read
+        self._table_key = (wl.cache_key(), self.arch)
 
         self._fn = _jitted_eval(d, self.n_pad, self.arch.topology,
                                 self.dens_key)
+        # genome segment -> first column of the packed row buffer
         s = spec.segments
-        self._sl_perm = (s["perm"].start, s["perm"].stop)
-        self._sl_til = (s["tiling"].start, s["tiling"].stop)
-        self._sl_fmt = [(s[f"fmt_{t.name}"].start, s[f"fmt_{t.name}"].stop)
-                        for t in wl.tensors]
-        self._sl_sg = (s["sg"].start, s["sg"].stop)
+        tt = _topo_tables(self.arch.topology)
+        self._nl = nl = tt.n_levels
+        f = nl + self.n_pad
+        self._pack_cols = [(s["perm"].slice, 0), (s["tiling"].slice, nl)] + [
+            (s[f"fmt_{t.name}"].slice, f + i * MAX_FMT_GENES)
+            for i, t in enumerate(wl.tensors)] + [
+            (s["sg"].slice, f + 3 * MAX_FMT_GENES)]
+        self._row_width = f + 3 * MAX_FMT_GENES + tt.n_sites + 1
 
     @property
     def signature(self) -> Tuple[int, int, str, str]:
@@ -1603,23 +1669,16 @@ class JaxCostModel:
         return (self.d, self.n_pad, self.arch.topology.fingerprint,
                 self.dens_key)
 
-    def _prepare(self, genomes: np.ndarray
-                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-        """Slice a (B, L) genome batch into the kernel's (perm, tiling,
-        fmt, sg) inputs, padding the prime axis to its bucket.  For one
-        compilation signature these arrays have identical trailing shapes
-        across workloads — the property mega-batch stacking relies on."""
-        genomes = np.asarray(genomes, dtype=np.int32)
-        n = len(genomes)
-        perm = genomes[:, self._sl_perm[0]:self._sl_perm[1]]
-        til = genomes[:, self._sl_til[0]:self._sl_til[1]]
-        if self.n_pad != self.n_primes:
-            til = np.concatenate(
-                [til, np.zeros((n, self.n_pad - self.n_primes),
-                               dtype=np.int32)], axis=1)
-        fmt = np.stack([genomes[:, a:b] for a, b in self._sl_fmt], axis=1)
-        sg = genomes[:, self._sl_sg[0]:self._sl_sg[1]]
-        return perm, til, fmt, sg
+    def _pack(self, genomes, out: np.ndarray) -> None:
+        """Write a (B, L) genome batch into ``out``, a zeroed (B, W) slice
+        of a packed int32 row buffer: perm | tiling, zero-padded to the
+        prime bucket | fmt, tensor by tensor | sg | the row's task index
+        (the caller's).  For one compilation signature the columns are
+        the same across workloads — the property mega-batch stacking
+        relies on; :func:`_split_rows` reads them back."""
+        genomes = np.asarray(genomes)
+        for sl, col in self._pack_cols:
+            out[:, col:col + sl.stop - sl.start] = genomes[:, sl]
 
     def __call__(self, genomes) -> Dict[str, np.ndarray]:
         """genomes: (B, L) ints -> dict of (B,) arrays.  Pads the batch to
@@ -1628,13 +1687,9 @@ class JaxCostModel:
         padded = _pad_batch(n)
         sig = self.signature
         with trace.span("fleet.dispatch", sig=sig, kind="bcast"):
-            perm, til, fmt, sg = self._prepare(genomes)
-            if padded != n:
-                perm, til, fmt, sg = (
-                    np.concatenate(
-                        [a, np.zeros((padded - n,) + a.shape[1:],
-                                     np.int32)],
-                        axis=0) for a in (perm, til, fmt, sg))
+            rows = np.zeros((padded, self._row_width), np.int32)
+            self._pack(genomes, rows[:n])
+            perm, til, fmt, sg = _split_rows(rows, self._nl, self.n_pad)
             _count_dispatch()
             trace.count(ROWS, n, sig=sig, kind="bcast")
             trace.count(ROWS_PADDED, padded - n, sig=sig, kind="bcast")
@@ -1669,7 +1724,8 @@ def _canonical(out: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
     kernel's float32 cycles/energy.  XLA is free to fuse the final
     ``cycles * energy`` differently in the broadcast vs stacked kernel
     (observed: 1-ULP drift), so deriving them outside the jit makes every
-    dispatch path bit-identical for the same rows."""
+    dispatch path bit-identical for the same rows.  Keys come back in
+    sorted order, as a jitted program's dict output has them."""
     cycles = out["cycles"]
     energy = out["energy_pj"]
     with np.errstate(over="ignore"):
@@ -1677,24 +1733,37 @@ def _canonical(out: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
         out["log10_edp"] = (np.log10(np.maximum(cycles, 1e-30)) +
                             np.log10(np.maximum(energy, 1e-30))
                             ).astype(cycles.dtype)
-    return out
+    return dict(sorted(out.items()))
 
 
-# ----------------------------------------------- stacked-constants cache
+# ------------------------------------------------- per-task constants table
 
-# eval_stacked used to re-tile every model's workload constants across its
-# rows (np.broadcast_to + concat) on EVERY round; for a steady fleet the
-# (models, row-counts, padded shape) triple is identical round after
-# round, so the concatenated constants are cached per signature (one
-# epoch slot each) and rebuilt only when the fleet composition or
-# mega-batch shape changes.  Epoch keys are CONTENT (workload cache_key +
-# arch per model), never id(), so a recycled object can't alias a stale
-# entry and no strong model refs need pinning.
-_STACK_CONSTS: Dict[Tuple[int, int, str, str], Tuple[Tuple, List]] = {}
+# eval_stacked's workload/platform constants live on the device: per
+# signature, one float32 table with a row per distinct (workload, arch)
+# of the group (``JaxCostModel._const_row``), gathered per genome row by
+# the row's task index inside the program.  The table is uploaded again
+# only when the group's set of tasks changes (an admission or a
+# retirement), never for a change of row counts or padding.  Keys are
+# CONTENT (workload cache_key + arch), never id(), so a recycled object
+# can't alias a stale table.  Its slots are a power of two, at least
+# TABLE_FLOOR and never shrinking per signature, so a served group's
+# table keeps one shape whatever its make-up and compile-ahead can build
+# it (:func:`table_slots`).
+_STACK_TABLES: Dict[Tuple[int, int, str, str], "_Table"] = {}
+
+#: the fewest slots a per-task table has: one shape for every group of
+#: up to this many distinct tasks
+TABLE_FLOOR = 16
+
+
+class _Table(NamedTuple):
+    row_of: Dict[Tuple, int]    # (cache_key, arch) -> table row
+    dev: jax.Array              # (slots, width) float32, on the device
 
 
 def stack_prep_counts() -> Tuple[int, int]:
-    """(cache hits, cache misses) of the stacked-constants prep cache."""
+    """(table reuses, table uploads) of the per-task constants tables
+    :func:`eval_stacked` keeps on the device."""
     return int(_since_reset(PREP_HITS)), int(_since_reset(PREP_MISSES))
 
 
@@ -1702,38 +1771,48 @@ def reset_stack_prep_counts() -> None:
     _reset(PREP_HITS, PREP_MISSES)
 
 
-def _stacked_consts(models: Sequence["JaxCostModel"],
-                    sizes: Sequence[int], padded: int) -> List[np.ndarray]:
+def table_slots(sig: Tuple, n: int) -> int:
+    """The slots of ``sig``'s per-task table for a group of ``n``
+    distinct tasks: the next power of two, at least :data:`TABLE_FLOOR`
+    and at least the slots the signature's table already has."""
+    slots = max(TABLE_FLOOR, 1 << max(0, n - 1).bit_length())
+    with _LOCK:
+        held = _STACK_TABLES.get(sig)
+    return slots if held is None else max(slots, held.dev.shape[0])
+
+
+def _stack_table(models: Sequence["JaxCostModel"]) -> _Table:
+    """The device table of the group's per-task constants: the one held
+    for the signature when it holds the same tasks (counted a reuse),
+    else a new one uploaded in one copy (counted an upload)."""
     sig = models[0].signature
-    key = (tuple((m.spec.workload.cache_key(), m.arch) for m in models),
-           tuple(sizes), padded)
+    first: Dict[Tuple, "JaxCostModel"] = {}
+    for m in models:
+        first.setdefault(m._table_key, m)
     with _LOCK:
-        hit = _STACK_CONSTS.get(sig)
-    if hit is not None and hit[0] == key:
+        held = _STACK_TABLES.get(sig)
+    if held is not None and held.row_of.keys() == first.keys():
         trace.count(PREP_HITS)
-        return hit[1]
+        return held
     trace.count(PREP_MISSES)
-    consts: List[np.ndarray] = []
-    for j in range(len(models[0]._np_consts)):
-        rows = [np.broadcast_to(m._np_consts[j],
-                                (n,) + np.shape(m._np_consts[j]))
-                for m, n in zip(models, sizes)]
-        total = sum(sizes)
-        if padded != total:
-            rows.append(np.broadcast_to(
-                models[0]._np_consts[j],
-                (padded - total,) + np.shape(models[0]._np_consts[j])))
-        consts.append(np.ascontiguousarray(np.concatenate(rows, axis=0)))
+    rows = np.empty((table_slots(sig, len(first)),
+                     models[0]._const_row.size), np.float32)
+    rows[:] = models[0]._const_row          # unused slots: never read
+    for i, m in enumerate(first.values()):
+        rows[i] = m._const_row
+    table = _Table({k: i for i, k in enumerate(first)}, jnp.asarray(rows))
+    trace.count(TRANSFERS, dir="h2d", what="table")
     with _LOCK:
-        _STACK_CONSTS[sig] = (key, consts)
-    return consts
+        _STACK_TABLES[sig] = table
+    return table
 
 
 class StackedPending:
     """Handle to an in-flight ``eval_stacked(..., defer=True)`` dispatch:
     the device is computing when this is constructed, and ``finalize()``
-    blocks (charged to :func:`host_blocked_s`), canonicalizes, and slices
-    the mega-batch back per task.  ``finalize`` is idempotent."""
+    blocks (charged to :func:`host_blocked_s`) on the one copy of the
+    (3, padded) result back, canonicalizes, and slices the mega-batch
+    back per task.  ``finalize`` is idempotent."""
 
     def __init__(self, out, sizes: Sequence[int]):
         self._out = out
@@ -1743,8 +1822,10 @@ class StackedPending:
     def finalize(self) -> List[Dict[str, np.ndarray]]:
         if self._sliced is None:
             out = self._out
-            flat = _canonical(_time_block(
-                lambda: {k: np.asarray(v) for k, v in out.items()}))
+            res = _time_block(lambda: np.asarray(out))
+            trace.count(TRANSFERS, dir="d2h", what="out")
+            flat = _canonical(dict(valid=res[0] != 0, energy_pj=res[1],
+                                   cycles=res[2]))
             sliced: List[Dict[str, np.ndarray]] = []
             off = 0
             for n in self._sizes:
@@ -1773,27 +1854,31 @@ def eval_stacked(models: Sequence["JaxCostModel"],
     """Evaluate several (model, genome-batch) pairs sharing one
     compilation signature in a SINGLE device dispatch.
 
-    The batches are concatenated along the batch axis, each model's
-    workload/platform constants are tiled across its rows, and the
-    stacked-constants kernel variant runs once on the padded mega-batch;
-    the output dict is then sliced back per input pair.  Rows are
-    evaluated by exactly the same per-row computation as the broadcast
-    kernel, so results are bit-identical to per-model calls.  The tiled
-    constants are cached per (fleet, signature) epoch — see
-    :func:`stack_prep_counts` — so a steady fleet pays the
-    broadcast+concat prep only when its composition changes.
+    The batches are packed into one zeroed (padded, W) int32 row buffer
+    (perm | tiling | fmt | sg | task index, see
+    :meth:`JaxCostModel._pack`) and sent in one host-to-device copy; each
+    row gathers its workload/platform constants from the signature's
+    per-task table on the device (:func:`_stack_table`, uploaded again
+    only when the group's set of tasks changes — see
+    :func:`stack_prep_counts`); the program returns one (3, padded)
+    float32 array (valid, energy_pj, cycles), fetched in one copy and
+    sliced back per input pair.  Rows are evaluated by exactly the same
+    per-row computation as the broadcast kernel, so results are
+    bit-identical to per-model calls.  Each copy counts in the recorder's
+    :data:`TRANSFERS` (``dir`` h2d/d2h, ``what`` rows/table/out).
 
     ``pad_floor`` raises the batch padding beyond the power-of-two rule —
     drivers pass the watermark of earlier rounds so a shrinking fleet
     keeps hitting an already-compiled mega-batch shape instead of tracing
-    a new one (padding rows are zero genomes, sliced off).
+    a new one (padding rows are zero genomes reading the first model's
+    constants, sliced off).
 
     ``mesh`` shards the padded rows across the mesh's devices via
-    ``jax.shard_map`` (rows are further padded to a device-count
-    multiple — a no-op for the usual power-of-two shapes);
+    ``jax.shard_map``, the table replicated (rows are further padded to a
+    device-count multiple — a no-op for the usual power-of-two shapes);
     with ``mesh=None`` (or one device) the single-device path runs
     unchanged, and per-row results are identical either way because both
-    wrap the same per-row kernel.
+    wrap the same program.
 
     ``defer=True`` returns a :class:`StackedPending` instead of the
     sliced list: the dispatch has been issued (JAX async dispatch keeps
@@ -1813,30 +1898,30 @@ def eval_stacked(models: Sequence["JaxCostModel"],
     total = sum(sizes)
     padded = stacked_rows(total, pad_floor, mesh)
     ndev = _mesh_ndev(mesh)
+    m0 = models[0]
     with trace.span("fleet.dispatch", sig=sig, kind="stacked"):
-        preps = [m._prepare(b) for m, b in zip(models, batches)]
-        ins = []
-        for cols in zip(*preps):
-            arr = np.concatenate(cols, axis=0)
-            if padded != total:
-                arr = np.concatenate(
-                    [arr, np.zeros((padded - total,) + arr.shape[1:],
-                                   np.int32)], axis=0)
-            ins.append(arr)
-        consts = _stacked_consts(models, sizes, padded)
+        table = _stack_table(models)
+        rows = np.zeros((padded, m0._row_width), np.int32)
+        off = 0
+        for m, b in zip(models, batches):
+            m._pack(b, rows[off:off + len(b)])
+            rows[off:off + len(b), -1] = table.row_of[m._table_key]
+            off += len(b)
+        rows[total:, -1] = table.row_of[m0._table_key]
         _count_dispatch()
         trace.count(ROWS, total, sig=sig, kind="stacked")
         trace.count(ROWS_PADDED, padded - total, sig=sig, kind="stacked")
-        args = tuple(jnp.asarray(a) for a in ins) + \
-            tuple(jnp.asarray(c) for c in consts)
+        args = (jnp.asarray(rows), table.dev)
+        trace.count(TRANSFERS, dir="h2d", what="rows")
         if ndev > 1:
-            fn = _sharded_stacked_fn(sig[0], sig[1],
-                                     models[0].arch.topology, sig[3], mesh)
+            fn = _sharded_stacked_fn(sig[0], sig[1], m0.arch.topology,
+                                     sig[3], m0._const_layout, mesh)
             out = fn(*args)
         else:
-            fn = _jitted_eval(sig[0], sig[1], models[0].arch.topology,
-                              sig[3], stacked=True)
-            out = _aot_call(sig + ("stacked", padded), fn, args)
+            fn = _stacked_fn(sig[0], sig[1], m0.arch.topology, sig[3],
+                             m0._const_layout)
+            out = _aot_call(sig + ("stacked", padded,
+                                   table.dev.shape[0]), fn, args)
         pending = StackedPending(out, sizes)
     if defer:
         return pending
@@ -1862,16 +1947,20 @@ def _row_structs(model: "JaxCostModel", padded: int) -> Tuple:
             S((padded, tt.n_sites), np.int32))
 
 
-def stacked_compile_job(model: "JaxCostModel", padded: int) -> Tuple:
-    """AOT job for one ``eval_stacked`` mega-batch shape."""
+def stacked_compile_job(model: "JaxCostModel", padded: int,
+                        slots: Optional[int] = None) -> Tuple:
+    """AOT job for one ``eval_stacked`` mega-batch shape: ``padded``
+    packed rows against a per-task table of ``slots`` rows (by default
+    the slots :func:`table_slots` gives a one-task group now)."""
     sig = model.signature
-    fn = _jitted_eval(sig[0], sig[1], model.arch.topology, sig[3],
-                      stacked=True)
+    if slots is None:
+        slots = table_slots(sig, 1)
+    fn = _stacked_fn(sig[0], sig[1], model.arch.topology, sig[3],
+                     model._const_layout)
     S = jax.ShapeDtypeStruct
-    consts = tuple(S((padded,) + np.shape(np.asarray(c)),
-                     np.asarray(c).dtype) for c in model._np_consts)
-    return (sig + ("stacked", padded), fn,
-            _row_structs(model, padded) + consts)
+    return (sig + ("stacked", padded, slots), fn,
+            (S((padded, model._row_width), np.int32),
+             S((slots, model._const_row.size), np.float32)))
 
 
 def bcast_compile_job(model: "JaxCostModel", padded: int) -> Tuple:

@@ -667,10 +667,13 @@ class MultiSearch:
             for sig in sorted(by_sig):
                 idx = by_sig[sig]
                 model = infos[idx[0]][3]
+                # the group's per-task constants table: one slot count
+                slots = jax_cost.table_slots(
+                    sig, len({infos[i][3]._table_key for i in idx}))
                 if all(rows[i] is not None for i in idx):
                     total = sum(rows[i] for i in idx)
                     add(jax_cost.stacked_compile_job(
-                        model, jax_cost._pad_batch(total)))
+                        model, jax_cost._pad_batch(total), slots))
                     # decayed steady-state shapes: once round-1 shapes
                     # (calibration / first chunks) age out of the pad
                     # watermark, the mega-batch settles on the sum of
@@ -690,10 +693,11 @@ class MultiSearch:
                                            sum(s[-1] for s in alive)}):
                             if tot > 0:
                                 add(jax_cost.stacked_compile_job(
-                                    model, jax_cost._pad_batch(tot)),
-                                    when=late)
+                                    model, jax_cost._pad_batch(tot),
+                                    slots), when=late)
                     for v in watermarks(sig[2]):
-                        add(jax_cost.stacked_compile_job(model, int(v)),
+                        add(jax_cost.stacked_compile_job(model, int(v),
+                                                         slots),
                             when=late)
         else:
             for (task, kw, spec, ev), r in zip(infos, rows):

@@ -180,10 +180,10 @@ def test_eval_stacked_bitexact_vs_per_model_calls():
 
 
 def test_eval_stacked_caches_tiled_constants_per_fleet_epoch():
-    """The per-row workload constants are rebuilt only when the (models,
-    row-counts, padded shape) fleet epoch changes — repeated rounds of a
-    steady fleet hit the prep cache, and cached rounds stay bit-identical
-    to uncached ones."""
+    """The per-task constants table on the device is uploaded again only
+    when the group's set of tasks changes — repeated rounds of a steady
+    fleet reuse it, and reusing rounds stay bit-identical to uploading
+    ones."""
     a = spmm("prep_a", 32, 64, 48, 0.2, 0.5)
     b = spmm("prep_b", 48, 32, 64, 0.4, 0.3)
     sa, eva = search.get_evaluator(a, "cloud")

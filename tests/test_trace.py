@@ -207,6 +207,19 @@ def test_readers_read_the_served_window(served):
         pytest.approx(1.0, abs=1e-6)
 
 
+def test_transfers_per_batch_reads_the_served_window(served):
+    """Each stacked mega-batch of the served window sends one rows
+    buffer and fetches one result; constants tables go up only as the
+    fleet's set of queries changes, so the ratio stays near 2."""
+    ctx, _, _ = served
+    got = _bench_module("cell").reader("transfers_per_batch", ROOT)(ctx)
+    evs = trace.events(ctx["t_open"], ctx["t_close"],
+                       {jax_cost.TRANSFERS})
+    whats = {(e.attrs["dir"], e.attrs["what"]) for e in evs}
+    assert whats == {("h2d", "rows"), ("h2d", "table"), ("d2h", "out")}
+    assert 2.0 < got < 2.5, got
+
+
 def test_stats_trace_totals_survive_epochs(served):
     _, first, second = served
     assert second["epochs"] == first["epochs"] + 1
@@ -225,7 +238,7 @@ def test_readers_find_nothing_without_the_recorder(monkeypatch):
     monkeypatch.delattr(repro.core, "trace")
     monkeypatch.setitem(sys.modules, "repro.core.trace", None)
     ctx = dict(t_open=0.0, t_close=1.0, window_s=1.0)
-    for m in READERS:
+    for m in READERS + ("transfers_per_batch",):
         assert cell.reader(m, ROOT)(ctx) is None, m
 
 

@@ -29,58 +29,6 @@ from typing import Dict, List, Tuple
 TIME_WARN_RATIO = 1.5
 
 
-def _derive_decay_rounds(trajectory):
-    """Stdlib mirror of ``repro.core.search.derive_pad_policy`` (this
-    gate must not import the package): one-off spike trajectories (step
-    down from the peak, never re-grow) suggest ``decay_rounds=2``,
-    re-growing ones the conservative default 3, and a trajectory that
-    never decayed at all (e.g. a short device-resident fleet that holds
-    one mega-batch size throughout) carries no evidence either way —
-    ``None``, never warned against the registered policy."""
-    traj = list(trajectory)
-    peak = max(traj, default=0)
-    if peak <= 0 or traj[-1] >= peak:
-        return None
-    first_down = next(i for i, v in enumerate(traj) if v < peak
-                      and max(traj[:i], default=0) == peak)
-    regrew = any(b > a for a, b in zip(traj[first_down:],
-                                       traj[first_down + 1:]))
-    return 3 if regrew else 2
-
-
-def stale_policy_warnings(current: dict) -> List[str]:
-    """Warn when a fresh run's watermark trajectory suggests the
-    registered PadPolicy is stale (registration lives in
-    ``repro.configs.archs._BASELINE_PAD_WATERMARKS``), or when a policy
-    still carries ``source="seed"`` — the author-declared placeholder
-    from ``_SEED_PAD_WATERMARKS`` — even though the run just MEASURED
-    the topology's real trajectory and the seed should be promoted."""
-    out: List[str] = []
-    for arec in current.get("archs", []):
-        policies = arec.get("pad_policies", {})
-        for sig_key, traj in arec.get("pad_watermarks", {}).items():
-            fp = sig_key.rsplit("_", 1)[-1]
-            pol = policies.get(fp)
-            if pol is None:
-                continue
-            if pol.get("source") == "seed":
-                out.append(
-                    f"{arec['arch']}: topology {fp} still runs on a "
-                    f"seed pad policy but this run measured trajectory "
-                    f"{traj} — promote the entry from "
-                    f"repro.configs.archs._SEED_PAD_WATERMARKS to "
-                    f"_BASELINE_PAD_WATERMARKS")
-            want = _derive_decay_rounds(traj)
-            if want is not None and want != pol.get("decay_rounds"):
-                out.append(
-                    f"{arec['arch']}: watermark trajectory {traj} for "
-                    f"topology {fp} suggests decay_rounds={want} but the "
-                    f"registered policy has "
-                    f"decay_rounds={pol.get('decay_rounds')} — update "
-                    f"repro.configs.archs._BASELINE_PAD_WATERMARKS")
-    return out
-
-
 def compare(baseline: dict, current: dict) -> Tuple[List[str], List[str]]:
     """(failures, warnings) between two bench_sweep_json records."""
     failures: List[str] = []
@@ -194,7 +142,6 @@ def main(argv=None) -> int:
     with open(args.current) as f:
         current = json.load(f)
     failures, warnings = compare(baseline, current)
-    warnings += stale_policy_warnings(current)
     for w in warnings:
         print(f"WARN: {w}")
     for x in failures:

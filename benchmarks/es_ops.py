@@ -10,7 +10,7 @@ Benchmarks backing the vectorized/concurrent-engine claims:
   must perform FEWER XLA compilations than sequential ``search.run``
   calls (signature alignment) while matching their best-EDP results.
 * ``bench_method_sweep`` — a 2-workload x 3-method fig17-style grid via
-  ``run_method_sweep(stack_batches=True)`` must perform strictly fewer
+  ``run_method_sweep`` must perform strictly fewer
   XLA compilations AND fewer device dispatches per round (one padded
   mega-batch per signature) than the sequential equivalent, while
   matching sequential per-method best-EDP exactly at fixed seeds.
@@ -193,7 +193,7 @@ def bench_device_rounds(budget: int = 2000, seed: int = 0,
         t0 = time.perf_counter()
         grid = search.run_method_sweep(
             ["sparsemap"], wls, "cloud", budget=budget, seed=seed,
-            stack_batches=True, device_rounds=device_rounds,
+            device_rounds=device_rounds,
             device_execute=execute, stats_out=stats)
         dt = time.perf_counter() - t0
         best = {w.name: grid["sparsemap"][w.name].best_edp for w in wls}
@@ -273,8 +273,7 @@ def bench_method_sweep(budget: int = 2000, seed: int = 0
     stats: Dict = {}
     t0 = time.perf_counter()
     grid = search.run_method_sweep(methods, wls, "cloud", budget=budget,
-                                   seed=seed, stack_batches=True,
-                                   stats_out=stats)
+                                   seed=seed, stats_out=stats)
     sweep_s = time.perf_counter() - t0
     sweep_compiles = jax_cost.compilation_count()
 

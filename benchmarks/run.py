@@ -48,7 +48,7 @@ def bench_sweep_json(budget: int, out_path: str = SWEEP_JSON) -> dict:
         search.clear_cache()
         stats: dict = {}
         t0 = time.time()
-        config = search.FleetConfig(stack_batches=True, **fleet_kw)
+        config = search.FleetConfig(**fleet_kw)
         grid = search.run_method_sweep(fleet_methods, fleet_wls, arch,
                                        budget=fleet_budget or budget,
                                        seed=0, stats_out=stats,
@@ -79,12 +79,9 @@ def bench_sweep_json(budget: int, out_path: str = SWEEP_JSON) -> dict:
             pipeline=stats["pipeline"],
             n_devices=stats["devices"],
             signatures=[list(s) for s in stats["signatures"]],
-            # per-topology mega-batch watermark trajectory + the
-            # grow/decay policy that produced it (PadPolicy, per
-            # Topology.fingerprint) — the cross-PR record for tuning the
-            # retrace-vs-padded-compute trade-off per topology
-            pad_watermarks=stats.get("pad_watermarks", {}),
-            pad_policies=stats.get("pad_policies", {}))
+            # per-topology mega-batch watermark trajectory — the
+            # cross-PR record of the retrace-vs-padded-compute trade-off
+            pad_watermarks=stats.get("pad_watermarks", {}))
         record["archs"].append(arec)
         for m in fleet_methods:
             for w in fleet_wls:
@@ -146,8 +143,7 @@ def bench_sweep_json(budget: int, out_path: str = SWEEP_JSON) -> dict:
     search.clear_cache()
     serve_budget = min(budget, 600)
     srv = sweep_serve.SweepServer(
-        port=0, config=search.FleetConfig(stack_batches=True,
-                                          device_rounds=1))
+        port=0, config=search.FleetConfig(device_rounds=1))
     srv.start_background()
     t0 = time.time()
 
@@ -190,8 +186,7 @@ def bench_sweep_json(budget: int, out_path: str = SWEEP_JSON) -> dict:
             default=0),
         queries=st["queries"], completed=st["completed"],
         warm_started=st["warm_started"],
-        pad_watermarks=fleet.get("pad_watermarks", {}),
-        pad_policies=fleet.get("pad_policies", {})))
+        pad_watermarks=fleet.get("pad_watermarks", {})))
 
     # contract-analysis provenance: lint wall-time + per-rule violation
     # counts, and the canonical jaxpr hash of every registered kernel

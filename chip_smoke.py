@@ -201,7 +201,7 @@ def served_path(budget: int = BUDGET, queries=QUERIES) -> None:
     from repro.launch import sweep_serve
 
     srv = sweep_serve.SweepServer(
-        port=0, config=FleetConfig(stack_batches=True, device_rounds=1))
+        port=0, config=FleetConfig(device_rounds=1))
     srv.start_background()
     results = {}
 
@@ -371,7 +371,7 @@ def sharded(ndev: int) -> None:
         tasks = [search.SearchTask(by_name("mm1"), "cloud", budget=700,
                                    seed=s, name=f"t{s}") for s in range(8)]
         ms = search.MultiSearch(tasks, search.FleetConfig(
-            stack_batches=True, device_rounds=4, mesh=m))
+            device_rounds=4, mesh=m))
         return ms.run(), ms.stats
 
     res1, st1 = fleet(None)

@@ -82,8 +82,7 @@ def main(argv=None):
         stats = {}
         grid = search.run_method_sweep(
             methods, workloads, plat, budget=args.budget, seed=0,
-            stats_out=stats,
-            config=search.FleetConfig(stack_batches=True))
+            stats_out=stats)
         for wl in workloads:
             row = {m: grid[m][wl.name].best_edp for m in methods}
             ours = row["sparsemap"]

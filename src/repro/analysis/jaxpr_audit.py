@@ -197,8 +197,9 @@ def _perturb(spec):
 
 
 def _trace_eval(model):
-    """ClosedJaxpr of the un-jitted vmapped row kernel, exactly the
-    program every dispatch path compiles."""
+    """ClosedJaxpr of the un-jitted row kernel (:func:`_build_eval_one`,
+    the closure every dispatch path vmaps), vmapped over rows with the
+    workload constants broadcast."""
     import jax
 
     from repro.core.jax_cost import _build_eval_one, _row_structs
@@ -380,27 +381,6 @@ def check_aot_job(key: Tuple, fn, arg_structs) -> List[Violation]:
         if table.shape[0] != key[6] or table.dtype != np.float32:
             bad(f"stacked table {table.shape} {table.dtype} is not "
                 f"({key[6]}, width) float32 as the key says")
-    elif tag == "bcast":
-        padded = key[5]
-        if len(leaves) != 13:
-            bad(f"{tag} job has {len(leaves)} arg leaves, kernel "
-                f"takes 13")
-            return out
-        for i in range(4):
-            if leaves[i].shape[0] != padded:
-                bad(f"{tag} row arg {i} leading dim "
-                    f"{leaves[i].shape[0]} != padded batch {padded} "
-                    f"in the key")
-                break
-        if leaves[1].shape[-1] != n_pad:
-            bad(f"{tag} tiling arg width {leaves[1].shape[-1]} != "
-                f"prime bucket {n_pad} in the key")
-        if any(lv.shape[:1] == (padded,) and lv.ndim > 0
-               for lv in leaves[4:6]):
-            # bcast primes/prime_dim are (n_pad,); a padded leading dim
-            # means batched consts were paired with a bcast key
-            bad("bcast consts look batched — per-row structs under a "
-                "bcast key")
     elif isinstance(tag, str) and (tag.startswith("scan:")
                                    or tag.startswith("dscan:")):
         if len(key) != 9:

@@ -261,21 +261,15 @@ def zoo_validation_report() -> Dict[str, Dict[str, float]]:
         },
     }
 
-# ------------------------------------------- measured pad-watermark policies
+# ------------------------------------- compile-ahead steady-state shapes
 #
 # Per-round mega-batch pad-watermark trajectories from the committed
 # benchmark baseline (benchmarks/BENCH_sweep.baseline.json, regenerated
 # with ``python -m benchmarks.run --quick --only sweep_json``), keyed by
-# arch name.  Every topology measured so far shows the same shape — a
-# round-1 calibration/chunk spike that decays once and never re-grows —
-# so ``search.derive_pad_policy`` tunes them all to the faster
-# ``decay_rounds=2`` instead of the conservative CPU default.  The
-# derived ``decay_ratio`` guards only shapes a fleet has not run yet: a
-# fleet decays to a shape it has already dispatched whatever the ratio,
-# so these CPU trajectories matter only for cold shapes.  When a
-# regenerated baseline changes a trajectory, update the table; the
-# ``benchmarks/compare_sweep.py`` staleness check warns when a fresh
-# run's trajectory disagrees with the policy registered here.
+# arch name.  Their one role: the distinct values are the steady-state
+# mega-batch shapes ``search.MultiSearch`` compiles ahead of round 1
+# (:func:`measured_watermark_values`).  When a regenerated baseline
+# changes a trajectory, update the table.
 _BASELINE_PAD_WATERMARKS: Dict[str, tuple] = {
     "cloud": (2048, 2048, 256, 256, 256, 256),
     "maple_edge": (2048, 2048, 256, 256, 256, 256),
@@ -287,61 +281,22 @@ _BASELINE_PAD_WATERMARKS: Dict[str, tuple] = {
     "dstc_like": (2048, 2048, 256, 256, 256, 256),
 }
 
-# Author-declared EXPECTED trajectories for topologies registered ahead
-# of their first committed baseline run.  A new zoo entry lands here (so
-# it never silently inherits the default pad policy); measured baseline
-# entries above always shadow a seed, and
-# ``benchmarks/compare_sweep.stale_policy_warnings`` flags a still-seeded
-# policy once a fresh run has measured the real trajectory.  All zoo
-# seeds so far matched the measured round-1-spike shape and were
-# promoted; the mechanism (and its test) stays for the next entry.
-_SEED_PAD_WATERMARKS: Dict[str, tuple] = {
-    "eyeriss_like": (2048, 2048, 256, 256, 256, 256),
-    "sigma_like": (2048, 2048, 256, 256, 256, 256),
-    "dstc_like": (2048, 2048, 256, 256, 256, 256),
-}
-
 
 def measured_watermark_values(topology_fingerprint: str) -> list:
     """The DISTINCT pad-watermark values a topology's committed baseline
-    trajectory visited (measured entry, else the author-declared seed),
-    sorted descending — the steady-state mega-batch shapes
-    ``search.MultiSearch`` AOT-compiles ahead of round 1.  Unknown
-    topologies return ``[]`` (no shapes claimed, so their dispatches
-    never count as compile-ahead misses)."""
+    trajectory visited, sorted descending — the steady-state mega-batch
+    shapes ``search.MultiSearch`` AOT-compiles ahead of round 1.
+    Unknown topologies return ``[]`` (no shapes claimed, so their
+    dispatches never count as compile-ahead misses)."""
     from repro.core.arch import as_arch
-    for table in (_BASELINE_PAD_WATERMARKS, _SEED_PAD_WATERMARKS):
-        for name, traj in table.items():
-            try:
-                fp = as_arch(name).topology.fingerprint
-            except KeyError:        # pragma: no cover - stale entry
-                continue
-            if fp == topology_fingerprint:
-                return sorted({int(v) for v in traj}, reverse=True)
-    return []
-
-
-def register_measured_pad_policies() -> None:
-    """Derive and register a tuned :class:`~repro.core.search.PadPolicy`
-    per known topology (idempotent; runs at import).  Seeds register
-    first with ``source="seed"``; measured baseline trajectories follow
-    and override, stamped ``source="measured"``."""
-    from repro.core.arch import as_arch
-    from repro.core.search import derive_pad_policy, set_pad_policy
-    for name, traj in _SEED_PAD_WATERMARKS.items():
-        if name in _BASELINE_PAD_WATERMARKS:
-            continue                     # a measurement shadows the seed
-        set_pad_policy(as_arch(name).topology.fingerprint,
-                       derive_pad_policy(traj, source="seed"))
     for name, traj in _BASELINE_PAD_WATERMARKS.items():
-        set_pad_policy(as_arch(name).topology.fingerprint,
-                       derive_pad_policy(traj))
-
-
-try:
-    register_measured_pad_policies()
-except ImportError:             # pragma: no cover - jax-less install
-    pass
+        try:
+            fp = as_arch(name).topology.fingerprint
+        except KeyError:        # pragma: no cover - stale entry
+            continue
+        if fp == topology_fingerprint:
+            return sorted({int(v) for v in traj}, reverse=True)
+    return []
 
 # --------------------------------------------------------------- LM family
 

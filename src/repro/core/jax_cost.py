@@ -86,9 +86,9 @@ def _bucket(n: int, size: int = 16) -> int:
 
 # Registry of live jitted evaluators, keyed by compilation signature
 # (ndims, padded prime count, topology fingerprint, density key, kind)
-# where kind is "bcast" (workload constants broadcast over the batch) or
-# "stacked" (each row's constants gathered from a per-task table, the
-# mega-batch kernel) — used to count
+# where kind is "stacked" (each row's constants gathered from a per-task
+# table, the mega-batch kernel every per-round dispatch runs) — used to
+# count
 # actual XLA compilations (one per distinct traced argument-shape set per
 # signature).  The density key is "u" for all-uniform workloads (the
 # literal pre-density-model kernel, bit-identical to the goldens) or
@@ -125,7 +125,7 @@ SCAN_BUILDS = "fleet.scan_builds"   # scan programs built in the process
 
 #: the names of the kernel programs, which XLA's module names carry
 #: (``jit_<name>``) and device traces are read by
-EVAL_PROGRAM = "eval_one"       # the row evaluator, broadcast or stacked
+EVAL_PROGRAM = "eval_one"       # the row evaluator (the mega-batch program)
 SCAN_PROGRAM = "one_task"       # the segment scans, canonical and direct
 
 
@@ -422,7 +422,6 @@ except Exception:               # pragma: no cover - future-proofing
 
 def clear_compile_cache() -> None:
     """Drop all shared jitted evaluators (benchmarking hook)."""
-    _jitted_eval.cache_clear()
     _stacked_program.cache_clear()
     _stacked_fn.cache_clear()
     _build_eval_one.cache_clear()
@@ -653,9 +652,9 @@ def _build_eval_one(d: int, n_primes_pad: int, topo: Topology,
                     dens_key: str = "u"):
     """Build the un-vmapped per-row kernel closure for (ndims=d, padded
     prime count, topology, density mode).  Every dispatch path — the
-    broadcast and stacked batch evaluators, the sharded mega-batch, and
-    the device-resident ``run_segments`` scan — vmaps this ONE closure,
-    so per-row results are identical across all of them.
+    mega-batch program, its sharded variant, and the device-resident
+    ``run_segments`` scan — vmaps this ONE closure, so per-row results
+    are identical across all of them.
 
     ``dens_key == "u"`` bakes the uniform-random occupancy model exactly
     as the pre-density-model code did (bit-identical to the goldens);
@@ -905,21 +904,6 @@ def _build_eval_one(d: int, n_primes_pad: int, topo: Topology,
     return _named(eval_one, EVAL_PROGRAM)
 
 
-@lru_cache(maxsize=32)
-def _jitted_eval(d: int, n_primes_pad: int, topo: Topology,
-                 dens_key: str = "u"):
-    """The jitted batch evaluator for (ndims=d, padded prime count,
-    topology, density mode): :func:`_build_eval_one` vmapped over the
-    batch axis, the workload/platform quantities broadcast over the batch
-    (one workload per call)."""
-    eval_one = _build_eval_one(d, n_primes_pad, topo, dens_key)
-    fn = jax.jit(jax.vmap(eval_one, in_axes=(0, 0, 0, 0) + (None,) * 9))
-    with _LOCK:
-        _JIT_FNS[(d, n_primes_pad, topo.fingerprint, dens_key,
-                  "bcast")] = fn
-    return fn
-
-
 def _split_rows(rows, nl: int, n_pad: int) -> Tuple:
     """The kernel's (perm, tiling, fmt, sg) inputs as column slices of a
     packed (B, W) int32 row buffer (:meth:`JaxCostModel._pack`), numpy or
@@ -950,8 +934,8 @@ def _stacked_program(d: int, n_pad: int, topo: Topology, dens_key: str,
     """The un-jitted mega-batch program: ``(rows, table) -> (3, B)``.
     ``rows`` is the packed int32 row buffer, its last column each row's
     index into ``table``, the (slots, width) float32 per-task constants
-    table; each row gathers its constants and runs the same vmapped
-    per-row kernel as the broadcast path.  The result rows are ``valid``
+    table; each row gathers its constants and runs the vmapped per-row
+    kernel (:func:`_build_eval_one`).  The result rows are ``valid``
     (0/1), ``energy_pj`` and ``cycles``; ``edp`` and ``log10_edp`` are
     derived on the host (:func:`_canonical`)."""
     veval = jax.vmap(_build_eval_one(d, n_pad, topo, dens_key))
@@ -1619,9 +1603,8 @@ class JaxCostModel:
         for i, (dd, p) in enumerate(spec.primes):
             primes[i] = p
             prime_dim[i] = dim_idx[dd]
-        # numpy copies feed the scans and the AOT structs, jnp copies the
-        # broadcast kernel, and one float32 row of them all the per-task
-        # table of eval_stacked
+        # numpy copies feed the scans and the AOT structs, and one float32
+        # row of them all the per-task table of eval_stacked
         self._np_consts = (
             primes,
             prime_dim,
@@ -1638,9 +1621,6 @@ class JaxCostModel:
             # per-tensor traced density rows [code, hit, family params..]
             np.asarray([density_lib.param_row(wl.density_model_of(t.name))
                         for t in wl.tensors], np.float32))
-        (self._primes, self._prime_dim, self._relevance, self._densities,
-         self._full_elems, self._total_macs, self._z_onehot, self._plat,
-         self._dens_params) = [jnp.asarray(c) for c in self._np_consts]
         self._const_layout = tuple((np.shape(c), np.asarray(c).dtype.str)
                                    for c in self._np_consts)
         self._const_row = np.concatenate(
@@ -1648,13 +1628,10 @@ class JaxCostModel:
              for c in self._np_consts])
         # the row of eval_stacked's table this model's rows read
         self._table_key = (wl.cache_key(), self.arch)
-
-        self._fn = _jitted_eval(d, self.n_pad, self.arch.topology,
-                                self.dens_key)
         # genome segment -> first column of the packed row buffer
         s = spec.segments
         tt = _topo_tables(self.arch.topology)
-        self._nl = nl = tt.n_levels
+        nl = tt.n_levels
         f = nl + self.n_pad
         self._pack_cols = [(s["perm"].slice, 0), (s["tiling"].slice, nl)] + [
             (s[f"fmt_{t.name}"].slice, f + i * MAX_FMT_GENES)
@@ -1681,27 +1658,10 @@ class JaxCostModel:
             out[:, col:col + sl.stop - sl.start] = genomes[:, sl]
 
     def __call__(self, genomes) -> Dict[str, np.ndarray]:
-        """genomes: (B, L) ints -> dict of (B,) arrays.  Pads the batch to
-        the next power of two and the prime axis to its bucket."""
-        n = len(genomes)
-        padded = _pad_batch(n)
-        sig = self.signature
-        with trace.span("fleet.dispatch", sig=sig, kind="bcast"):
-            rows = np.zeros((padded, self._row_width), np.int32)
-            self._pack(genomes, rows[:n])
-            perm, til, fmt, sg = _split_rows(rows, self._nl, self.n_pad)
-            _count_dispatch()
-            trace.count(ROWS, n, sig=sig, kind="bcast")
-            trace.count(ROWS_PADDED, padded - n, sig=sig, kind="bcast")
-            out = _aot_call(
-                sig + ("bcast", padded), self._fn,
-                (jnp.asarray(perm), jnp.asarray(til),
-                 jnp.asarray(fmt), jnp.asarray(sg),
-                 self._primes, self._prime_dim, self._relevance,
-                 self._densities, self._full_elems, self._total_macs,
-                 self._z_onehot, self._plat, self._dens_params))
-        return _canonical(_time_block(
-            lambda: {k: np.asarray(v)[:n] for k, v in out.items()}))
+        """genomes: (B, L) ints -> dict of (B,) arrays: a one-task
+        mega-batch (:func:`eval_stacked`), so a single search runs the
+        same compiled program as a fleet."""
+        return eval_stacked([self], [genomes])[0]
 
     def run_segment(self, seg: DeviceSegment) -> SegmentResult:
         """Execute one device-resident ES segment against this model
@@ -1720,12 +1680,11 @@ def _pad_batch(n: int) -> int:
 
 
 def _canonical(out: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
-    """Recompute the derived outputs (edp, log10_edp) in numpy from the
-    kernel's float32 cycles/energy.  XLA is free to fuse the final
-    ``cycles * energy`` differently in the broadcast vs stacked kernel
-    (observed: 1-ULP drift), so deriving them outside the jit makes every
-    dispatch path bit-identical for the same rows.  Keys come back in
-    sorted order, as a jitted program's dict output has them."""
+    """Derive the outputs the mega-batch program does not return (edp,
+    log10_edp) in numpy from its float32 cycles/energy: the program
+    returns only ``valid``, ``energy_pj`` and ``cycles``, which keeps its
+    one result copy small.  Keys come back in sorted order, as a jitted
+    program's dict output has them."""
     cycles = out["cycles"]
     energy = out["energy_pj"]
     with np.errstate(over="ignore"):
@@ -1862,9 +1821,9 @@ def eval_stacked(models: Sequence["JaxCostModel"],
     only when the group's set of tasks changes — see
     :func:`stack_prep_counts`); the program returns one (3, padded)
     float32 array (valid, energy_pj, cycles), fetched in one copy and
-    sliced back per input pair.  Rows are evaluated by exactly the same
-    per-row computation as the broadcast kernel, so results are
-    bit-identical to per-model calls.  Each copy counts in the recorder's
+    sliced back per input pair.  Each row's result depends only on the
+    row and its task's constants, so a task's rows come back the same
+    whatever else shares the mega-batch.  Each copy counts in the recorder's
     :data:`TRANSFERS` (``dir`` h2d/d2h, ``what`` rows/table/out).
 
     ``pad_floor`` raises the batch padding beyond the power-of-two rule —
@@ -1961,16 +1920,6 @@ def stacked_compile_job(model: "JaxCostModel", padded: int,
     return (sig + ("stacked", padded, slots), fn,
             (S((padded, model._row_width), np.int32),
              S((slots, model._const_row.size), np.float32)))
-
-
-def bcast_compile_job(model: "JaxCostModel", padded: int) -> Tuple:
-    """AOT job for one broadcast (per-task ``model(genomes)``) shape."""
-    sig = model.signature
-    S = jax.ShapeDtypeStruct
-    consts = tuple(S(np.shape(np.asarray(c)), np.asarray(c).dtype)
-                   for c in model._np_consts)
-    return (sig + ("bcast", padded), model._fn,
-            _row_structs(model, padded) + consts)
 
 
 def _draw_structs(T: int, k: int, n_children: int, genes_per: int) -> Dict:

@@ -19,10 +19,10 @@ batch is evaluated and its generator advanced.  Tasks are ordered by
 (ndims, prime-bucket, topology) compilation signature; with
 ``align_signatures=True``
 each workload's prime axis is padded up to the largest bucket among its
-same-ndims peers so the whole group shares ONE XLA compilation, and with
-``stack_batches=True`` all same-signature pending batches are concatenated
-into one padded mega-batch per round — a single device dispatch per
-signature instead of one per task:
+same-ndims peers so the whole group shares ONE XLA compilation, and all
+same-signature pending batches are concatenated into one padded
+mega-batch per round — a single device dispatch per signature instead of
+one per task:
 
     results = search.run_sweep([wl_a, wl_b], "cloud", budget=20_000)
     grid = search.run_method_sweep(["sparsemap", "pso", "random_mapper"],
@@ -131,116 +131,6 @@ def report_best(workload: Workload, platform: PlatformLike,
 # ---------------------------------------------------------------- multi
 
 
-@dataclasses.dataclass(frozen=True)
-class PadPolicy:
-    """Mega-batch pad-watermark grow/decay constants for ONE topology.
-
-    The watermark grows to the largest padded round immediately; after
-    ``decay_rounds`` quiet rounds it decays to their largest shape.  A
-    shape the fleet has already dispatched costs no trace, so the decay
-    to it is taken at once; ``decay_ratio`` guards only shapes the fleet
-    has not run yet, which it decays to only when every quiet round
-    needs at most ``decay_ratio`` of the watermark as growth and such
-    cold decays set it (warm decays leave that reference alone).  The
-    defaults are CPU-tuned; each registered topology compiles its own
-    kernel family, so the retrace-vs-padded-compute sweet spot for a
-    cold shape is a per-topology number — register a measured policy
-    with :func:`set_pad_policy` (keyed by ``Topology.fingerprint``) or
-    pass ``pad_policies`` to :class:`MultiSearch` for a one-off
-    override.
-
-    ``source`` records where the constants came from: ``"default"`` (the
-    CPU-tuned fallback), ``"measured"`` (derived from a committed
-    benchmark trajectory) or ``"seed"`` (declared by a topology's author
-    ahead of its first committed baseline run — a zoo entry lands with a
-    seed so it never *silently* inherits the default, and
-    ``benchmarks/compare_sweep.stale_policy_warnings`` flags the seed for
-    promotion once a baseline run has measured the real trajectory)."""
-
-    decay_rounds: int = 3
-    decay_ratio: float = 0.5
-    source: str = "default"
-
-
-#: recorder counter of watermark decays; ``warm=True`` marks one the
-#: ``decay_ratio`` test refused, taken because the fleet had run the shape
-PAD_DECAYS = "fleet.pad_decays"
-
-#: The explicit policy :func:`pad_policy_for` returns for topologies with
-#: no registered entry: the conservative CPU-tuned constants.
-DEFAULT_PAD_POLICY = PadPolicy()
-
-
-def derive_pad_policy(trajectory: Sequence[int],
-                      source: str = "measured") -> PadPolicy:
-    """Derive a per-topology :class:`PadPolicy` from a pad-watermark
-    trajectory (``stats["pad_watermarks"]`` of a committed benchmark
-    run, e.g. ``BENCH_sweep.baseline.json``; pass ``source="seed"`` when
-    the trajectory is an author-declared expectation rather than a
-    committed measurement).
-
-    Heuristic: a trajectory that steps down from its peak and never
-    re-grows afterwards is a one-off spike (round-1 calibration probes /
-    random_mapper chunks).  Such topologies decay earlier
-    (``decay_rounds=2``) — one fewer round of mostly-padding kernel
-    compute — with ``decay_ratio`` tightened to the observed post-spike
-    plateau, so the earlier decay does NOT buy extra re-traces later
-    (marginal follow-up decays to a shape not yet run, e.g. 256 -> 128,
-    stay suppressed).  A trajectory that re-grows after decaying
-    (oscillating fleet demand) keeps the conservative default, where an
-    extra quiet round must pass before paying the re-trace.
-    ``decay_ratio`` guards only shapes the fleet has not run yet: the
-    fleet decays to a shape it has already dispatched whatever the
-    ratio (:meth:`MultiSearch.step`).  ``benchmarks/compare_sweep.py``
-    mirrors the decay_rounds rule (stdlib-only) to warn when a fresh
-    trajectory disagrees with the registered policy."""
-    traj = list(trajectory)
-    peak = max(traj, default=0)
-    if peak <= 0 or traj[-1] >= peak:
-        # never decayed: no evidence either way — default constants, but
-        # stamped with the source so the registry records it was derived
-        return PadPolicy(source=source)
-    first_down = next(i for i, v in enumerate(traj) if v < peak
-                      and max(traj[:i], default=0) == peak)
-    regrew = any(b > a for a, b in zip(traj[first_down:],
-                                       traj[first_down + 1:]))
-    if regrew:
-        return PadPolicy(source=source)
-    plateau_ratio = max(traj[first_down:]) / peak
-    return PadPolicy(decay_rounds=2,
-                     decay_ratio=min(max(plateau_ratio, 1 / 32), 0.5),
-                     source=source)
-
-
-#: topology fingerprint -> tuned PadPolicy (default policy when absent)
-_PAD_POLICIES: Dict[str, PadPolicy] = {}
-
-
-def set_pad_policy(topology_fingerprint: str, policy: PadPolicy) -> None:
-    """Register the tuned pad-watermark policy for a topology."""
-    _PAD_POLICIES[topology_fingerprint] = policy
-
-
-def pad_policy_for(topology_fingerprint: str) -> PadPolicy:
-    """The registered policy for a topology, or — documented, not an
-    accident — :data:`DEFAULT_PAD_POLICY` when none is registered (new
-    topologies start on the conservative CPU-tuned constants until a
-    seed or measured policy lands in ``repro.configs.archs``)."""
-    _load_measured_policies()
-    return _PAD_POLICIES.get(topology_fingerprint, DEFAULT_PAD_POLICY)
-
-
-def _load_measured_policies() -> None:
-    """Importing ``repro.configs.archs`` registers the PadPolicies
-    derived from the committed benchmark baseline; built-in topologies
-    (e.g. the paper arch) never trigger ``as_arch``'s lazy configs
-    import, so the policy lookup triggers it itself."""
-    try:
-        import repro.configs.archs  # noqa: F401  (side effect: register)
-    except ImportError:             # pragma: no cover - jax-less install
-        pass
-
-
 #: per-backend default for ``MultiSearch(device_rounds=None)``.  CPU stays
 #: at 1 — measured in the PR 6 baseline: folded scans win on host syncs
 #: but XLA:CPU's scan program loses wall-clock to the per-round path, so
@@ -267,7 +157,7 @@ class FleetConfig:
     """The fleet runtime configuration — every knob
     :class:`MultiSearch` accepts, in one validated, frozen, serializable
     object (``MultiSearch(tasks, FleetConfig(...))``).  This replaces the
-    eight accreted ``MultiSearch.__init__`` kwargs (still accepted as
+    accreted ``MultiSearch.__init__`` kwargs (still accepted as
     deprecated aliases) and doubles as the sweep server's wire schema:
     ``to_json()``/``from_json()`` round-trip everything except ``mesh``,
     which is a process-local ``jax.sharding.Mesh`` and must be rebuilt on
@@ -276,12 +166,15 @@ class FleetConfig:
     ``device_rounds=None`` defers to the per-backend default
     (:func:`default_device_rounds`); :meth:`resolved_device_rounds`
     resolves it in exactly one place and reports the provenance string
-    the fleet ``stats`` record."""
+    the fleet ``stats`` record.
+
+    ``stack_batches`` is a validated constant: the fleet always
+    mega-batches per signature, and ``False`` is refused.  The field
+    stays only so that configurations which spell out
+    ``"stack_batches": true`` still load."""
 
     align_signatures: bool = True
-    stack_batches: bool = False
-    pad_policies: Dict[str, PadPolicy] = \
-        dataclasses.field(default_factory=dict)
+    stack_batches: bool = True
     device_rounds: Optional[int] = None
     mesh: object = None
     device_execute: bool = True
@@ -292,20 +185,16 @@ class FleetConfig:
         for flag in ("align_signatures", "stack_batches",
                      "device_execute", "pipeline", "compile_ahead"):
             object.__setattr__(self, flag, bool(getattr(self, flag)))
+        if not self.stack_batches:
+            raise ValueError(
+                "stack_batches=False is not supported: the per-task "
+                "dispatch path was removed, and every fleet mega-batches "
+                "its same-signature tasks")
         if self.device_rounds is not None:
             if int(self.device_rounds) < 1:
                 raise ValueError("device_rounds must be >= 1")
             object.__setattr__(self, "device_rounds",
                                int(self.device_rounds))
-        pols = {}
-        for fp, pol in (self.pad_policies or {}).items():
-            if isinstance(pol, dict):
-                pol = PadPolicy(**pol)
-            if not isinstance(pol, PadPolicy):
-                raise TypeError(f"pad_policies[{fp!r}] must be a "
-                                f"PadPolicy or dict, got {type(pol)}")
-            pols[str(fp)] = pol
-        object.__setattr__(self, "pad_policies", pols)
 
     def resolved_device_rounds(self) -> Tuple[int, str]:
         """``(value, provenance)``: the explicit value, or the
@@ -326,9 +215,6 @@ class FleetConfig:
         return dict(
             version=1,
             align_signatures=self.align_signatures,
-            stack_batches=self.stack_batches,
-            pad_policies={fp: dataclasses.asdict(pol)
-                          for fp, pol in sorted(self.pad_policies.items())},
             device_rounds=self.device_rounds,
             device_execute=self.device_execute,
             pipeline=self.pipeline,
@@ -461,6 +347,22 @@ class _TaskState:
         return self.ev.signature
 
 
+#: The mega-batch pad watermark's decay rule (:meth:`MultiSearch.step`):
+#: decay after this many quiet rounds, and to a shape the fleet has not
+#: run only when each quiet round needs at most this share of it.  These
+#: are the values every registered topology derived from the CPU
+#: baseline's round-1-spike watermark trajectory (a spike that decays
+#: once and never re-grows); a fleet decays to a shape it has already
+#: run whatever the ratio, so they decide only decays to new shapes.
+PAD_DECAY_ROUNDS = 2
+PAD_DECAY_RATIO = 0.125
+
+#: recorder counter of watermark decays; ``warm=True`` marks one the
+#: :data:`PAD_DECAY_RATIO` test refused, taken because the fleet had run
+#: the shape
+PAD_DECAYS = "fleet.pad_decays"
+
+
 class MultiSearch:
     """Run a fleet of (method, workload, platform) searches concurrently.
 
@@ -468,8 +370,12 @@ class MultiSearch:
     SparseMap populations, ``baselines.*_requests`` for the baseline
     optimizers); every round, each pending task's next batch is evaluated
     and the generator advanced, with tasks ordered by compilation
-    signature so same-signature tasks hit the shared jitted evaluator
-    back-to-back.
+    signature.  Every round concatenates all same-signature pending
+    batches into ONE padded mega-batch and issues a single device
+    dispatch per signature (``jax_cost.eval_stacked``), slicing the
+    results back per task; the baselines' odd native batch sizes (48,
+    50, 64) simply become rows of the shared power-of-two-padded
+    mega-batch.
 
     With ``align_signatures=True`` (default), each workload's prime axis
     is padded up to the largest bucket among its same-ndims peers,
@@ -477,14 +383,6 @@ class MultiSearch:
     the paper's workload table then reuses compilations instead of paying
     XLA tracing per workload (the padding primes are 1.0 and numerically
     inert).
-
-    With ``stack_batches=True``, every round concatenates all
-    same-signature pending batches into ONE padded mega-batch and issues a
-    single device dispatch per signature (``jax_cost.eval_stacked``),
-    slicing the results back per task.  Rows run through the same per-row
-    kernel math either way, so stacked and per-task dispatch give
-    bit-identical results; the baselines' odd native batch sizes (48, 50,
-    64) simply become rows of the shared power-of-two-padded mega-batch.
 
     With ``device_rounds=k > 1``, tasks whose method is scan-foldable
     (``baselines.SEGMENT_METHODS``) advance in k-generation device
@@ -534,8 +432,7 @@ class MultiSearch:
 
     def __init__(self, tasks: Iterable,
                  config: Optional[FleetConfig] = None, *,
-                 align_signatures=_UNSET, stack_batches=_UNSET,
-                 pad_policies=_UNSET, device_rounds=_UNSET, mesh=_UNSET,
+                 align_signatures=_UNSET, device_rounds=_UNSET, mesh=_UNSET,
                  device_execute=_UNSET, pipeline=_UNSET,
                  compile_ahead=_UNSET):
         norm: List[SearchTask] = []
@@ -545,7 +442,6 @@ class MultiSearch:
             raise ValueError("MultiSearch needs at least one task")
         legacy = {k: v for k, v in dict(
             align_signatures=align_signatures,
-            stack_batches=stack_batches, pad_policies=pad_policies,
             device_rounds=device_rounds, mesh=mesh,
             device_execute=device_execute, pipeline=pipeline,
             compile_ahead=compile_ahead).items() if v is not _UNSET}
@@ -560,8 +456,6 @@ class MultiSearch:
                 f"configuration is deprecated; pass "
                 f"config=FleetConfig(...)", DeprecationWarning,
                 stacklevel=2)
-            if legacy.get("pad_policies") is None:
-                legacy["pad_policies"] = {}
             config = FleetConfig(**legacy)
         if config is None:
             config = FleetConfig()
@@ -569,8 +463,6 @@ class MultiSearch:
         self.config = config
         # resolved views (one resolution point: FleetConfig)
         self.align_signatures = config.align_signatures
-        self.stack_batches = config.stack_batches
-        self.pad_policies = dict(config.pad_policies)
         self.device_rounds, self.device_rounds_source = \
             config.resolved_device_rounds()
         self.mesh = config.mesh
@@ -595,11 +487,6 @@ class MultiSearch:
             return SearchTask(t)
         return SearchTask(*t)
 
-    def _pad_policy(self, topology_fingerprint: str) -> PadPolicy:
-        if topology_fingerprint in self.pad_policies:
-            return self.pad_policies[topology_fingerprint]
-        return pad_policy_for(topology_fingerprint)
-
     @staticmethod
     def _resolve_names(tasks: Sequence[SearchTask]) -> List[str]:
         base = [t.resolved_name() for t in tasks]
@@ -621,9 +508,8 @@ class MultiSearch:
 
     def _compile_ahead_jobs(self, infos: List[Tuple]) -> List[Tuple]:
         """The AOT (key, jit_fn, arg_structs) jobs predicted from the
-        fleet's tasks: round-1 eval shapes (stacked mega-batch per
-        signature group, or per-task broadcast), the registered
-        pad-watermark shapes of each topology (the steady-state
+        fleet's tasks: round-1 mega-batch shapes per signature group, the
+        baseline's pad-watermark shapes of each topology (the steady-state
         mega-batch sizes a committed baseline measured), and the scan /
         direct-scan programs of segment-foldable tasks at every task-slot
         bucket (``jax_cost.scan_slots``) up to each group's size; a
@@ -660,50 +546,43 @@ class MultiSearch:
                                         task.seed, **kw))
             except (TypeError, ValueError):
                 rows.append(None)
-        if self.stack_batches:
-            by_sig: Dict[Tuple, List[int]] = {}
-            for i, (task, kw, spec, ev) in enumerate(infos):
-                by_sig.setdefault(ev.signature, []).append(i)
-            for sig in sorted(by_sig):
-                idx = by_sig[sig]
-                model = infos[idx[0]][3]
-                # the group's per-task constants table: one slot count
-                slots = jax_cost.table_slots(
-                    sig, len({infos[i][3]._table_key for i in idx}))
-                if all(rows[i] is not None for i in idx):
-                    total = sum(rows[i] for i in idx)
-                    add(jax_cost.stacked_compile_job(
-                        model, jax_cost._pad_batch(total), slots))
-                    # decayed steady-state shapes: once round-1 shapes
-                    # (calibration / first chunks) age out of the pad
-                    # watermark, the mega-batch settles on the sum of
-                    # the survivors' per-round batches
-                    steads = []
-                    for i in idx:
-                        task, kw = infos[i][0], infos[i][1]
-                        try:
-                            steads.append(steady_rows(
-                                task.method, infos[i][2], task.budget,
-                                task.seed, **kw))
-                        except (TypeError, ValueError):
-                            steads.append(None)
-                    if all(s is not None for s in steads):
-                        alive = [s for s in steads if s]
-                        for tot in sorted({sum(s[0] for s in alive),
-                                           sum(s[-1] for s in alive)}):
-                            if tot > 0:
-                                add(jax_cost.stacked_compile_job(
-                                    model, jax_cost._pad_batch(tot),
-                                    slots), when=late)
-                    for v in watermarks(sig[2]):
-                        add(jax_cost.stacked_compile_job(model, int(v),
-                                                         slots),
-                            when=late)
-        else:
-            for (task, kw, spec, ev), r in zip(infos, rows):
-                if r is not None:
-                    add(jax_cost.bcast_compile_job(
-                        ev, jax_cost._pad_batch(r)))
+        by_sig: Dict[Tuple, List[int]] = {}
+        for i, (task, kw, spec, ev) in enumerate(infos):
+            by_sig.setdefault(ev.signature, []).append(i)
+        for sig in sorted(by_sig):
+            idx = by_sig[sig]
+            model = infos[idx[0]][3]
+            # the group's per-task constants table: one slot count
+            slots = jax_cost.table_slots(
+                sig, len({infos[i][3]._table_key for i in idx}))
+            if all(rows[i] is not None for i in idx):
+                total = sum(rows[i] for i in idx)
+                add(jax_cost.stacked_compile_job(
+                    model, jax_cost._pad_batch(total), slots))
+                # decayed steady-state shapes: once round-1 shapes
+                # (calibration / first chunks) age out of the pad
+                # watermark, the mega-batch settles on the sum of the
+                # survivors' per-round batches
+                steads = []
+                for i in idx:
+                    task, kw = infos[i][0], infos[i][1]
+                    try:
+                        steads.append(steady_rows(
+                            task.method, infos[i][2], task.budget,
+                            task.seed, **kw))
+                    except (TypeError, ValueError):
+                        steads.append(None)
+                if all(s is not None for s in steads):
+                    alive = [s for s in steads if s]
+                    for tot in sorted({sum(s[0] for s in alive),
+                                       sum(s[-1] for s in alive)}):
+                        if tot > 0:
+                            add(jax_cost.stacked_compile_job(
+                                model, jax_cost._pad_batch(tot), slots),
+                                when=late)
+                for v in watermarks(sig[2]):
+                    add(jax_cost.stacked_compile_job(model, int(v), slots),
+                        when=late)
         # every task-slot bucket the group's scans can run at, so that
         # no count of tasks the traffic leaves compiles at dispatch
         seg_groups: Dict[Tuple, List[Tuple]] = {}
@@ -988,21 +867,21 @@ class MultiSearch:
 
         The pad floor (mega-batch watermark) grows to the largest padded
         round immediately (shrinking fleets keep hitting the warm
-        shape), and decays to the recent maximum after ``decay_rounds``
-        quiet rounds.  When this fleet has already dispatched that
-        shape (it keeps the padded rows of each of its mega-batches per
-        signature) the decay costs no trace and is taken at once, so a
-        spike from an admitted query's calibration batch does not hold
-        later rounds at its shape.  A shape not yet run is decayed to
-        only when each quiet round needs at most ``decay_ratio`` of the
-        watermark as growth and these cold decays set it (``_pad_ref``;
+        shape), and decays to the recent maximum after
+        :data:`PAD_DECAY_ROUNDS` quiet rounds.  When this fleet has
+        already dispatched that shape (it keeps the padded rows of each
+        of its mega-batches per signature) the decay costs no trace and
+        is taken at once, so a spike from an admitted query's
+        calibration batch does not hold later rounds at its shape.  A
+        shape not yet run is decayed to only when each quiet round needs
+        at most :data:`PAD_DECAY_RATIO` of the watermark as growth and
+        these cold decays set it (``_pad_ref``;
         a warm decay to a middle shape must not shut out a smaller cold
         one the ratio allows) — one extra XLA trace instead of paying
         mostly-padding kernel compute every round after a one-off spike
         (e.g. round-1 calibration probes + random_mapper's 512-row
         chunks).  Each decay counts in the recorder's
         :data:`PAD_DECAYS` and in ``stats["pad_decays"]``.  The
-        grow/decay constants are a per-TOPOLOGY :class:`PadPolicy`; the
         per-round watermark trajectory lands in
         ``stats["pad_watermarks"]`` for cross-PR tracking.  The
         ``pad_recent`` observations are (target, weight) pairs; weight =
@@ -1038,67 +917,60 @@ class MultiSearch:
         if plain:
             iter_weight = max(iter_weight, 1)
         dispatched: List[Tuple[List[_TaskState], object]] = []
-        if self.stack_batches:
-            groups: Dict[Tuple[int, int, str],
-                         List[_TaskState]] = {}
-            for st in plain:
-                groups.setdefault(st.signature, []).append(st)
-            # two-phase round: FIRST enqueue every signature group's
-            # mega-batch (with pipeline=True the dispatches return
-            # StackedPending handles, so all groups' device work is
-            # in flight together), THEN finalize + advance in the
-            # same sorted order — round N's host-blocking conversion
-            # of group i overlaps groups i+1..n computing.  The
-            # watermark bookkeeping is value-independent (row counts
-            # are known at dispatch), so it stays in dispatch order
-            # and pipeline on/off cannot change any padded shape.
-            # The mega-batches go to the device ahead of this step's
-            # scans: the step waits for them (finalize below), and a
-            # scan queued in front would hold them back by its whole
-            # run, where the next step harvests the scan's results
-            for sig in sorted(groups):
-                grp = groups[sig]
-                pol = self._pad_policy(sig[2])
-                hwm = pad_hwm.get(sig, 0)
-                outs = jax_cost.eval_stacked(
-                    [s.ev for s in grp], [s.req for s in grp],
-                    pad_floor=hwm, mesh=self.mesh,
-                    defer=self.pipeline)
-                dispatched.append((grp, outs))
-                total = sum(len(s.req) for s in grp)
-                target = jax_cost._pad_batch(total)
-                ran = pad_run.setdefault(sig, set())
-                ran.add(jax_cost.stacked_rows(total, hwm, self.mesh))
-                hist = pad_recent.setdefault(sig, [])
-                hist.append((target, max(iter_weight, 1)))
-                wtot = sum(w for _, w in hist)
-                while hist and wtot - hist[0][1] >= pol.decay_rounds:
-                    wtot -= hist.pop(0)[1]
-                ref = pad_ref.get(sig, 0)
-                if target > hwm:
-                    pad_hwm[sig] = target
-                    pad_ref[sig] = max(ref, target)
+        groups: Dict[Tuple[int, int, str], List[_TaskState]] = {}
+        for st in plain:
+            groups.setdefault(st.signature, []).append(st)
+        # two-phase round: FIRST enqueue every signature group's
+        # mega-batch (with pipeline=True the dispatches return
+        # StackedPending handles, so all groups' device work is in
+        # flight together), THEN finalize + advance in the same sorted
+        # order — round N's host-blocking conversion of group i overlaps
+        # groups i+1..n computing.  The watermark bookkeeping is
+        # value-independent (row counts are known at dispatch), so it
+        # stays in dispatch order and pipeline on/off cannot change any
+        # padded shape.  The mega-batches go to the device ahead of this
+        # step's scans: the step waits for them (finalize below), and a
+        # scan queued in front would hold them back by its whole run,
+        # where the next step harvests the scan's results
+        for sig in sorted(groups):
+            grp = groups[sig]
+            hwm = pad_hwm.get(sig, 0)
+            outs = jax_cost.eval_stacked(
+                [s.ev for s in grp], [s.req for s in grp],
+                pad_floor=hwm, mesh=self.mesh,
+                defer=self.pipeline)
+            dispatched.append((grp, outs))
+            total = sum(len(s.req) for s in grp)
+            target = jax_cost._pad_batch(total)
+            ran = pad_run.setdefault(sig, set())
+            ran.add(jax_cost.stacked_rows(total, hwm, self.mesh))
+            hist = pad_recent.setdefault(sig, [])
+            hist.append((target, max(iter_weight, 1)))
+            wtot = sum(w for _, w in hist)
+            while hist and wtot - hist[0][1] >= PAD_DECAY_ROUNDS:
+                wtot -= hist.pop(0)[1]
+            ref = pad_ref.get(sig, 0)
+            if target > hwm:
+                pad_hwm[sig] = target
+                pad_ref[sig] = max(ref, target)
+                hist.clear()
+            elif wtot >= PAD_DECAY_ROUNDS:
+                peak = max(t for t, _ in hist)
+                # the ratio is taken against the watermark warm decays
+                # leave alone, so that a warm decay to a middle shape
+                # never shuts out a smaller cold one
+                cold = all(t <= ref * PAD_DECAY_RATIO for t, _ in hist)
+                warm = peak < hwm and jax_cost.stacked_rows(
+                    peak, mesh=self.mesh) in ran
+                if cold or warm:
+                    pad_hwm[sig] = peak
+                    if cold:
+                        pad_ref[sig] = peak
                     hist.clear()
-                elif wtot >= pol.decay_rounds:
-                    peak = max(t for t, _ in hist)
-                    # the ratio is taken against the watermark warm
-                    # decays leave alone, so that a warm decay to a
-                    # middle shape never shuts out a smaller cold one
-                    cold = all(t <= ref * pol.decay_ratio
-                               for t, _ in hist)
-                    warm = peak < hwm and jax_cost.stacked_rows(
-                        peak, mesh=self.mesh) in ran
-                    if cold or warm:
-                        pad_hwm[sig] = peak
-                        if cold:
-                            pad_ref[sig] = peak
-                        hist.clear()
-                        if peak < hwm:
-                            self._pad_decays["cold" if cold
-                                             else "warm"] += 1
-                            trace.count(PAD_DECAYS, sig=sig,
-                                        warm=not cold)
-                wm_hist.setdefault(sig, []).append(pad_hwm[sig])
+                    if peak < hwm:
+                        self._pad_decays["cold" if cold else "warm"] += 1
+                        trace.count(PAD_DECAYS, sig=sig, warm=not cold)
+            wm_hist.setdefault(sig, []).append(pad_hwm[sig])
         for key in sorted(seg_groups):
             grp = seg_groups[key]
             # with pipeline=True the SegmentResults come back
@@ -1137,13 +1009,6 @@ class MultiSearch:
                 if isinstance(outs, jax_cost.StackedPending):
                     outs = outs.finalize()
                 for st, out in zip(grp, outs):
-                    if self._advance(st, out):
-                        pending.append(st)
-        if not self.stack_batches:
-            for st in plain:
-                out = st.ev(st.req)
-                with trace.span("fleet.advance", step=self._host_syncs,
-                                sig=st.signature):
                     if self._advance(st, out):
                         pending.append(st)
         live = {id(st) for st in pending}
@@ -1204,16 +1069,13 @@ class MultiSearch:
             signatures=sorted({s.signature for s in self._states}),
             natural_signatures=sorted({s.natural
                                        for s in self._states}),
-            # per-signature mega-batch watermark trajectory + the policy
-            # that produced it, keyed "d{ndims}_p{bucket}_{topology}"
+            # per-signature mega-batch watermark trajectory, keyed
+            # "d{ndims}_p{bucket}_{topology}"
             pad_watermarks={
                 f"d{sig[0]}_p{sig[1]}_{sig[2]}": hist
                 for sig, hist in self._wm_hist.items()},
-            pad_policies={
-                sig[2]: dataclasses.asdict(self._pad_policy(sig[2]))
-                for sig in self._wm_hist},
             # watermark decays to a shape the fleet had run (warm) and
-            # by the decay_ratio test (cold)
+            # by the PAD_DECAY_RATIO test (cold)
             pad_decays=dict(self._pad_decays))
 
     def finish(self) -> Dict[str, SearchResult]:
@@ -1237,7 +1099,7 @@ class MultiSearch:
 def run_sweep(workloads: Sequence[Workload],
               platform: PlatformLike = "cloud",
               budget: int = 20_000, seed: int = 0,
-              align_signatures: bool = True, stack_batches: bool = False,
+              align_signatures: bool = True,
               device_rounds: Optional[int] = None, mesh=None,
               pipeline: bool = True, compile_ahead: bool = True,
               config: Optional[FleetConfig] = None,
@@ -1248,8 +1110,7 @@ def run_sweep(workloads: Sequence[Workload],
     :class:`FleetConfig` and remain for convenience)."""
     if config is None:
         config = FleetConfig(
-            align_signatures=align_signatures,
-            stack_batches=stack_batches, device_rounds=device_rounds,
+            align_signatures=align_signatures, device_rounds=device_rounds,
             mesh=mesh, pipeline=pipeline, compile_ahead=compile_ahead)
     ms = MultiSearch(
         [SearchTask(wl, platform, budget=budget, seed=seed,
@@ -1263,7 +1124,6 @@ def run_method_sweep(methods: Sequence[str],
                      platform: PlatformLike = "cloud",
                      budget: int = 20_000, seed: int = 0,
                      align_signatures: bool = True,
-                     stack_batches: bool = True,
                      method_kw: Optional[Dict[str, Dict]] = None,
                      stats_out: Optional[Dict] = None,
                      device_rounds: Optional[int] = None, mesh=None,
@@ -1272,10 +1132,10 @@ def run_method_sweep(methods: Sequence[str],
                      config: Optional[FleetConfig] = None
                      ) -> Dict[str, Dict[str, SearchResult]]:
     """The full fig17-style grid — every method on every workload — as ONE
-    concurrent :class:`MultiSearch` fleet, mega-batched per signature by
-    default.  Returns ``{method: {workload_name: SearchResult}}``;
-    ``method_kw`` maps method name -> factory kwargs; ``stats_out``, if
-    given, receives the fleet's ``MultiSearch.stats``."""
+    concurrent :class:`MultiSearch` fleet, mega-batched per signature.
+    Returns ``{method: {workload_name: SearchResult}}``; ``method_kw``
+    maps method name -> factory kwargs; ``stats_out``, if given, receives
+    the fleet's ``MultiSearch.stats``."""
     method_kw = method_kw or {}
     dup_m = [m for m, c in Counter(methods).items() if c > 1]
     dup_w = [n for n, c in Counter(w.name for w in workloads).items()
@@ -1291,8 +1151,7 @@ def run_method_sweep(methods: Sequence[str],
              for m in methods for wl in workloads]
     if config is None:
         config = FleetConfig(
-            align_signatures=align_signatures,
-            stack_batches=stack_batches, device_rounds=device_rounds,
+            align_signatures=align_signatures, device_rounds=device_rounds,
             mesh=mesh, device_execute=device_execute,
             pipeline=pipeline, compile_ahead=compile_ahead)
     ms = MultiSearch(tasks, config)

@@ -40,7 +40,7 @@ modes:
   sweep    persistent accelerator-search sweep server (query coalescing,
            checkpointed populations, crash recovery); options: --host
            --port --checkpoint-dir --checkpoint-every --max-restarts
-           --no-warm-start --device-rounds --no-stack
+           --no-warm-start --device-rounds
 
 `<mode> --help` shows that mode's full options.  Legacy invocations with
 bare flags (no mode word) run decode.

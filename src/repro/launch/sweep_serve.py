@@ -242,7 +242,7 @@ class SweepServer:
                  ckpt_dir: Optional[str] = None, ckpt_every: int = 8,
                  max_restarts: int = 3, warm_start: bool = True):
         self.config = config if config is not None else \
-            FleetConfig(stack_batches=True, device_rounds=1)
+            FleetConfig(device_rounds=1)
         if ckpt_dir is not None:
             resolved, _ = self.config.resolved_device_rounds()
             if resolved != 1:
@@ -685,12 +685,9 @@ def main(argv=None) -> int:
     ap.add_argument("--no-warm-start", action="store_true",
                     help="disable the best-genome warm-start library")
     ap.add_argument("--device-rounds", type=int, default=1)
-    ap.add_argument("--no-stack", action="store_true",
-                    help="disable mega-batch stacking (debug)")
     args = ap.parse_args(argv)
 
-    config = FleetConfig(stack_batches=not args.no_stack,
-                         device_rounds=args.device_rounds)
+    config = FleetConfig(device_rounds=args.device_rounds)
     server = SweepServer(args.host, args.port, config=config,
                          ckpt_dir=args.checkpoint_dir,
                          ckpt_every=args.checkpoint_every,

@@ -224,7 +224,6 @@ def test_check_aot_jobs_accepts_real_builders():
     dspec = DirectValueSpec(m.spec)
     jobs = [
         jax_cost.stacked_compile_job(m, 64),
-        jax_cost.bcast_compile_job(m, 128),
         jax_cost.scan_compile_job(m, B=8, k=2, n_parents=2, n_elite=1,
                                   genes_per=2, T=3),
         jax_cost.scan_compile_job(m, B=8, k=2, n_parents=2, n_elite=1,
@@ -284,8 +283,7 @@ def test_compile_ahead_jobs_include_steady_stacked_shape():
         search.SearchTask(wl, "cloud", budget=300, seed=0,
                           method="random_mapper"),
     ]
-    ms = search.MultiSearch(tasks, stack_batches=True,
-                            compile_ahead=False)
+    ms = search.MultiSearch(tasks, compile_ahead=False)
     jobs = ms._compile_ahead_jobs(ms._task_infos())
     assert check_aot_jobs(jobs) == []
     stacked = [j[0] for j in jobs if j[0][4] == "stacked"]

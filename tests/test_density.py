@@ -183,12 +183,18 @@ def test_fiber_tree_bytes_float_equals_uniform_bitwise():
 
 GOLDEN = os.path.join(os.path.dirname(__file__), "golden",
                       "arch_sparsemap_golden.npz")
+#: the kernel's float32 columns in the golden captures are held to 4 ULP
+#: (``valid`` and the float64 oracle stay exact): the pin is on what the
+#: model means, not on one XLA release's bits (tests/golden/README.md)
+GOLDEN_F32_RTOL = 4.8e-7
 
 
 def test_explicit_uniform_matches_seed_goldens_bit_for_bit():
     """Workloads declared with explicit Uniform(d) models reproduce the
-    pre-DensityModel kernel outputs EXACTLY (same baked uniform kernel,
-    same constants) against the pinned golden captures."""
+    pre-DensityModel kernel outputs (same baked uniform kernel, same
+    constants) against the pinned golden captures: ``valid`` and the
+    oracle prefix exactly, the float32 columns to
+    :data:`GOLDEN_F32_RTOL`."""
     g = np.load(GOLDEN)
     wl = spmm("mm_small", 32, 64, 48, Uniform(0.2), Uniform(0.5))
     assert not wl.structured_density
@@ -202,8 +208,9 @@ def test_explicit_uniform_matches_seed_goldens_bit_for_bit():
                                   np.asarray(res["valid"]))
     for fld, out_key in (("jax_edp", "edp"), ("jax_energy", "energy_pj"),
                         ("jax_cycles", "cycles")):
-        np.testing.assert_array_equal(
-            g[f"{key}:{fld}"], np.asarray(res[out_key]),
+        np.testing.assert_allclose(
+            np.asarray(res[out_key]), g[f"{key}:{fld}"],
+            rtol=GOLDEN_F32_RTOL, atol=0,
             err_msg=f"{out_key} drifted under explicit Uniform models")
     # numpy oracle on the captured prefix, bit-for-bit too
     ov, oe = g[f"{key}:np_valid"], g[f"{key}:np_edp"]
@@ -312,7 +319,7 @@ def test_mixed_density_fleet_one_dispatch_per_round():
     stats = {}
     grid = search.run_method_sweep(["sparsemap", "random_mapper"], wls,
                                    "cloud", budget=200, seed=0,
-                                   stack_batches=True, stats_out=stats)
+                                   stats_out=stats)
     assert len(stats["signatures"]) == 1
     assert stats["signatures"][0][3].startswith("s:")
     assert stats["dispatches"] == stats["rounds"]

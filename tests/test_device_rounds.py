@@ -46,7 +46,7 @@ def _sweep(methods, wls, arch, device_execute, device_rounds=K,
            stats=None, method_kw=None):
     return search.run_method_sweep(
         methods, [by_name(w) for w in wls], arch, budget=BUDGET,
-        seed=SEED, stack_batches=True, device_rounds=device_rounds,
+        seed=SEED, device_rounds=device_rounds,
         device_execute=device_execute,
         stats_out=stats if stats is not None else {},
         method_kw=method_kw)
@@ -221,8 +221,7 @@ print("EVAL_STACKED_SHARDED_OK")
 def fleet(mesh):
     tasks = [search.SearchTask(by_name("mm1"), "cloud", budget=700,
                                seed=s, name=f"t{s}") for s in range(8)]
-    ms = search.MultiSearch(tasks, stack_batches=True, device_rounds=4,
-                            mesh=mesh)
+    ms = search.MultiSearch(tasks, device_rounds=4, mesh=mesh)
     return ms.run(), ms.stats
 
 res1, st1 = fleet(None)

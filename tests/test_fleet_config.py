@@ -41,9 +41,8 @@ def wl(name="fc_wl", m=16, k=16, n=8, dp=0.5, dq=0.5):
 
 
 def test_fleet_config_json_round_trip():
-    cfg = FleetConfig(align_signatures=False, stack_batches=True,
-                      device_rounds=4, pipeline=False,
-                      compile_ahead=False)
+    cfg = FleetConfig(align_signatures=False, device_rounds=4,
+                      pipeline=False, compile_ahead=False)
     back = FleetConfig.from_json(cfg.to_json())
     assert back == cfg
     # defaults round-trip too
@@ -59,6 +58,20 @@ def test_fleet_config_rejects_unknown_fields_and_versions():
     d2["version"] = 99
     with pytest.raises(ValueError):
         FleetConfig.from_json(d2)
+
+
+def test_fleet_config_stack_batches_is_a_validated_constant():
+    """The fleet always mega-batches: ``stack_batches=True`` (as the
+    benchmark configurations spell it) is accepted and changes nothing,
+    and ``False`` is refused, from the constructor and from JSON."""
+    assert FleetConfig(stack_batches=True) == FleetConfig()
+    assert FleetConfig.from_json({"stack_batches": True,
+                                  "device_rounds": 1}) == \
+        FleetConfig(device_rounds=1)
+    with pytest.raises(ValueError, match="per-task dispatch path"):
+        FleetConfig(stack_batches=False)
+    with pytest.raises(ValueError, match="per-task dispatch path"):
+        FleetConfig.from_json('{"version": 1, "stack_batches": false}')
 
 
 def test_fleet_config_mesh_is_process_local():
@@ -80,20 +93,17 @@ def test_fleet_config_validates_device_rounds():
 
 
 def test_legacy_kwargs_warn_and_match_config():
-    """``MultiSearch(tasks, stack_batches=True)`` must warn AND give
+    """``MultiSearch(tasks, compile_ahead=False)`` must warn AND give
     bit-identical results to the FleetConfig spelling."""
     def tasks():
         return [SearchTask(wl("lg1"), "cloud", budget=BUDGET, seed=7),
                 SearchTask(wl("lg2", m=24), "cloud", budget=BUDGET,
                            seed=7)]
     with pytest.warns(DeprecationWarning, match="FleetConfig"):
-        ms_old = MultiSearch(tasks(), stack_batches=True,
-                             compile_ahead=False)
-    assert ms_old.config == FleetConfig(stack_batches=True,
-                                        compile_ahead=False)
+        ms_old = MultiSearch(tasks(), compile_ahead=False)
+    assert ms_old.config == FleetConfig(compile_ahead=False)
     old = ms_old.run()
-    new = MultiSearch(tasks(), FleetConfig(stack_batches=True,
-                                           compile_ahead=False)).run()
+    new = MultiSearch(tasks(), FleetConfig(compile_ahead=False)).run()
     for name in old:
         assert old[name].best_edp == new[name].best_edp
         assert np.array_equal(old[name].history, new[name].history)
@@ -102,7 +112,7 @@ def test_legacy_kwargs_warn_and_match_config():
 def test_legacy_kwargs_conflict_with_config_is_an_error():
     t = [SearchTask(wl(), "cloud", budget=BUDGET)]
     with pytest.raises(ValueError, match="config"):
-        MultiSearch(t, FleetConfig(), stack_batches=True)
+        MultiSearch(t, FleetConfig(), compile_ahead=False)
 
 
 def test_config_spelling_does_not_warn():
@@ -113,7 +123,7 @@ def test_config_spelling_does_not_warn():
                     FleetConfig())
         search.run_method_sweep(
             ["random_mapper"], [wl()], "cloud", budget=BUDGET,
-            config=FleetConfig(stack_batches=True))
+            config=FleetConfig())
 
 
 # ------------------------------------------------ es_kw deprecation
@@ -227,8 +237,8 @@ def test_property_search_task_round_trip(kw):
 @settings(max_examples=25, deadline=None)
 @given(st.integers(0, 1), st.integers(0, 1), st.integers(0, 1),
        st.integers(1, 8))
-def test_property_fleet_config_round_trip(align, stack, pipe, dr):
+def test_property_fleet_config_round_trip(align, ahead, pipe, dr):
     cfg = FleetConfig(align_signatures=bool(align),
-                      stack_batches=bool(stack), pipeline=bool(pipe),
+                      compile_ahead=bool(ahead), pipeline=bool(pipe),
                       device_rounds=dr)
     assert FleetConfig.from_json(cfg.to_json()) == cfg

@@ -4,10 +4,10 @@
 * `_Budget.register` budget-truncation semantics (NaN tail, not inf),
 * explicit duplicate-task-name handling in `MultiSearch`,
 * mixed-method `MultiSearch` == sequential `search.run` at fixed seeds,
-* `stack_batches=True` (mega-batch dispatch) == `stack_batches=False`
-  bit-for-bit, with strictly fewer compilations AND dispatches than the
-  sequential equivalent,
-* `jax_cost.eval_stacked` == per-model calls, bit-for-bit.
+  with strictly fewer compilations AND dispatches than the sequential
+  equivalent,
+* `search.run` and a one-task `MultiSearch` share one compiled program
+  family per signature.
 """
 import numpy as np
 import pytest
@@ -159,26 +159,6 @@ def test_run_method_sweep_rejects_grid_collisions():
 # ------------------------------------------------- stacked evaluator
 
 
-def test_eval_stacked_bitexact_vs_per_model_calls():
-    a = spmm("stk_a", 32, 64, 48, 0.2, 0.5)
-    b = spmm("stk_b", 48, 32, 64, 0.4, 0.3)
-    sa, eva = search.get_evaluator(a, "cloud")
-    sb, evb = search.get_evaluator(b, "edge")
-    assert eva.signature == evb.signature
-    rng = np.random.default_rng(0)
-    ga, gb = sa.random_genomes(rng, 37), sb.random_genomes(rng, 50)
-    ra, rb = eva(ga), evb(gb)
-    oa, ob = jax_cost.eval_stacked([eva, evb], [ga, gb])
-    for k in ra:
-        np.testing.assert_array_equal(np.asarray(ra[k]), np.asarray(oa[k]))
-        np.testing.assert_array_equal(np.asarray(rb[k]), np.asarray(ob[k]))
-    # pad_floor (the sticky mega-batch shape) must not change results
-    (oa2,) = jax_cost.eval_stacked([eva], [ga], pad_floor=512)
-    for k in ra:
-        np.testing.assert_array_equal(np.asarray(ra[k]),
-                                      np.asarray(oa2[k]))
-
-
 def test_eval_stacked_caches_tiled_constants_per_fleet_epoch():
     """The per-task constants table on the device is uploaded again only
     when the group's set of tasks changes — repeated rounds of a steady
@@ -224,10 +204,10 @@ def test_eval_stacked_rejects_mixed_signatures():
 
 @pytest.fixture(scope="module")
 def sweep_runs():
-    """One shared (sequential, stacked, unstacked) run triple: sequential
-    `search.run` per (method, workload), then the same grid through
-    `MultiSearch` with and without mega-batch stacking, each from a cold
-    compile cache so compilation/dispatch counts are comparable."""
+    """One shared (sequential, stacked) run pair: sequential `search.run`
+    per (method, workload), then the same grid through one mega-batched
+    `MultiSearch`, each from a cold compile cache so compilation/dispatch
+    counts are comparable."""
     wls = [by_name(n) for n in WLS]
     search.clear_cache()
     seq = {m: {w.name: search.run(m, w, "cloud", budget=BUDGET, seed=0)
@@ -237,20 +217,11 @@ def sweep_runs():
     search.clear_cache()
     stacked_stats: dict = {}
     stacked = search.run_method_sweep(METHODS, wls, "cloud", budget=BUDGET,
-                                      seed=0, stack_batches=True,
-                                      stats_out=stacked_stats)
+                                      seed=0, stats_out=stacked_stats)
     stacked_counts = (jax_cost.compilation_count(),
                       stacked_stats["dispatches"])
-
-    search.clear_cache()
-    unstacked_stats: dict = {}
-    unstacked = search.run_method_sweep(METHODS, wls, "cloud",
-                                        budget=BUDGET, seed=0,
-                                        stack_batches=False,
-                                        stats_out=unstacked_stats)
-    return dict(seq=seq, stacked=stacked, unstacked=unstacked,
-                seq_counts=seq_counts, stacked_counts=stacked_counts,
-                stacked_stats=stacked_stats, unstacked_stats=unstacked_stats)
+    return dict(seq=seq, stacked=stacked, seq_counts=seq_counts,
+                stacked_counts=stacked_counts, stacked_stats=stacked_stats)
 
 
 def test_mixed_method_fleet_matches_sequential_exactly(sweep_runs):
@@ -267,16 +238,6 @@ def test_mixed_method_fleet_matches_sequential_exactly(sweep_runs):
                 np.testing.assert_array_equal(a.best_genome, b.best_genome)
 
 
-def test_stacked_matches_unstacked_bit_for_bit(sweep_runs):
-    for m in METHODS:
-        for w in WLS:
-            a = sweep_runs["unstacked"][m][w]
-            b = sweep_runs["stacked"][m][w]
-            assert a.best_edp == b.best_edp, (m, w)
-            np.testing.assert_array_equal(a.history, b.history,
-                                          err_msg=f"{m}/{w}")
-
-
 def test_stacked_sweep_fewer_compiles_and_dispatches(sweep_runs):
     seq_compiles, seq_dispatches = sweep_runs["seq_counts"]
     st_compiles, st_dispatches = sweep_runs["stacked_counts"]
@@ -289,8 +250,10 @@ def test_stacked_sweep_fewer_compiles_and_dispatches(sweep_runs):
     assert stats["signatures"] == \
         [(3, 16, ARCH_SPARSEMAP.topology.fingerprint, "u")]
     assert stats["dispatches"] == stats["rounds"]
-    # unstacked pays one dispatch per alive task per round
-    assert stats["dispatches"] < sweep_runs["unstacked_stats"]["dispatches"]
+    # the sequential runs pay one dispatch per task per round: the
+    # longest task alone makes as many as the fleet's rounds, and every
+    # other task at least one
+    assert seq_dispatches >= stats["rounds"] + len(METHODS) * len(WLS) - 1
 
 
 def test_run_method_sweep_grid_shape(sweep_runs):
@@ -300,3 +263,23 @@ def test_run_method_sweep_grid_shape(sweep_runs):
         assert sorted(grid[m]) == sorted(WLS)
         for w in WLS:
             assert grid[m][w].extras["method"] == m
+
+
+def test_single_search_and_one_task_fleet_share_one_program_family():
+    """`search.run` prices through the fleet's mega-batch program: after
+    it, a one-task `MultiSearch` of the same search compiles nothing new
+    (its padded shapes are ones the single search already ran) and
+    returns the same result."""
+    wl = spmm("one_family", 32, 64, 48, 0.2, 0.5)
+    search.clear_cache()
+    seq = search.run("sparsemap", wl, "cloud", budget=BUDGET, seed=4)
+    compiles = jax_cost.compilation_count()
+    assert compiles >= 1
+    ms = search.MultiSearch(
+        [search.SearchTask(wl, "cloud", budget=BUDGET, seed=4)],
+        search.FleetConfig(compile_ahead=False))
+    (res,) = ms.run().values()
+    assert jax_cost.compilation_count() == compiles
+    assert {k[4] for k in jax_cost._JIT_FNS} == {"stacked"}
+    assert res.best_edp == seq.best_edp
+    np.testing.assert_array_equal(res.history, seq.history)

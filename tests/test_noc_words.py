@@ -13,7 +13,7 @@ import pytest
 from repro.configs.archs import (CLUSTER_CLOUD, DSTC_LIKE, EYERISS_LIKE,
                                  MAPLE_EDGE, QUANT_EDGE, SIGMA_LIKE,
                                  SYSTOLIC_MESH)
-from repro.core import search
+from repro.core import jax_cost, search
 from repro.core.arch import (ARCH_SPARSEMAP, ArchSpec, NoCSpec,
                              StorageLevel)
 from repro.core.baselines import (fixed_mapping_genes_for_arch,
@@ -134,7 +134,8 @@ def test_fractional_noc_family_shares_one_compilation():
     m_a = JaxCostModel(GenomeSpec(wl, arch=a), a)
     m_b = JaxCostModel(GenomeSpec(wl, arch=b), b)
     assert m_a.signature == m_b.signature
-    assert m_a._fn is m_b._fn
+    job_a, job_b = (jax_cost.stacked_compile_job(m, 64) for m in (m_a, m_b))
+    assert job_a[0] == job_b[0] and job_a[1] is job_b[1]
     assert m_a.signature != JaxCostModel(GenomeSpec(wl, arch=c), c).signature
 
 

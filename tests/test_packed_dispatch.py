@@ -1,9 +1,10 @@
 """The stacked mega-batch's transfer scheme (`jax_cost.eval_stacked`):
 
 * one packed int32 row buffer and a device-resident per-task constants
-  table give results bit-identical to per-model broadcast calls, over
-  mixed-size, mixed-workload groups with padding, with tables of more
-  slots than tasks;
+  table give each task the rows a one-task call gives it, bit for bit,
+  over mixed-size, mixed-workload, mixed-arch groups with padding, with
+  tables of more slots than tasks, and rows the float64 oracle agrees
+  with;
 * a steady fleet round makes one rows upload and one result fetch, and
   uploads the table only when the group's set of tasks changes;
 * compile-ahead builds every (row bucket, table slots) pair a fleet
@@ -14,6 +15,7 @@ import pytest
 
 from repro.configs.paper_workloads import by_name
 from repro.core import jax_cost, search, trace
+from repro.core.cost_model import check_against_oracle
 from repro.core.search import FleetConfig, MultiSearch, SearchTask
 from repro.core.workload import spmm
 
@@ -34,44 +36,51 @@ def _group(names, sizes, seed=0):
     return models, batches
 
 
-def _assert_matches_broadcast(models, batches, outs):
+def _assert_matches_one_task_calls(models, batches, outs):
+    """Each task's rows equal its own one-task call's, bit for bit, and
+    the float64 oracle agrees with them under its shared rule — so a
+    packing or gather fault that hit both sides alike still fails."""
     for m, g, out in zip(models, batches, outs):
         want = m(g)
         assert list(out) == list(want)
         for k in want:
             np.testing.assert_array_equal(out[k], want[k])
+        agr = check_against_oracle(m.spec, m.arch, g, out)
+        assert agr.checked == len(g)
+        assert not agr.disagreements, agr.disagreements[:5]
 
 
 @pytest.mark.parametrize("names,sizes,pad_floor", [
     (("a", "b", "a", "c"), (37, 50, 5, 64), 0),       # 156 rows -> 256
     (("a", "b", "a", "c"), (37, 50, 5, 64), 1024),    # watermark floor
     (("c",), (3,), 0),                                # one task -> 64
-], ids=["mixed", "floor", "single"])
-def test_packed_rows_match_broadcast_calls(names, sizes, pad_floor,
-                                           monkeypatch):
+    (("a", "b"), (37, 50), 512),          # cloud + edge, sticky shape
+], ids=["mixed", "floor", "single", "two_archs_floor"])
+def test_packed_rows_match_one_task_calls(names, sizes, pad_floor,
+                                          monkeypatch):
     monkeypatch.setattr(jax_cost, "_STACK_TABLES", {})
     models, batches = _group(names, sizes)
     outs = jax_cost.eval_stacked(models, batches, pad_floor=pad_floor)
-    _assert_matches_broadcast(models, batches, outs)
     table = jax_cost._STACK_TABLES[models[0].signature]
     # a repeated workload shares its table row
     assert len(table.row_of) == len(set(names))
     assert table.dev.shape[0] == jax_cost.TABLE_FLOOR > len(set(names))
+    _assert_matches_one_task_calls(models, batches, outs)
 
 
 def test_grown_table_keeps_its_slots_and_results(monkeypatch):
     """Slots are a power of two that never shrinks per signature: after
     three tasks (4 slots) a one-task group still gets a 4-slot table,
-    and both groups price as the broadcast kernel does."""
+    and both groups price as one-task calls do."""
     monkeypatch.setattr(jax_cost, "_STACK_TABLES", {})
     monkeypatch.setattr(jax_cost, "TABLE_FLOOR", 1)
     models, batches = _group(("a", "b", "c"), (20, 9, 40), seed=3)
     sig = models[0].signature
-    _assert_matches_broadcast(models, batches,
-                              jax_cost.eval_stacked(models, batches))
+    _assert_matches_one_task_calls(models, batches,
+                                   jax_cost.eval_stacked(models, batches))
     assert jax_cost._STACK_TABLES[sig].dev.shape[0] == 4
     one = jax_cost.eval_stacked(models[1:2], batches[1:2])
-    _assert_matches_broadcast(models[1:2], batches[1:2], one)
+    _assert_matches_one_task_calls(models[1:2], batches[1:2], one)
     table = jax_cost._STACK_TABLES[sig]
     assert table.dev.shape[0] == 4 and len(table.row_of) == 1
     assert jax_cost.table_slots(sig, 1) == 4
@@ -95,8 +104,7 @@ def test_fleet_round_makes_one_upload_and_one_fetch(monkeypatch):
     monkeypatch.setattr(jax_cost, "_STACK_TABLES", {})
     tasks = [SearchTask(by_name("mm1"), "cloud", budget=1500, seed=0),
              SearchTask(by_name("mm3"), "cloud", budget=2500, seed=1)]
-    ms = MultiSearch(tasks, FleetConfig(stack_batches=True,
-                                        device_rounds=1,
+    ms = MultiSearch(tasks, FleetConfig(device_rounds=1,
                                         compile_ahead=False))
     ms.start()
     prev, step, uploads, quiet = None, 0, 0, 0
@@ -143,8 +151,7 @@ def test_compile_ahead_covers_every_stacked_dispatch(monkeypatch):
     monkeypatch.setattr(jax_cost, "_aot_call", logged)
     tasks = [SearchTask(by_name(w), "cloud", budget=700, seed=3)
              for w in ("mm1", "mm2", "mm3")]
-    ms = MultiSearch(tasks, FleetConfig(stack_batches=True,
-                                        device_rounds=1))
+    ms = MultiSearch(tasks, FleetConfig(device_rounds=1))
     ms.run()
     assert seen and all(hit for _, hit in seen), \
         sorted({k for k, hit in seen if not hit})

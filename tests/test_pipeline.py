@@ -23,10 +23,13 @@ from repro.configs.paper_workloads import by_name, structured_workloads
 from repro.core import es_ops, jax_cost, search
 from repro.core.direct_encoding import DirectValueSpec
 from repro.core.es_ops import DeviceSegment
+from repro.core.workload import spmm
 
 BUDGET = 700
 SEED = 3
 K = 4
+#: a workload on another signature than mm2/mm3's (prime bucket 32)
+OTHER_SIG = spmm("other_sig", 128, 256, 512, 0.1, 0.9)
 
 
 def _grid_equal(a, b):
@@ -48,7 +51,7 @@ def _sweep(pipeline, compile_ahead=True, device_rounds=K, stats=None):
     wls = [by_name("mm1"), structured_workloads()[0]]
     return search.run_method_sweep(
         ["sparsemap", "standard_es", "pso"], wls, "cloud",
-        budget=BUDGET, seed=SEED, stack_batches=True,
+        budget=BUDGET, seed=SEED,
         device_rounds=device_rounds, pipeline=pipeline,
         compile_ahead=compile_ahead, stats_out=stats)
 
@@ -142,7 +145,7 @@ def test_standard_es_segments_match_host_loop():
     def go(device_execute):
         return search.run_method_sweep(
             ["standard_es"], wls, "cloud", budget=BUDGET, seed=SEED,
-            stack_batches=True, device_rounds=K,
+            device_rounds=K,
             device_execute=device_execute)
 
     _grid_equal(go(True), go(False))
@@ -158,7 +161,7 @@ def test_restart_segment_matches_host_replay():
     def go(device_execute, stats):
         return search.run_method_sweep(
             ["sparsemap"], wls, "cloud", budget=BUDGET, seed=SEED,
-            stack_batches=True, device_rounds=K,
+            device_rounds=K,
             device_execute=device_execute, method_kw=kw, stats_out=stats)
 
     sa, sb = {}, {}
@@ -177,7 +180,8 @@ def test_compile_ahead_hits_and_misses():
     search._CACHE.clear()
     spec, ev = search.get_evaluator(wl, "cloud")
     jax_cost.reset_compile_ahead_counts()
-    jax_cost.compile_ahead([jax_cost.bcast_compile_job(ev, 64)], wait=True)
+    jax_cost.compile_ahead([jax_cost.stacked_compile_job(ev, 64)],
+                           wait=True)
     rng = np.random.default_rng(0)
     ev(spec.random_genomes(rng, 10))        # pads to 64 -> AOT hit
     assert jax_cost.compile_ahead_counts() == (1, 0)
@@ -193,12 +197,14 @@ def test_unclaimed_families_never_count_misses():
     jax_cost.clear_compile_cache()
     search._CACHE.clear()
     spec, ev = search.get_evaluator(wl, "cloud")
+    _, other = search.get_evaluator(OTHER_SIG, "cloud")
+    assert other.signature != ev.signature
     jax_cost.reset_compile_ahead_counts()
-    # compile-ahead runs for an unrelated stacked family only
-    jax_cost.compile_ahead([jax_cost.stacked_compile_job(ev, 256)],
+    # compile-ahead runs for an unrelated family only
+    jax_cost.compile_ahead([jax_cost.stacked_compile_job(other, 256)],
                            wait=True)
     rng = np.random.default_rng(0)
-    ev(spec.random_genomes(rng, 10))        # bcast family unclaimed
+    ev(spec.random_genomes(rng, 10))        # mm3's family unclaimed
     assert jax_cost.compile_ahead_counts() == (0, 0)
 
 
@@ -212,7 +218,7 @@ def test_compile_ahead_failures_are_counted_not_swallowed():
     def broken(x):
         raise ValueError("forced lowering failure")
 
-    key = (3, 16, "forced", "u", "bcast", 4)
+    key = (3, 16, "forced", "u", "stacked", 4)
     job = (key, jax.jit(broken), (jax.ShapeDtypeStruct((4,), np.float32),))
     jax_cost.compile_ahead([job], wait=True)
     n, first = jax_cost.compile_ahead_errors()
@@ -239,11 +245,13 @@ def test_compile_ahead_claims_are_per_pass():
     jax_cost.clear_compile_cache()
     search._CACHE.clear()
     spec, ev = search.get_evaluator(wl, "cloud")
-    jax_cost.compile_ahead([jax_cost.bcast_compile_job(ev, 64)], wait=True)
-    jax_cost.compile_ahead([jax_cost.stacked_compile_job(ev, 256)],
+    _, other = search.get_evaluator(OTHER_SIG, "cloud")
+    jax_cost.compile_ahead([jax_cost.stacked_compile_job(ev, 64)],
+                           wait=True)
+    jax_cost.compile_ahead([jax_cost.stacked_compile_job(other, 256)],
                            wait=True)
     jax_cost.reset_compile_ahead_counts()
-    ev(spec.random_genomes(np.random.default_rng(0), 100))  # bcast, 128
+    ev(spec.random_genomes(np.random.default_rng(0), 100))  # mm3's, 128
     assert jax_cost.compile_ahead_counts() == (0, 0)
 
 

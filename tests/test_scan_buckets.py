@@ -109,8 +109,7 @@ def test_served_fleet_builds_one_scan_program_per_bucket():
     dispatch that needs it."""
     search.clear_cache()
     t0 = time.perf_counter()
-    srv = SweepServer(port=0, config=FleetConfig(stack_batches=True,
-                                                 device_rounds=8))
+    srv = SweepServer(port=0, config=FleetConfig(device_rounds=8))
     srv.start_background()
     ends = []
 
@@ -149,8 +148,7 @@ def test_fleet_builds_every_bucket_ahead(compile_ahead):
     t0 = time.perf_counter()
     tasks = [SearchTask(by_name(w), "cloud", budget=700, seed=3)
              for w in ("mm1", "mm2", "mm3")]
-    ms = MultiSearch(tasks, FleetConfig(stack_batches=True,
-                                        device_rounds=4,
+    ms = MultiSearch(tasks, FleetConfig(device_rounds=4,
                                         compile_ahead=compile_ahead))
     ms.run()
     _, builds = _scan_events(t0)

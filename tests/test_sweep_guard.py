@@ -1,42 +1,41 @@
-"""Per-topology pad-watermark policies (MultiSearch) and the CI
-BENCH_sweep.json regression gate (benchmarks/compare_sweep.py)."""
+"""The mega-batch pad watermark's grow/decay rule (MultiSearch) and the
+CI BENCH_sweep.json regression gate (benchmarks/compare_sweep.py)."""
 import copy
 import time
 
 import numpy as np
 import pytest
 
-from benchmarks.compare_sweep import compare, stale_policy_warnings
-from repro.core import jax_cost, search, trace
+from benchmarks.compare_sweep import compare
+from repro.core import jax_cost, trace
 from repro.core.arch import ARCH_SPARSEMAP
-from repro.core.search import (PAD_DECAYS, FleetConfig, MultiSearch,
-                               PadPolicy, SearchTask, pad_policy_for,
-                               set_pad_policy)
+from repro.core.arch_dsl import compile_arch
+from repro.core.search import (PAD_DECAY_ROUNDS, PAD_DECAYS, FleetConfig,
+                               MultiSearch, SearchTask)
 from repro.core.workload import spmm
 
 WL_A = spmm("pad_a", 32, 64, 48, 0.2, 0.5)
 WL_B = spmm("pad_b", 48, 32, 64, 0.4, 0.3)
 
 
-def _fleet(**kw):
-    tasks = [SearchTask(WL_A, "cloud", budget=300, seed=0,
+def _fleet(platform="cloud", budget=300):
+    tasks = [SearchTask(WL_A, platform, budget=budget, seed=0,
                         method="random_mapper"),
-             SearchTask(WL_B, "cloud", budget=300, seed=0,
+             SearchTask(WL_B, platform, budget=budget, seed=0,
                         method="sparsemap")]
-    return MultiSearch(tasks, stack_batches=True, **kw)
+    return MultiSearch(tasks)
+
+
+def _first_decay(hist):
+    """(rounds the watermark held its round-1 value, its value after)."""
+    held = next(i for i, h in enumerate(hist) if h != hist[0])
+    return held, hist[held]
 
 
 def test_pad_watermark_history_recorded_per_topology():
     ms = _fleet()
     ms.run()
     fp = ARCH_SPARSEMAP.topology.fingerprint
-    assert list(ms.stats["pad_policies"]) == [fp]
-    # the paper topology carries the measured policy derived from the
-    # committed baseline trajectory (configs.archs), not the default:
-    # earlier decay (2 quiet rounds), ratio tightened to the observed
-    # post-spike plateau (256/2048)
-    assert ms.stats["pad_policies"][fp] == \
-        {"decay_rounds": 2, "decay_ratio": 0.125, "source": "measured"}
     wms = ms.stats["pad_watermarks"]
     assert len(wms) == 1
     (key, hist), = wms.items()
@@ -45,32 +44,37 @@ def test_pad_watermark_history_recorded_per_topology():
     assert all(h >= 64 for h in hist)       # the pad floor
 
 
-def test_pad_policy_override_and_registry():
-    aggressive = PadPolicy(decay_rounds=1, decay_ratio=1.0)
-    fp = ARCH_SPARSEMAP.topology.fingerprint
-    ms = _fleet(pad_policies={fp: aggressive})
-    res_o = ms.run()
-    assert ms.stats["pad_policies"][fp] == \
-        {"decay_rounds": 1, "decay_ratio": 1.0, "source": "default"}
-    (_, hist_o), = ms.stats["pad_watermarks"].items()
-    ms_d = _fleet()
-    res_d = ms_d.run()
-    (_, hist_d), = ms_d.stats["pad_watermarks"].items()
-    # an always-decay policy tracks each round's own shape, so its
-    # watermark can only be at or below the sticky default's
-    assert len(hist_o) == len(hist_d)
-    assert all(o <= d for o, d in zip(hist_o, hist_d))
-    # padding rows are inert: results are identical under either policy
-    for name in res_d:
-        assert res_d[name].best_edp == res_o[name].best_edp
-        assert np.array_equal(res_d[name].history, res_o[name].history)
-    # the global registry is consulted when no override is passed
-    try:
-        set_pad_policy("deadbeef", aggressive)
-        assert pad_policy_for("deadbeef") == aggressive
-        assert pad_policy_for("not_registered") == PadPolicy()
-    finally:
-        search._PAD_POLICIES.pop("deadbeef", None)
+def test_unregistered_topology_decays_like_the_zoo():
+    """One decay rule for every topology: on an arch declared here,
+    whose topology no configuration registers, the round-1 spike (the
+    random mapper's first chunk) falls after PAD_DECAY_ROUNDS = 2 quiet
+    rounds, as it does on the paper's cloud arch."""
+    from repro.configs.archs import ACCEL_ARCHS, ZOO_ARCHS
+    arch = compile_arch({
+        "name": "pad_rule_probe",
+        "levels": [
+            {"name": "dram"},
+            {"name": "glb", "capacity": "48KB",
+             "energy": [["dram", [90.0]]], "sg_site": "L2"},
+            {"name": "reg", "energy": [["glb", [2.5]]], "fanout": [4, 4],
+             "noc": {"multicast": "row", "reduction": ["cluster", 2]}},
+        ],
+    })
+    known = {a.topology.fingerprint
+             for a in (ARCH_SPARSEMAP, *ZOO_ARCHS.values(),
+                       *ACCEL_ARCHS.values())}
+    assert arch.topology.fingerprint not in known
+    hists = []
+    for platform in (arch, "cloud"):
+        ms = _fleet(platform, budget=600)
+        assert all(r.evals >= 600 for r in ms.run().values())
+        (hist,) = ms.stats["pad_watermarks"].values()
+        hists.append(hist)
+        # the spike's round and the first quiet round hold it; the
+        # second quiet round decays it
+        assert _first_decay(hist)[0] == PAD_DECAY_ROUNDS == 2
+        assert _first_decay(hist)[1] < hist[0]
+    assert _first_decay(hists[0]) == _first_decay(hists[1])
 
 
 def _admitting_fleet(pin_floor: int = 0):
@@ -99,7 +103,7 @@ def _admitting_fleet(pin_floor: int = 0):
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax_cost, "eval_stacked", logged)
         ms = MultiSearch([SearchTask(WL_B, "cloud", budget=3000, seed=2)],
-                         FleetConfig(stack_batches=True))
+                         FleetConfig())
         steps = 0
         while ms.step():
             steps += 1
@@ -125,9 +129,6 @@ def admitted_spikes():
 def test_watermark_falls_back_to_shapes_the_fleet_has_run(admitted_spikes,
                                                           case):
     ms, log = admitted_spikes["ms"], admitted_spikes["log"]
-    fp = ARCH_SPARSEMAP.topology.fingerprint
-    assert ms.stats["pad_policies"][fp] == \
-        {"decay_rounds": 2, "decay_ratio": 0.125, "source": "measured"}
     (wm,) = ms.stats["pad_watermarks"].values()
     targets = [jax_cost._pad_batch(rows) for rows, _, _, _ in log]
     spike = targets.index(2048)
@@ -240,13 +241,10 @@ def test_committed_baseline_is_well_formed():
         # fleet (cloud_device_k4) folds k generations per dispatch
         assert a["dispatches_per_round"] <= 1.0
         assert a["host_syncs_per_round"] <= 1.0
-        assert a["pad_watermarks"] and a["pad_policies"]
+        assert a["pad_watermarks"]
     k4 = {a["arch"]: a for a in base["archs"]}["cloud_device_k4"]
     assert k4["device_rounds"] == 4
     assert k4["host_syncs_per_round"] <= 1 / 4
-    # no stale-policy warnings against the baseline itself: registered
-    # policies must match what its own trajectories derive
-    assert stale_policy_warnings(base) == []
 
 
 def test_compare_sweep_fails_on_host_sync_regression():
@@ -259,40 +257,6 @@ def test_compare_sweep_fails_on_host_sync_regression():
     # absent on either side (old baseline) -> not comparable, no failure
     failures, _ = compare(BASE, cur)
     assert failures == []
-
-
-def test_stale_policy_warning_fires_on_mismatched_trajectory():
-    rec = dict(archs=[dict(
-        arch="cloud",
-        # one-off spike, never re-grows -> derivation says decay_rounds=2
-        pad_watermarks={"d3_p16_feedf00d": [2048, 2048, 2048, 256, 256]},
-        pad_policies={"feedf00d": {"decay_rounds": 3,
-                                   "decay_ratio": 0.5}})])
-    warns = stale_policy_warnings(rec)
-    assert len(warns) == 1 and "decay_rounds=2" in warns[0]
-    # re-growing trajectory matches the conservative registered policy
-    rec["archs"][0]["pad_watermarks"]["d3_p16_feedf00d"] = \
-        [2048, 256, 2048, 256, 2048]
-    assert stale_policy_warnings(rec) == []
-
-
-def test_stale_policy_warning_promotes_seed_policies():
-    """A policy still carrying source="seed" after a run that measured
-    the topology's real trajectory asks for promotion to the baseline
-    watermark table — even when decay_rounds already agrees."""
-    rec = dict(archs=[dict(
-        arch="sigma_like",
-        pad_watermarks={"d3_p16_8b2430a8": [2048, 2048, 256, 256]},
-        pad_policies={"8b2430a8": {"decay_rounds": 2,
-                                   "decay_ratio": 0.125,
-                                   "source": "seed"}})])
-    warns = stale_policy_warnings(rec)
-    assert len(warns) == 1
-    assert "seed pad policy" in warns[0]
-    assert "_SEED_PAD_WATERMARKS" in warns[0]
-    # once promoted (source measured), the same record is quiet
-    rec["archs"][0]["pad_policies"]["8b2430a8"]["source"] = "measured"
-    assert stale_policy_warnings(rec) == []
 
 
 def test_compare_sweep_fails_when_arch_disappears():

@@ -36,7 +36,7 @@ from repro.launch.sweep_serve import (GenomeLibrary, SweepServer,
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BUDGET = 800
-CFG = FleetConfig(stack_batches=True, device_rounds=1)
+CFG = FleetConfig(device_rounds=1)
 
 
 def task(name="wa", m=16, seed=5, budget=BUDGET, method="sparsemap",
@@ -55,8 +55,7 @@ def test_admission_coalesces_into_shared_mega_batch():
     that started with both tasks (admission itself is compile-free; the
     only new shape is the bigger mega-batch, which the from-start fleet
     pays for too)."""
-    cfg = FleetConfig(stack_batches=True, device_rounds=1,
-                      compile_ahead=False)   # deterministic counts
+    cfg = FleetConfig(device_rounds=1, compile_ahead=False)   # deterministic counts
 
     jax_cost.clear_compile_cache()
     MultiSearch([task("a1", seed=5), task("a2", seed=6)], cfg).run()
@@ -265,7 +264,7 @@ def test_config_fragment_mismatch_rejected():
     srv.start_background()
     try:
         msg = {"op": "submit", "task": task("cf").to_json_dict(),
-               "config": FleetConfig(stack_batches=False).to_json_dict()}
+               "config": FleetConfig(device_rounds=2).to_json_dict()}
         evs = list(sweep_serve.request(srv.host, srv.port, msg))
         assert not evs[0]["ok"] and "disagrees" in evs[0]["error"]
     finally:

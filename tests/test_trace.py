@@ -149,8 +149,7 @@ def served():
     """A small sweep server: two concurrent queries inside a window,
     then one more query in an epoch of its own, with a ``stats`` reading
     after each epoch."""
-    srv = SweepServer(port=0, config=FleetConfig(stack_batches=True,
-                                                 device_rounds=1),
+    srv = SweepServer(port=0, config=FleetConfig(device_rounds=1),
                       warm_start=False)
     srv.start_background()
     try:
@@ -253,8 +252,7 @@ def test_spans_land_in_the_profiler_host_plane(tmp_path):
     import jax
     devtrace = _bench_module("devtrace")
     ms = MultiSearch([_task("pt", 16, 3, budget=400)],
-                     FleetConfig(stack_batches=True, device_rounds=1,
-                                 compile_ahead=False))
+                     FleetConfig(device_rounds=1, compile_ahead=False))
     ms.start()
     ms.step()                   # compiles outside the trace
 
@@ -314,13 +312,12 @@ def test_kernel_module_names_match_the_benchmark():
     dspec = DirectValueSpec(spec)
     got = {
         "stacked": _module_name(jax_cost.stacked_compile_job(ev, 64)),
-        "bcast": _module_name(jax_cost.bcast_compile_job(ev, 64)),
         "scan": _module_name(jax_cost.scan_compile_job(ev, 20, 2, 8, 2,
                                                        2, 2)),
         "dscan": _module_name(jax_cost.direct_scan_compile_job(
             ev, 20, 2, 8, 2, 2, 2, dspec.length, dspec.n_perm_codes)),
     }
-    assert got["stacked"] == got["bcast"] == f"jit_{jax_cost.EVAL_PROGRAM}"
+    assert got["stacked"] == f"jit_{jax_cost.EVAL_PROGRAM}"
     assert got["scan"] == got["dscan"] == f"jit_{jax_cost.SCAN_PROGRAM}"
     assert set(got.values()) == set(want)
 

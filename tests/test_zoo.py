@@ -1,7 +1,7 @@
 """The accelerator zoo: published-accelerator design points declared
 through the DSL frontend — registry resolution and its error surface,
-the pinned published-vs-modeled validation table, pad-policy seeds for
-pre-baseline topologies, and the end-to-end CI-gated sweeps."""
+the pinned published-vs-modeled validation table, and the end-to-end
+CI-gated sweeps."""
 import json
 import os
 
@@ -11,9 +11,7 @@ import pytest
 from repro.configs.archs import (ACCEL_ARCHS, ZOO_ARCHS,
                                  zoo_validation_report)
 from repro.core import search
-from repro.core.arch import (UnknownArchError, as_arch, register_arch,
-                             registered_archs)
-from repro.core.arch_dsl import compile_arch
+from repro.core.arch import UnknownArchError, as_arch, registered_archs
 from repro.core.workload import spmm
 
 VALIDATION = os.path.join(os.path.dirname(__file__), "golden",
@@ -72,57 +70,6 @@ def test_zoo_validation_table_matches_published_numbers():
             pub = pin["published"]
             assert abs(got - pub) <= tol * abs(pub) + 1e-9, \
                 f"{arch_name}.{check}: modeled {got} vs published {pub}"
-
-
-# ----------------------------------------------------------- pad policy
-
-
-def test_zoo_pad_policies_are_registered_not_inherited():
-    """Zoo topologies never silently inherit the default pad policy:
-    each has a registered policy (measured from the committed baseline),
-    while a genuinely unknown topology gets the documented explicit
-    default."""
-    for name, spec in ZOO_ARCHS.items():
-        pol = search.pad_policy_for(spec.topology.fingerprint)
-        assert pol.source == "measured", name
-        assert pol == search.PadPolicy(decay_rounds=2, decay_ratio=0.125,
-                                       source="measured")
-    assert search.pad_policy_for("no_such_topology") \
-        is search.DEFAULT_PAD_POLICY
-    assert search.DEFAULT_PAD_POLICY.source == "default"
-
-
-def test_seed_pad_policy_mechanism():
-    """A brand-new topology declared ahead of its first baseline run:
-    its seed trajectory registers with source="seed" (and would be
-    flagged for promotion by compare_sweep once measured), and a
-    measured registration overrides the seed."""
-    probe = register_arch(compile_arch({
-        "name": "zoo_seed_probe",
-        "levels": [
-            {"name": "dram"},
-            {"name": "glb", "capacity": "32KB",
-             "energy": [["dram", [100.0]]], "sg_site": "L2"},
-            {"name": "reg", "energy": [["glb", [3.0]]],
-             "fanout": [4, 8],
-             "noc": {"multicast": "row", "reduction": ["cluster", 4]}},
-        ],
-    }), replace=True)
-    fp = probe.topology.fingerprint
-    seed = search.derive_pad_policy((2048, 2048, 256, 256), source="seed")
-    assert seed.source == "seed"
-    assert seed.decay_rounds == 2
-    search.set_pad_policy(fp, seed)
-    try:
-        assert search.pad_policy_for(fp) == seed
-        measured = search.derive_pad_policy((2048, 2048, 256, 256))
-        assert measured.source == "measured"
-        search.set_pad_policy(fp, measured)
-        assert search.pad_policy_for(fp).source == "measured"
-    finally:
-        search._PAD_POLICIES.pop(fp, None)
-        from repro.core import arch as arch_mod
-        arch_mod._REGISTRY.pop("zoo_seed_probe", None)
 
 
 # ------------------------------------------------------------------ e2e

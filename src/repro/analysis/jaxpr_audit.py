@@ -222,14 +222,13 @@ def _trace_scan(model, restart: int = 0):
     ``run_segments`` fold), un-jitted, one task, tiny shapes."""
     import jax
 
-    from repro.core.jax_cost import (_per_task_carry, _scan_task_fn,
-                                     scan_compile_job)
+    from repro.core.jax_cost import _scan_program, scan_compile_job
     _, _, structs = scan_compile_job(model, B=_TRACE_B, k=2, n_parents=2,
                                      n_elite=1, genes_per=2, T=1,
                                      restart=restart)
-    fn = _per_task_carry(_scan_task_fn(model.d, model.n_pad,
-                                       model.arch.topology, model.dens_key,
-                                       2, 1, 2, restart))
+    fn = _scan_program(model.d, model.n_pad, model.arch.topology,
+                       model.dens_key, 2, 1, 2, restart,
+                       model._const_layout)
     return jax.make_jaxpr(fn)(*_zeros_like_structs(structs))
 
 
@@ -238,15 +237,13 @@ def _trace_direct_scan(model):
     import jax
 
     from repro.core.direct_encoding import DirectValueSpec
-    from repro.core.jax_cost import (_direct_scan_task_fn,
-                                     _per_task_carry,
+    from repro.core.jax_cost import (_direct_scan_program,
                                      direct_scan_compile_job)
-    dspec = DirectValueSpec(model.spec)
     _, _, structs = direct_scan_compile_job(
         model, B=_TRACE_B, k=2, n_parents=2, n_elite=1, genes_per=2,
-        T=1, direct_len=dspec.length, n_perm_codes=dspec.n_perm_codes)
-    fn = _per_task_carry(_direct_scan_task_fn(
-        model.d, model.n_pad, model.arch.topology, model.dens_key, 2, 1, 2))
+        T=1, direct_len=DirectValueSpec(model.spec).length)
+    fn = _direct_scan_program(model.d, model.n_pad, model.arch.topology,
+                              model.dens_key, 2, 1, 2, model._const_layout)
     return jax.make_jaxpr(fn)(*_zeros_like_structs(structs))
 
 
@@ -349,6 +346,8 @@ def check_aot_job(key: Tuple, fn, arg_structs) -> List[Violation]:
     is a silently wasted compile."""
     import jax
 
+    from repro.core.jax_cost import (SCAN_TAG_RE, _fields_width,
+                                     _scan_in_fields)
     out: List[Violation] = []
     where = "aot:" + "/".join(str(k) for k in key[:5])
 
@@ -381,24 +380,29 @@ def check_aot_job(key: Tuple, fn, arg_structs) -> List[Violation]:
         if table.shape[0] != key[6] or table.dtype != np.float32:
             bad(f"stacked table {table.shape} {table.dtype} is not "
                 f"({key[6]}, width) float32 as the key says")
-    elif isinstance(tag, str) and (tag.startswith("scan:")
-                                   or tag.startswith("dscan:")):
-        if len(key) != 9:
+    elif isinstance(tag, str) and SCAN_TAG_RE.match(tag):
+        if len(key) != 10 or len(arg_structs) != 3:
             bad(f"scan-family key {key!r} must be sig + (tag, T, B, k, "
-                f"n_children)")
+                f"n_children, table slots) over (carries, rows, table)")
             return out
-        T, B, k, n_children = key[5], key[6], key[7], key[8]
-        pops = arg_structs[0]
-        if len(pops) != T or pops[0].shape[0] != B:
-            bad(f"{tag} population structs {len(pops)} x {pops[0].shape} "
-                f"!= (T={T}) x (B={B}, ...) in the key")
-        draws = arg_structs[5] if tag.startswith("scan:") else \
-            arg_structs[4]
-        if not isinstance(draws, dict) or "ab" not in draws:
-            bad(f"{tag} job args missing the draws dict")
-        elif draws["ab"].shape != (T, k, n_children, 2):
-            bad(f"{tag} draws['ab'] struct {draws['ab'].shape} != "
-                f"(T={T}, k={k}, n_children={n_children}, 2) in the key")
+        T, B, k, n_children, slots = key[5:10]
+        carries, rows, table = arg_structs
+        if len(carries) != T or carries[0].shape[0] != B:
+            bad(f"{tag} carry structs {len(carries)} x "
+                f"{carries[0].shape} != (T={T}) x (B={B}, ...) in the key")
+            return out
+        fam, _, _, genes_per, restart = SCAN_TAG_RE.match(tag).groups()
+        kind = "direct" if fam == "dscan" else "es"
+        width = _fields_width(_scan_in_fields(
+            kind, k, n_children, int(genes_per), carries[0].shape[1] - 1,
+            int(restart or 0), d))
+        if rows.shape != (T, width) or rows.dtype != np.int32:
+            bad(f"{tag} packed rows {rows.shape} {rows.dtype} are not "
+                f"(T={T}, {width}) int32 for k={k}, "
+                f"n_children={n_children} in the key")
+        if table.shape[0] != slots or table.dtype != np.float32:
+            bad(f"{tag} table {table.shape} {table.dtype} is not "
+                f"({slots}, width) float32 as the key says")
     else:
         bad(f"unknown AOT tag {tag!r}")
     return out

@@ -318,9 +318,10 @@ class DeviceSegment:
     rng_backend: str = "numpy"
     # pipelined dispatch (COMPAT.md "Pipelined dispatch contract"):
     # ``carry`` holds the previous segment's device-resident PADDED
-    # (pop, edp) pair — when set, drivers feed the scan from it directly
-    # and ``pop``/``edp`` are only the host-side fallback of record.
-    carry: Optional[Tuple] = None
+    # carry (``jax_cost`` packs pop and edp into one array) — when set,
+    # drivers feed the scan from it directly and ``pop``/``edp`` are only
+    # the host-side fallback of record.
+    carry: Optional[object] = None
     # segment flavor: "es" runs in canonical genome coordinates;
     # "direct" carries direct-value genomes plus the translation tables
     # in ``aux`` (scramble, dim_sizes) and translates rows in-scan.
@@ -346,14 +347,14 @@ class SegmentResult:
     thunk that converts the device outputs to numpy on first call —
     request generators call :meth:`resolve` one round late, so the
     blocking conversion overlaps the next segment's device execution.
-    ``carry`` always holds the device-resident PADDED (pop, edp) pair for
+    ``carry`` always holds the device-resident PADDED (pop, edp) carry for
     the follow-up segment, and ``state`` the device (best, since) restart
     carry when the segment folded stagnation restarts."""
 
     gens: List[Tuple[np.ndarray, Dict[str, np.ndarray]]]
     final_pop: Optional[np.ndarray]  # (B, L) int64, unpadded
     final_edp: Optional[np.ndarray]  # (B,) float32
-    carry: Optional[Tuple] = None    # device-resident padded (pop, edp)
+    carry: Optional[object] = None   # device-resident padded (pop, edp)
     state: Optional[Tuple] = None    # device (best, since) restart carry
     harvest: Optional[Callable] = None
 

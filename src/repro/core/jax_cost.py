@@ -36,6 +36,8 @@ the fiber-tree byte accounting via a lax.scan over the loop slots.
 from __future__ import annotations
 
 import dataclasses
+import math
+import re
 import threading
 from functools import lru_cache
 from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
@@ -99,9 +101,9 @@ def _bucket(n: int, size: int = 16) -> int:
 _JIT_FNS: Dict[Tuple[int, int, str, str, str], object] = {}
 
 # One reentrant lock guards every module-level registry
-# (_JIT_FNS/_SHARD_FNS/_STACK_TABLES/_AOT_*) and the reset baselines:
-# the compile-ahead worker mutates them from its background thread while
-# the search thread dispatches, so bare stores are not safe.
+# (_JIT_FNS/_SHARD_FNS/_STACK_TABLES/_FILLERS/_AOT_*) and the reset
+# baselines: the compile-ahead worker mutates them from its background
+# thread while the search thread dispatches, so bare stores are not safe.
 _LOCK = threading.RLock()
 
 # The module's counters live in the trace recorder (``core/trace.py``),
@@ -118,6 +120,7 @@ CA_ERRORS = "compile_ahead.errors"
 PREP_HITS = "fleet.stack_prep.hits"       # constants tables reused
 PREP_MISSES = "fleet.stack_prep.misses"   # constants tables uploaded
 TRANSFERS = "fleet.transfers"   # host<->device copies of the stacked path
+SCAN_TRANSFERS = "fleet.scan_transfers"   # host<->device copies of scans
 ROWS = "fleet.rows"                 # genome rows sent to the device
 ROWS_PADDED = "fleet.rows_padded"   # padding rows sent beside them
 SCAN_TASKS = "fleet.scan_tasks"     # real tasks of a scan dispatch
@@ -433,6 +436,7 @@ def clear_compile_cache() -> None:
         _JIT_FNS.clear()
         _SHARD_FNS.clear()
         _STACK_TABLES.clear()
+        _FILLERS.clear()
         _AOT_FNS.clear()
         _AOT_PENDING.clear()
         _CA_PREFIXES.clear()
@@ -1098,39 +1102,226 @@ def _scan_task_fn(d: int, n_pad: int, topo: Topology, dens_key: str,
                     in_axes=(0, 0, 0, 0, 0, 0, 0))
 
 
-def _per_task_carry(vfn: Callable) -> Callable:
-    """The scan program as dispatched: the carry (pop, edp) enters and
-    leaves as one array per task, stacked and split inside the program.
-    Stacking or slicing it outside would run eager XLA ops, whose
-    programs compile anew for every task count and task index.  The
-    stacked final (pop, edp) comes back too: a pipelined driver reads it
-    after the next dispatch has taken (donated) the per-task carries."""
-    def program(pops, edps, *rest):
-        pop_f, edp_f, ys = vfn(jnp.stack(pops), jnp.stack(edps), *rest)
-        n = len(pops)
-        return (pop_f, edp_f, ys, tuple(pop_f[i] for i in range(n)),
-                tuple(edp_f[i] for i in range(n)))
+# ------------------------------------------ packed scan rows and results
+#
+# A scan dispatch sends its per-slot inputs up as ONE (slots, W) int32
+# buffer and its results come back as ONE (slots, W') int32 buffer: each
+# slot's row holds named fields end to end (``_scan_in_fields``,
+# ``_scan_out_fields``), float32 fields by exact bitcast and flags as
+# 0/1.  A task's carry is one (B, width + 1) int32 array: its padded
+# population and, in the last column, the bits of its float32 edp.
+
+_I32, _F32, _BOOL = np.dtype(np.int32), np.dtype(np.float32), \
+    np.dtype(np.bool_)
+
+
+def _fields_width(fields: Tuple) -> int:
+    return sum(int(np.prod(shape)) for _, shape, _ in fields)
+
+
+def _as_i32(x):
+    """``x`` as int32 of the same shape, numpy or jax alike: float32 bit
+    for bit, bools and integers by value."""
+    if isinstance(x, np.ndarray):
+        if x.dtype == _F32:
+            return np.ascontiguousarray(x).view(np.int32)
+        return x.astype(np.int32)
+    if x.dtype == jnp.float32:
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+    return x.astype(jnp.int32)
+
+
+def _from_i32(x, dtype: np.dtype):
+    """The inverse of :func:`_as_i32` for a field of ``dtype``."""
+    if dtype == _F32:
+        if isinstance(x, np.ndarray):
+            return x.view(np.float32)
+        return jax.lax.bitcast_convert_type(x, jnp.float32)
+    if dtype == _BOOL:
+        return x != 0
+    return x
+
+
+def _split_fields(buf, fields: Tuple) -> Dict:
+    """The named fields of a packed (n, W) int32 buffer, numpy or jax
+    alike, each (n, *shape) in its own dtype."""
+    out, off = {}, 0
+    for name, shape, dtype in fields:
+        size = int(np.prod(shape))
+        out[name] = _from_i32(
+            buf[:, off:off + size].reshape((-1,) + shape), dtype)
+        off += size
+    return out
+
+
+def _pack_slots(per_task: Sequence[Dict], fields: Tuple,
+                slots: int) -> np.ndarray:
+    """The tasks' fields packed into one (slots, W) int32 buffer; the
+    filler slots repeat the first task's row."""
+    T = len(per_task)
+    buf = np.empty((slots, _fields_width(fields)), np.int32)
+    off = 0
+    for name, shape, dtype in fields:
+        size = int(np.prod(shape))
+        col = np.stack([np.asarray(v[name], dtype) for v in per_task])
+        buf[:T, off:off + size] = _as_i32(col).reshape(T, size)
+        off += size
+    buf[T:] = buf[0]
+    return buf
+
+
+def _scan_in_fields(kind: str, k: int, C: int, genes_per: int,
+                    width: int, restart: int, d: int) -> Tuple:
+    """(name, shape, dtype) of one task slot's row of a scan dispatch's
+    packed input: the task's own inputs, its row of the per-task
+    constants table (``row``), then its ``k`` generations' draws of
+    ``C`` children each (the restart scan adds its fresh blocks and
+    its (best, since) state)."""
+    if kind == "direct":
+        own = (("scramble", (math.factorial(d),), _I32),
+               ("dim_sizes", (d,), _F32))
+    else:
+        own = (("gene_ub", (width,), _I32), ("fixed_mask", (width,), _BOOL),
+               ("fixed_vals", (width,), _I32))
+    draws = (("ab", (k, C, 2), _I32), ("cuts", (k, C), _I32),
+             ("active", (k, C), _BOOL), ("gene", (k, C, genes_per), _I32),
+             ("vals", (k, C, genes_per), _I32))
+    if restart:
+        draws += (("fresh", (k, C, width), _I32), ("best0", (1,), _F32),
+                  ("since0", (1,), _I32))
+    return own + (("row", (), _I32),) + draws
+
+
+def _scan_rounds(W: int, kind: str, C: int, genes_per: int, width: int,
+                 restart: int, d: int) -> int:
+    """The generations ``k`` of a packed input row ``W`` columns wide."""
+    w0, w1 = (_fields_width(_scan_in_fields(kind, k, C, genes_per, width,
+                                            restart, d)) for k in (0, 1))
+    k, rem = divmod(W - w0, w1 - w0)
+    if rem or k < 1:
+        raise ValueError(f"a packed scan row of {W} columns fits no "
+                         f"whole number of generations")
+    return k
+
+
+def _scan_out_fields(kind: str, k: int, C: int, B: int, width: int,
+                     canon_width: int, restart: int) -> Tuple:
+    """(name, shape, dtype) of one task slot's row of a scan dispatch's
+    packed result: each generation's kids (canonical rows of
+    ``canon_width`` for a direct scan) and costs, the restart scan's
+    fresh-block costs and state, and the final carry."""
+    kids = ("canon", (k, C, canon_width), _I32) if kind == "direct" else \
+        ("kids", (k, C, width), _I32)
+    out = (kids, ("valid", (k, C), _BOOL), ("energy_pj", (k, C), _F32),
+           ("cycles", (k, C), _F32))
+    if restart:
+        out += (("f_valid", (k, C), _BOOL), ("f_energy_pj", (k, C), _F32),
+                ("f_cycles", (k, C), _F32), ("restarted", (k,), _BOOL),
+                ("best", (1,), _F32), ("since", (1,), _I32))
+    return out + (("carry", (B, width + 1), _I32),)
+
+
+def _host_carry(pop: np.ndarray, edp: np.ndarray) -> np.ndarray:
+    """A task's (B, width + 1) int32 carry from its host population."""
+    return np.concatenate(
+        [np.asarray(pop, np.int32),
+         _as_i32(np.asarray(edp, np.float32))[:, None]], axis=1)
+
+
+def _split_carry(carry: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """(population, float32 edp) of a fetched carry."""
+    return carry[:, :-1], carry[:, -1].view(np.float32)
+
+
+def _scan_body(vfn: Callable, kind: str, n_elite: int, genes_per: int,
+               restart: int, d: int, layout: Tuple) -> Callable:
+    """The scan dispatch around the vmapped kernel ``vfn`` (over a task
+    axis): ``(carry, rows, table) -> (out, carry)``.  ``carry`` is the
+    stacked (T, B, width + 1) carries, ``rows`` the packed (T, W) input
+    and ``table`` the signature's per-task constants table, from which
+    each slot gathers its constants by its ``row`` field (as
+    :func:`_stacked_program` does per genome row); ``out`` is the packed
+    (T, W') result.  The generations ``k`` are read off ``W``."""
+    def body(carry, rows, table):
+        T, B, width = carry.shape[0], carry.shape[1], carry.shape[2] - 1
+        C = B - n_elite
+        k = _scan_rounds(rows.shape[1], kind, C, genes_per, width,
+                         restart, d)
+        f = _split_fields(rows, _scan_in_fields(kind, k, C, genes_per,
+                                                width, restart, d))
+        consts = tuple(_unpack_consts(table[f.pop("row")], layout))
+        own = ("scramble", "dim_sizes") if kind == "direct" else \
+            ("gene_ub", "fixed_mask", "fixed_vals")
+        own = tuple(f.pop(n) for n in own)      # the rest are the draws
+        pop_f, edp_f, ys = vfn(
+            carry[..., :width], _from_i32(carry[..., width], _F32),
+            *own, f, consts)
+        carry_f = jnp.concatenate([pop_f, _as_i32(edp_f)[..., None]],
+                                  axis=-1)
+        canon_width = ys["canon"].shape[-1] if kind == "direct" else width
+        vals = dict(ys, carry=carry_f)
+        out = jnp.concatenate(
+            [_as_i32(vals[name]).reshape(T, -1) for name, _, _ in
+             _scan_out_fields(kind, k, C, B, width, canon_width, restart)],
+            axis=1)
+        return out, carry_f
+    return body
+
+
+def _per_task_carry(body: Callable) -> Callable:
+    """The scan program as dispatched: the carries enter and leave as
+    one array per task, stacked and split inside the program.  Stacking
+    or slicing them outside would run eager XLA ops, whose programs
+    compile anew for every task count and task index.  The packed
+    result holds the final carries too: a pipelined driver reads it
+    after the next dispatch has taken (donated) the per-task ones."""
+    def program(carries, rows, table):
+        out, carry_f = body(jnp.stack(carries), rows, table)
+        return out, tuple(carry_f[i] for i in range(len(carries)))
     return _named(program, SCAN_PROGRAM)
 
 
 def _donate_args() -> Tuple[int, ...]:
-    """Donate the scan carry buffers (pop, edp) on accelerators so a
-    pipelined fleet's device-resident populations update in place;
-    donation on CPU only produces warnings, so it stays gated."""
-    return (0, 1) if jax.default_backend() in ("gpu", "tpu") else ()
+    """Donate the per-task carry buffers on accelerators so a pipelined
+    fleet's device-resident populations update in place; donation on
+    CPU only produces warnings, so it stays gated."""
+    return (0,) if jax.default_backend() in ("gpu", "tpu") else ()
+
+
+def _scan_tag(n_parents: int, n_elite: int, genes_per: int,
+              restart: int = 0, kind: str = "es") -> str:
+    """The scan family's tag in the jit registry and the AOT keys."""
+    return ("dscan:" if kind == "direct" else "scan:") + \
+        f"p{n_parents}e{n_elite}g{genes_per}" + \
+        (f"r{restart}" if restart else "")
+
+
+#: parses a :func:`_scan_tag` back into (family, n_parents, n_elite,
+#: genes_per, restart or None)
+SCAN_TAG_RE = re.compile(r"^(d?scan):p(\d+)e(\d+)g(\d+)(?:r(\d+))?$")
+
+
+@lru_cache(maxsize=32)
+def _scan_program(d: int, n_pad: int, topo: Topology, dens_key: str,
+                  n_parents: int, n_elite: int, genes_per: int,
+                  restart: int, layout: Tuple) -> Callable:
+    """The un-jitted canonical ES scan program (``run_segments``)."""
+    return _per_task_carry(_scan_body(
+        _scan_task_fn(d, n_pad, topo, dens_key, n_parents, n_elite,
+                      genes_per, restart),
+        "es", n_elite, genes_per, restart, d, layout))
 
 
 @lru_cache(maxsize=32)
 def _scan_fn(d: int, n_pad: int, topo: Topology, dens_key: str,
-             n_parents: int, n_elite: int, genes_per: int,
-             restart: int = 0):
-    fn = jax.jit(_per_task_carry(_scan_task_fn(
-        d, n_pad, topo, dens_key, n_parents, n_elite, genes_per, restart)),
+             n_parents: int, n_elite: int, genes_per: int, restart: int,
+             layout: Tuple):
+    fn = jax.jit(_scan_program(d, n_pad, topo, dens_key, n_parents,
+                               n_elite, genes_per, restart, layout),
                  donate_argnums=_donate_args())
-    tag = f"scan:p{n_parents}e{n_elite}g{genes_per}" + (
-        f"r{restart}" if restart else "")
     with _LOCK:
-        _JIT_FNS[(d, n_pad, topo.fingerprint, dens_key, tag)] = fn
+        _JIT_FNS[(d, n_pad, topo.fingerprint, dens_key,
+                  _scan_tag(n_parents, n_elite, genes_per, restart))] = fn
     return fn
 
 
@@ -1235,37 +1426,53 @@ def _direct_scan_task_fn(d: int, n_pad: int, topo: Topology,
 
 
 @lru_cache(maxsize=32)
+def _direct_scan_program(d: int, n_pad: int, topo: Topology,
+                         dens_key: str, n_parents: int, n_elite: int,
+                         genes_per: int, layout: Tuple) -> Callable:
+    """The un-jitted ``standard_es`` direct-coordinate scan program."""
+    return _per_task_carry(_scan_body(
+        _direct_scan_task_fn(d, n_pad, topo, dens_key, n_parents, n_elite,
+                             genes_per),
+        "direct", n_elite, genes_per, 0, d, layout))
+
+
+@lru_cache(maxsize=32)
 def _direct_scan_fn(d: int, n_pad: int, topo: Topology, dens_key: str,
-                    n_parents: int, n_elite: int, genes_per: int):
-    fn = jax.jit(_per_task_carry(_direct_scan_task_fn(
-        d, n_pad, topo, dens_key, n_parents, n_elite, genes_per)),
+                    n_parents: int, n_elite: int, genes_per: int,
+                    layout: Tuple):
+    fn = jax.jit(_direct_scan_program(d, n_pad, topo, dens_key, n_parents,
+                                      n_elite, genes_per, layout),
                  donate_argnums=_donate_args())
     with _LOCK:
         _JIT_FNS[(d, n_pad, topo.fingerprint, dens_key,
-                  f"dscan:p{n_parents}e{n_elite}g{genes_per}")] = fn
+                  _scan_tag(n_parents, n_elite, genes_per,
+                            kind="direct"))] = fn
     return fn
 
 
 def _sharded_scan_fn(d: int, n_pad: int, topo: Topology, dens_key: str,
-                     n_parents: int, n_elite: int, genes_per: int, mesh):
+                     n_parents: int, n_elite: int, genes_per: int,
+                     layout: Tuple, mesh):
     """The scan program shard_map-ed over the task axis of ``mesh``'s
-    first axis (task count must divide the device count's multiple —
-    checked by the caller)."""
-    key = (d, n_pad, topo.fingerprint, dens_key,
-           f"scan:p{n_parents}e{n_elite}g{genes_per}", _mesh_key(mesh))
+    first axis, the per-task table replicated on every device (the task
+    slots must be a multiple of the device count — checked by the
+    caller)."""
+    tag = _scan_tag(n_parents, n_elite, genes_per)
+    key = (d, n_pad, topo.fingerprint, dens_key, tag, _mesh_key(mesh))
     fn = _SHARD_FNS.get(key)
     if fn is None:
         from jax.sharding import PartitionSpec as P
-        vfn = _scan_task_fn(d, n_pad, topo, dens_key, n_parents, n_elite,
-                            genes_per)
+        body = _scan_body(
+            _scan_task_fn(d, n_pad, topo, dens_key, n_parents, n_elite,
+                          genes_per), "es", n_elite, genes_per, 0, d, layout)
         ax = mesh.axis_names[0]
         fn = jax.jit(_per_task_carry(jax.shard_map(
-            vfn, mesh=mesh, in_specs=(P(ax),) * 7, out_specs=P(ax))))
+            body, mesh=mesh, in_specs=(P(ax), P(ax), P()),
+            out_specs=P(ax))))
         with _LOCK:
             _SHARD_FNS[key] = fn
             _JIT_FNS[(d, n_pad, topo.fingerprint, dens_key,
-                      f"scan:p{n_parents}e{n_elite}g{genes_per}"
-                      f"@{_mesh_ndev(mesh)}")] = fn
+                      f"{tag}@{_mesh_ndev(mesh)}")] = fn
     return fn
 
 
@@ -1308,75 +1515,118 @@ def scan_slots(n: int, cap: Optional[int] = None) -> int:
     return cap if cap is not None and n <= cap < slots else slots
 
 
-def _scan_call(sig: Tuple, tag: str, kind: str, fn, tasks: List[Tuple],
-               filler: Callable[[], Tuple], task_rows: int,
+# Device-resident carries of scan filler slots, keyed by the program
+# (signature, tag, devices) and the carry's shape: each dispatch takes
+# its filler slots' carries from here and puts back the carries those
+# slots returned, so a filler slot costs no host copy after the first.
+_FILLERS: Dict[Tuple, List] = {}
+
+
+def _scan_call(sig: Tuple, tag: str, kind: str, fn,
+               models: Sequence["JaxCostModel"],
+               segs: Sequence[DeviceSegment], per_task: List[Dict],
+               fields: Tuple, host_carry: Callable, task_rows: int,
                shape: Tuple[int, int, int], cap: Optional[int] = None,
                sharded: Optional[Callable] = None, mesh=None) -> Tuple:
-    """Dispatch one scan over ``tasks`` (each task's program inputs:
-    pop, edp, then the rest), its task axis padded to
-    :func:`scan_slots`.  The spare slots take ``filler()``: a real
-    task's inputs with its host-side population, so valid genomes in
-    fresh buffers (the per-task carries are donated); their outputs are
-    never read.  ``shape`` is the AOT key's (B, k, n_children);
-    ``sharded`` builds the mesh program, used when the slots divide
-    over the mesh's devices."""
-    T = len(tasks)
+    """Dispatch one scan over the tasks of ``models``/``segs``, its task
+    axis padded to :func:`scan_slots`, in one host-to-device copy: the
+    tasks' ``per_task`` fields (plus each one's row of the signature's
+    per-task constants table, :func:`_stack_table`) packed into one
+    (slots, W) int32 buffer (:func:`_pack_slots`).  A task's carry is
+    its segment's device-resident ``carry``, or ``host_carry(m, s)``
+    uploaded on its first segment; the spare slots take carries kept on
+    the device from earlier dispatches (:data:`_FILLERS`), whose outputs
+    are never read.  Each copy counts in :data:`SCAN_TRANSFERS`.
+    ``shape`` is the AOT key's (B, k, n_children); ``sharded`` builds
+    the mesh program, used when the slots divide over the mesh's
+    devices."""
+    T = len(models)
     slots = scan_slots(T, cap)
-    if slots > T:
-        tasks = tasks + [filler() for _ in range(slots - T)]
-    args = (tuple(jnp.asarray(t[0]) for t in tasks),
-            tuple(jnp.asarray(t[1]) for t in tasks)) + tuple(
-        jax.tree_util.tree_map(lambda *xs: jnp.asarray(np.stack(xs)),
-                               *[t[2:] for t in tasks]))
+    table = _stack_table(models)
+    for v, m in zip(per_task, models):
+        v["row"] = table.row_of[m._table_key]
+    rows = _pack_slots(per_task, fields, slots)
+    carries = []
+    for m, s in zip(models, segs):
+        if s.carry is None:
+            carries.append(jnp.asarray(host_carry(m, s)))
+            trace.count(SCAN_TRANSFERS, dir="h2d", what="carry")
+        else:
+            carries.append(s.carry)
+    ndev = _mesh_ndev(mesh)
+    shard = sharded is not None and ndev > 1 and slots % ndev == 0
+    pool = sig + (tag, ndev if shard else 1) + tuple(carries[0].shape)
+    with _LOCK:
+        spare = _FILLERS.setdefault(pool, [])
+        fill = [spare.pop() for _ in range(min(slots - T, len(spare)))]
+    for _ in range(slots - T - len(fill)):
+        fill.append(jnp.asarray(host_carry(models[0], segs[0])))
+        trace.count(SCAN_TRANSFERS, dir="h2d", what="carry")
+    args = (tuple(carries + fill), jnp.asarray(rows), table.dev)
+    trace.count(SCAN_TRANSFERS, dir="h2d", what="rows")
     _count_dispatch()
     trace.count(ROWS, T * task_rows, sig=sig, kind=kind)
     trace.count(ROWS_PADDED, (slots - T) * task_rows, sig=sig, kind=kind)
     trace.count(SCAN_TASKS, T, sig=sig, slots=slots)
-    key = sig + (tag, slots) + shape
-    ndev = _mesh_ndev(mesh)
-    if sharded is not None and ndev > 1 and slots % ndev == 0:
+    tail = (slots,) + shape + (table.dev.shape[0],)
+    if shard:
         out = sharded()(*args)
-        _note_scan_build(sig + (f"{tag}@{ndev}", slots) + shape, "inline")
-        return out
-    out = _aot_call(key, fn, args)
+        key = sig + (f"{tag}@{ndev}",) + tail
+    else:
+        key = sig + (tag,) + tail
+        out = _aot_call(key, fn, args)
     _note_scan_build(key, "inline")
+    with _LOCK:
+        _FILLERS[pool].extend(out[1][T:])
     return out
 
 
 def _segment_results(models: Sequence["JaxCostModel"], out: Tuple,
-                     gens_of: Callable, defer: bool,
+                     fields: Tuple, gens_of: Callable, defer: bool,
                      restart: int = 0) -> List[SegmentResult]:
     """One SegmentResult per real task of a scan dispatch: each carries
-    its own (pop, edp) output; its ``gens_of(t, model, pop, edp, ys)``
-    reads its slot of the stacked outputs, converted to numpy once for
-    the whole dispatch on the first resolve."""
-    pop_f, edp_f, ys, pops, edps = out
-    host = {}
+    its own carry output; its ``gens_of(t, model, host)`` reads its slot
+    of the packed result, fetched with one copy for the whole dispatch
+    on the first resolve and split into ``fields``; ``host`` holds them
+    (``ys``) and the canonical costs of the whole block (``kout``, and
+    ``fout`` for the restart scan's fresh blocks)."""
+    packed, carries = out
+    T = len(models)
+    host: Dict = {}
 
     def materialize():
-        if "ys" not in host:
-            def conv():
-                return (np.asarray(pop_f), np.asarray(edp_f),
-                        {kk: np.asarray(v) for kk, v in ys.items()})
-            host["pf"], host["ef"], host["ys"] = _time_block(conv)
-        return host["pf"], host["ef"], host["ys"]
+        if not host:
+            buf = _time_block(lambda: np.asarray(packed))
+            trace.count(SCAN_TRANSFERS, dir="d2h", what="out")
+            ys = _split_fields(buf[:T], fields)
+            host["ys"] = ys
+            host["kout"] = _canonical(dict(
+                valid=ys["valid"], energy_pj=ys["energy_pj"],
+                cycles=ys["cycles"]))
+            if restart:
+                host["fout"] = _canonical(dict(
+                    valid=ys["f_valid"], energy_pj=ys["f_energy_pj"],
+                    cycles=ys["f_cycles"]))
+        return host
 
     def make_harvest(t, m):
-        return lambda: gens_of(t, m, *materialize())
+        return lambda: gens_of(t, m, materialize())
 
     results: List[SegmentResult] = []
     for t, m in enumerate(models):
         r = SegmentResult(gens=None, final_pop=None, final_edp=None,
-                          carry=(pops[t], edps[t]),
-                          harvest=make_harvest(t, m))
+                          carry=carries[t], harvest=make_harvest(t, m))
         if not defer:
             r.resolve()
         if restart:
-            _, _, ys_h = materialize()
-            r.state = (float(ys_h["best"][t, 0]),
-                       int(ys_h["since"][t, 0]))
+            ys = materialize()["ys"]
+            r.state = (float(ys["best"][t, 0]), int(ys["since"][t, 0]))
         results.append(r)
     return results
+
+
+def _slice_gen(block: Dict, t: int, g: int) -> Dict[str, np.ndarray]:
+    return {kk: v[t, g] for kk, v in block.items()}
 
 
 def run_segments(models: Sequence["JaxCostModel"],
@@ -1391,17 +1641,21 @@ def run_segments(models: Sequence["JaxCostModel"],
     The task axis is padded to :func:`scan_slots` (``cap``: the most
     tasks this group can hold, where the caller knows it), so a fleet
     whose task count changes runs a few programs, not one per count;
-    the spare slots repeat a real task's inputs and are dropped.
+    the spare slots run on carries kept on the device and are dropped.
 
-    Host work per call is limited to padding genomes/plan arrays into
-    the shared scan layout and, afterwards, slicing the per-generation
-    outputs back per task (``_canonical``-recomputed like every other
-    dispatch path).  With ``mesh`` given and the task slots divisible by
-    the device count, tasks shard across devices via ``jax.shard_map``;
-    otherwise the single-device program runs unchanged.
+    The dispatch makes one host-to-device copy, the packed per-slot
+    inputs (:func:`_scan_call`), beside a task's first carry, and one
+    device-to-host copy, the packed results, which the harvest slices
+    back per task and per generation (``_canonical``-recomputed like
+    every other dispatch path, once for the whole block).  Each task's
+    workload/platform constants come from the signature's per-task
+    table on the device, shared with :func:`eval_stacked`.  With
+    ``mesh`` given and the task slots divisible by the device count,
+    tasks shard across devices via ``jax.shard_map``; otherwise the
+    single-device program runs unchanged.
 
     Pipelining hooks: a segment carrying ``carry`` (the device-resident
-    padded (pop, edp) of its previous SegmentResult) skips the host-side
+    padded carry of its previous SegmentResult) skips the host-side
     genome padding entirely — the population never leaves the device
     between rounds.  With ``defer=True`` the returned results hold a
     ``harvest`` thunk instead of materialized numpy gens; the device is
@@ -1428,13 +1682,8 @@ def run_segments(models: Sequence["JaxCostModel"],
     if kind == "direct":
         return _run_direct_segments(models, segs, defer=defer, cap=cap)
 
-    def inputs(m, s, carry=True):
+    def fields_of(m, s):
         lay = _padded_layout(m)
-        if carry and s.carry is not None:
-            pop, edp = s.carry
-        else:
-            pop = lay.pad_rows(np.asarray(s.pop, dtype=np.int32))
-            edp = np.asarray(s.edp, dtype=np.float32)
         fm = np.zeros(lay.Lp, dtype=bool)
         fv = np.zeros(lay.Lp, dtype=np.int32)
         if s.fixed_genes:
@@ -1443,56 +1692,59 @@ def run_segments(models: Sequence["JaxCostModel"],
             fm[idx] = True
             fv[idx] = np.asarray(list(s.fixed_genes.values()),
                                  dtype=np.int32)
-        dr = dict(s.draws)
-        dr["gene"] = lay.pad_index(dr["gene"]).astype(np.int32)
-        dr["cuts"] = lay.pad_cut(dr["cuts"]).astype(np.int32)
+        dr = s.draws
+        v = dict(gene_ub=lay.pad_vector(m.spec.gene_ub.astype(np.int32), 1),
+                 fixed_mask=fm, fixed_vals=fv, ab=dr["ab"],
+                 cuts=lay.pad_cut(dr["cuts"]), active=dr["active"],
+                 gene=lay.pad_index(dr["gene"]), vals=dr["vals"])
         if restart:
             fr = np.asarray(dr["fresh"], dtype=np.int32)
-            gk, gc = fr.shape[0], fr.shape[1]
-            dr["fresh"] = lay.pad_rows(
-                fr.reshape(gk * gc, -1)).reshape(gk, gc, -1)
-            dr["best0"] = np.asarray([s.state[0]], dtype=np.float32)
-            dr["since0"] = np.asarray([s.state[1]], dtype=np.int32)
-        return (pop, edp, lay.pad_vector(m.spec.gene_ub.astype(np.int32), 1),
-                fm, fv, dr, m._np_consts)
+            v["fresh"] = lay.pad_rows(fr)
+            v["best0"] = [s.state[0]]
+            v["since0"] = [s.state[1]]
+        return v
+
+    def host_carry(m, s):
+        return _host_carry(_padded_layout(m).pad_rows(
+            np.asarray(s.pop, dtype=np.int32)), s.edp)
 
     n_children = segs[0].draws["ab"].shape[1]
     task_rows = k * n_children
     if restart:
         task_rows += k * np.shape(segs[0].draws["fresh"])[1]
-    tag = f"scan:p{n_parents}e{n_elite}g{genes_per}" + (
-        f"r{restart}" if restart else "")
+    tag = _scan_tag(n_parents, n_elite, genes_per, restart)
     topo = models[0].arch.topology
+    width = _padded_layout(models[0]).Lp
     with trace.span("fleet.dispatch", sig=sig, kind="scan"):
         out = _scan_call(
             sig, tag, "scan",
             _scan_fn(sig[0], sig[1], topo, sig[3], n_parents, n_elite,
-                     genes_per, restart),
-            [inputs(m, s) for m, s in zip(models, segs)],
-            lambda: inputs(models[0], segs[0], carry=False), task_rows,
-            (B, k, n_children), cap,
+                     genes_per, restart, models[0]._const_layout),
+            models, segs, [fields_of(m, s) for m, s in zip(models, segs)],
+            _scan_in_fields("es", k, n_children, genes_per, width,
+                            restart, sig[0]),
+            host_carry, task_rows, (B, k, n_children), cap,
             sharded=None if restart else lambda: _sharded_scan_fn(
                 sig[0], sig[1], topo, sig[3], n_parents, n_elite,
-                genes_per, mesh), mesh=mesh)
+                genes_per, models[0]._const_layout, mesh), mesh=mesh)
 
-    def gens_of(t, m, pf, ef, ys_h):
+    def gens_of(t, m, host):
         lay = _padded_layout(m)
+        ys = host["ys"]
         gens = []
         for g in range(k):
-            kids = lay.unpad_rows(ys_h["kids"][t, g]).astype(np.int64)
-            kout = _canonical(dict(valid=ys_h["valid"][t, g],
-                                   energy_pj=ys_h["energy_pj"][t, g],
-                                   cycles=ys_h["cycles"][t, g]))
+            kout = _slice_gen(host["kout"], t, g)
             if restart:
-                kout["fresh"] = _canonical(dict(
-                    valid=ys_h["f_valid"][t, g],
-                    energy_pj=ys_h["f_energy_pj"][t, g],
-                    cycles=ys_h["f_cycles"][t, g]))
-                kout["restarted"] = bool(ys_h["restarted"][t, g])
-            gens.append((kids, kout))
-        return gens, lay.unpad_rows(pf[t]).astype(np.int64), ef[t]
+                kout["fresh"] = _slice_gen(host["fout"], t, g)
+                kout["restarted"] = bool(ys["restarted"][t, g])
+            gens.append((lay.unpad_rows(ys["kids"][t, g]).astype(np.int64),
+                         kout))
+        pop, edp = _split_carry(ys["carry"][t])
+        return gens, lay.unpad_rows(pop).astype(np.int64), edp
 
-    return _segment_results(models, out, gens_of, defer, restart)
+    return _segment_results(
+        models, out, _scan_out_fields("es", k, n_children, B, width, width,
+                                      restart), gens_of, defer, restart)
 
 
 def _run_direct_segments(models: Sequence["JaxCostModel"],
@@ -1512,40 +1764,39 @@ def _run_direct_segments(models: Sequence["JaxCostModel"],
     if restart:
         raise ValueError("direct segments do not support in-scan restart")
 
-    def inputs(m, s, carry=True):
-        if carry and s.carry is not None:
-            pop, edp = s.carry
-        else:
-            pop = np.asarray(s.pop, dtype=np.int32)
-            edp = np.asarray(s.edp, dtype=np.float32)
-        return (pop, edp, np.asarray(s.aux["scramble"], dtype=np.int32),
-                np.asarray(s.aux["dim_sizes"], dtype=np.float32),
-                {kk: np.asarray(v) for kk, v in s.draws.items()},
-                m._np_consts)
+    def fields_of(m, s):
+        return dict(s.draws, scramble=s.aux["scramble"],
+                    dim_sizes=s.aux["dim_sizes"])
+
+    def host_carry(m, s):
+        return _host_carry(s.pop, s.edp)
 
     n_children = segs[0].draws["ab"].shape[1]
     topo = models[0].arch.topology
+    width = np.shape(segs[0].pop)[1]
     with trace.span("fleet.dispatch", sig=sig, kind="dscan"):
         out = _scan_call(
-            sig, f"dscan:p{n_parents}e{n_elite}g{genes_per}", "dscan",
+            sig, _scan_tag(n_parents, n_elite, genes_per, kind="direct"),
+            "dscan",
             _direct_scan_fn(sig[0], sig[1], topo, sig[3], n_parents,
-                            n_elite, genes_per),
-            [inputs(m, s) for m, s in zip(models, segs)],
-            lambda: inputs(models[0], segs[0], carry=False),
-            k * n_children, (B, k, n_children), cap)
+                            n_elite, genes_per, models[0]._const_layout),
+            models, segs, [fields_of(m, s) for m, s in zip(models, segs)],
+            _scan_in_fields("direct", k, n_children, genes_per, width, 0,
+                            sig[0]),
+            host_carry, k * n_children, (B, k, n_children), cap)
 
-    def gens_of(t, m, pf, ef, ys_h):
+    def gens_of(t, m, host):
         lay = _padded_layout(m)
-        gens = []
-        for g in range(k):
-            kids = lay.unpad_rows(ys_h["canon"][t, g]).astype(np.int64)
-            kout = _canonical(dict(valid=ys_h["valid"][t, g],
-                                   energy_pj=ys_h["energy_pj"][t, g],
-                                   cycles=ys_h["cycles"][t, g]))
-            gens.append((kids, kout))
-        return gens, pf[t].astype(np.int64), ef[t]
+        ys = host["ys"]
+        gens = [(lay.unpad_rows(ys["canon"][t, g]).astype(np.int64),
+                 _slice_gen(host["kout"], t, g)) for g in range(k)]
+        pop, edp = _split_carry(ys["carry"][t])
+        return gens, pop.astype(np.int64), edp
 
-    return _segment_results(models, out, gens_of, defer)
+    return _segment_results(
+        models, out, _scan_out_fields(
+            "direct", k, n_children, B, width,
+            _padded_layout(models[0]).Lp, 0), gens_of, defer)
 
 
 # ---------------------------------------------------------------- wrapper
@@ -1603,8 +1854,9 @@ class JaxCostModel:
         for i, (dd, p) in enumerate(spec.primes):
             primes[i] = p
             prime_dim[i] = dim_idx[dd]
-        # numpy copies feed the scans and the AOT structs, and one float32
-        # row of them all the per-task table of eval_stacked
+        # one float32 row of them all is this task's row of the per-task
+        # table every dispatch path gathers from (the jaxpr auditor
+        # traces the row kernel on them as they are)
         self._np_consts = (
             primes,
             prime_dim,
@@ -1697,17 +1949,21 @@ def _canonical(out: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
 
 # ------------------------------------------------- per-task constants table
 
-# eval_stacked's workload/platform constants live on the device: per
+# The per-task workload/platform constants live on the device: per
 # signature, one float32 table with a row per distinct (workload, arch)
-# of the group (``JaxCostModel._const_row``), gathered per genome row by
-# the row's task index inside the program.  The table is uploaded again
-# only when the group's set of tasks changes (an admission or a
-# retirement), never for a change of row counts or padding.  Keys are
-# CONTENT (workload cache_key + arch), never id(), so a recycled object
-# can't alias a stale table.  Its slots are a power of two, at least
-# TABLE_FLOOR and never shrinking per signature, so a served group's
-# table keeps one shape whatever its make-up and compile-ahead can build
-# it (:func:`table_slots`).
+# (``JaxCostModel._const_row``), gathered by each mega-batch row's task
+# index in ``eval_stacked`` and by each task slot's ``row`` field in the
+# scans, so one table serves both paths.  A group whose tasks all have a
+# row reuses the table as it is (a superset: a retirement, or a scan
+# group beside the mega-batch group, uploads nothing); a task that brings
+# a (workload, arch) the table lacks adds its row in the table's free
+# slots, or, where they are full, the table is rebuilt from the group —
+# either way uploaded again in one copy.  Keys are CONTENT (workload
+# cache_key + arch), never id(), so a recycled object can't alias a stale
+# table.  Its slots are a power of two, at least TABLE_FLOOR and never
+# shrinking per signature, so a served group's table keeps one shape
+# whatever its make-up and compile-ahead can build it
+# (:func:`table_slots`).
 _STACK_TABLES: Dict[Tuple[int, int, str, str], "_Table"] = {}
 
 #: the fewest slots a per-task table has: one shape for every group of
@@ -1717,12 +1973,13 @@ TABLE_FLOOR = 16
 
 class _Table(NamedTuple):
     row_of: Dict[Tuple, int]    # (cache_key, arch) -> table row
-    dev: jax.Array              # (slots, width) float32, on the device
+    rows: np.ndarray            # (slots, width) float32, on the host
+    dev: jax.Array              # the same rows on the device
 
 
 def stack_prep_counts() -> Tuple[int, int]:
     """(table reuses, table uploads) of the per-task constants tables
-    :func:`eval_stacked` keeps on the device."""
+    the dispatch paths keep on the device."""
     return int(_since_reset(PREP_HITS)), int(_since_reset(PREP_MISSES))
 
 
@@ -1737,29 +1994,38 @@ def table_slots(sig: Tuple, n: int) -> int:
     slots = max(TABLE_FLOOR, 1 << max(0, n - 1).bit_length())
     with _LOCK:
         held = _STACK_TABLES.get(sig)
-    return slots if held is None else max(slots, held.dev.shape[0])
+    return slots if held is None else max(slots, len(held.rows))
 
 
 def _stack_table(models: Sequence["JaxCostModel"]) -> _Table:
     """The device table of the group's per-task constants: the one held
-    for the signature when it holds the same tasks (counted a reuse),
-    else a new one uploaded in one copy (counted an upload)."""
+    for the signature when it has a row for every task of the group
+    (counted a reuse); else the held rows plus the new tasks' rows where
+    its free slots hold them, or the group's rows alone where they do
+    not, uploaded in one copy (counted an upload)."""
     sig = models[0].signature
     first: Dict[Tuple, "JaxCostModel"] = {}
     for m in models:
         first.setdefault(m._table_key, m)
     with _LOCK:
         held = _STACK_TABLES.get(sig)
-    if held is not None and held.row_of.keys() == first.keys():
+    if held is not None and first.keys() <= held.row_of.keys():
         trace.count(PREP_HITS)
         return held
     trace.count(PREP_MISSES)
-    rows = np.empty((table_slots(sig, len(first)),
-                     models[0]._const_row.size), np.float32)
-    rows[:] = models[0]._const_row          # unused slots: never read
-    for i, m in enumerate(first.values()):
+    new = [m for key, m in first.items()
+           if held is None or key not in held.row_of]
+    if held is not None and len(held.row_of) + len(new) <= len(held.rows):
+        row_of, rows = dict(held.row_of), held.rows.copy()
+    else:
+        row_of, new = {}, list(first.values())
+        rows = np.empty((table_slots(sig, len(new)),
+                         models[0]._const_row.size), np.float32)
+        rows[:] = models[0]._const_row          # unused slots: never read
+    for m in new:
+        row_of[m._table_key] = i = len(row_of)
         rows[i] = m._const_row
-    table = _Table({k: i for i, k in enumerate(first)}, jnp.asarray(rows))
+    table = _Table(row_of, rows, jnp.asarray(rows))
     trace.count(TRANSFERS, dir="h2d", what="table")
     with _LOCK:
         _STACK_TABLES[sig] = table
@@ -1818,7 +2084,7 @@ def eval_stacked(models: Sequence["JaxCostModel"],
     :meth:`JaxCostModel._pack`) and sent in one host-to-device copy; each
     row gathers its workload/platform constants from the signature's
     per-task table on the device (:func:`_stack_table`, uploaded again
-    only when the group's set of tasks changes — see
+    only when a task brings constants it lacks — see
     :func:`stack_prep_counts`); the program returns one (3, padded)
     float32 array (valid, energy_pj, cycles), fetched in one copy and
     sliced back per input pair.  Each row's result depends only on the
@@ -1922,67 +2188,56 @@ def stacked_compile_job(model: "JaxCostModel", padded: int,
              S((slots, model._const_row.size), np.float32)))
 
 
-def _draw_structs(T: int, k: int, n_children: int, genes_per: int) -> Dict:
-    S = jax.ShapeDtypeStruct
-    return dict(ab=S((T, k, n_children, 2), np.int32),
-                cuts=S((T, k, n_children), np.int32),
-                active=S((T, k, n_children), np.bool_),
-                gene=S((T, k, n_children, genes_per), np.int32),
-                vals=S((T, k, n_children, genes_per), np.int32))
-
-
-def _seg_consts_structs(model: "JaxCostModel", T: int) -> Tuple:
-    S = jax.ShapeDtypeStruct
-    return tuple(S((T,) + np.shape(np.asarray(c)), np.asarray(c).dtype)
-                 for c in model._np_consts)
-
-
 def _carry_structs(T: int, B: int, width: int) -> Tuple:
     S = jax.ShapeDtypeStruct
-    return (tuple(S((B, width), np.int32) for _ in range(T)),
-            tuple(S((B,), np.float32) for _ in range(T)))
+    return tuple(S((B, width + 1), np.int32) for _ in range(T))
+
+
+def _scan_job(model: "JaxCostModel", fn, kind: str, tag: str, B: int,
+              k: int, n_elite: int, genes_per: int, T: int, width: int,
+              restart: int, table: Optional[int]) -> Tuple:
+    """The AOT job of one scan program shape: ``T`` per-task carries of
+    ``B`` genomes ``width`` wide, the packed (T, W) input of ``k``
+    generations and a per-task table of ``table`` slots (by default the
+    slots :func:`table_slots` gives a one-task group now)."""
+    sig = model.signature
+    if table is None:
+        table = table_slots(sig, 1)
+    C = B - n_elite
+    fields = _scan_in_fields(kind, k, C, genes_per, width, restart, model.d)
+    S = jax.ShapeDtypeStruct
+    args = (_carry_structs(T, B, width),
+            S((T, _fields_width(fields)), np.int32),
+            S((table, model._const_row.size), np.float32))
+    return sig + (tag, T, B, k, C, table), fn, args
 
 
 def scan_compile_job(model: "JaxCostModel", B: int, k: int,
                      n_parents: int, n_elite: int, genes_per: int,
-                     T: int, restart: int = 0) -> Tuple:
+                     T: int, restart: int = 0,
+                     table: Optional[int] = None) -> Tuple:
     """AOT job for one ``run_segments`` ES-scan shape (``T`` task slots,
     a :func:`scan_slots` bucket, of ``B`` genomes advanced ``k``
-    generations)."""
+    generations, against a per-task table of ``table`` slots)."""
     sig = model.signature
     fn = _scan_fn(sig[0], sig[1], model.arch.topology, sig[3],
-                  n_parents, n_elite, genes_per, restart)
-    lay = _padded_layout(model)
-    n_children = B - n_elite
-    S = jax.ShapeDtypeStruct
-    draws = _draw_structs(T, k, n_children, genes_per)
-    if restart:
-        draws["fresh"] = S((T, k, n_children, lay.Lp), np.int32)
-        draws["best0"] = S((T, 1), np.float32)
-        draws["since0"] = S((T, 1), np.int32)
-    tag = f"scan:p{n_parents}e{n_elite}g{genes_per}" + (
-        f"r{restart}" if restart else "")
-    args = _carry_structs(T, B, lay.Lp) + (
-        S((T, lay.Lp), np.int32), S((T, lay.Lp), np.bool_),
-        S((T, lay.Lp), np.int32), draws,
-        _seg_consts_structs(model, T))
-    return sig + (tag, T, B, k, n_children), fn, args
+                  n_parents, n_elite, genes_per, restart,
+                  model._const_layout)
+    return _scan_job(model, fn, "es",
+                     _scan_tag(n_parents, n_elite, genes_per, restart),
+                     B, k, n_elite, genes_per, T, _padded_layout(model).Lp,
+                     restart, table)
 
 
 def direct_scan_compile_job(model: "JaxCostModel", B: int, k: int,
                             n_parents: int, n_elite: int, genes_per: int,
                             T: int, direct_len: int,
-                            n_perm_codes: int) -> Tuple:
+                            table: Optional[int] = None) -> Tuple:
     """AOT job for one ``standard_es`` direct-scan shape.  ``direct_len``
-    and ``n_perm_codes`` come from the task's ``DirectValueSpec``."""
+    comes from the task's ``DirectValueSpec``."""
     sig = model.signature
     fn = _direct_scan_fn(sig[0], sig[1], model.arch.topology, sig[3],
-                         n_parents, n_elite, genes_per)
-    n_children = B - n_elite
-    S = jax.ShapeDtypeStruct
-    args = _carry_structs(T, B, direct_len) + (
-        S((T, n_perm_codes), np.int32), S((T, model.d), np.float32),
-        _draw_structs(T, k, n_children, genes_per),
-        _seg_consts_structs(model, T))
-    return (sig + (f"dscan:p{n_parents}e{n_elite}g{genes_per}",
-                   T, B, k, n_children), fn, args)
+                         n_parents, n_elite, genes_per, model._const_layout)
+    return _scan_job(model, fn, "direct",
+                     _scan_tag(n_parents, n_elite, genes_per, kind="direct"),
+                     B, k, n_elite, genes_per, T, direct_len, 0, table)

@@ -549,11 +549,13 @@ class MultiSearch:
         by_sig: Dict[Tuple, List[int]] = {}
         for i, (task, kw, spec, ev) in enumerate(infos):
             by_sig.setdefault(ev.signature, []).append(i)
+        tables: Dict[Tuple, int] = {}
         for sig in sorted(by_sig):
             idx = by_sig[sig]
             model = infos[idx[0]][3]
-            # the group's per-task constants table: one slot count
-            slots = jax_cost.table_slots(
+            # the signature's per-task constants table, which its
+            # mega-batches and scans share: one slot count
+            slots = tables[sig] = jax_cost.table_slots(
                 sig, len({infos[i][3]._table_key for i in idx}))
             if all(rows[i] is not None for i in idx):
                 total = sum(rows[i] for i in idx)
@@ -595,7 +597,8 @@ class MultiSearch:
             cap = n if self._fixed_tasks else None
             for slots in sorted({jax_cost.scan_slots(t, cap)
                                  for t in range(1, n + 1)}):
-                add(self._scan_job(key, seg_groups[key][0], slots))
+                add(self._scan_job(key, seg_groups[key][0], slots,
+                                   tables[key[:4]]))
         return jobs + late
 
     def _scan_key(self, task: SearchTask, kw: Dict, spec,
@@ -615,17 +618,18 @@ class MultiSearch:
             "restart"))
 
     @staticmethod
-    def _scan_job(key: Tuple, ev, slots: int) -> Tuple:
-        """The AOT job of one scan group's program at ``slots`` tasks."""
+    def _scan_job(key: Tuple, ev, slots: int, table: int) -> Tuple:
+        """The AOT job of one scan group's program at ``slots`` tasks
+        against a per-task table of ``table`` slots."""
         B, k, n_parents, n_elite, genes_per, kind, restart = key[4:]
         if kind == "direct":
             from .direct_encoding import DirectValueSpec
-            dspec = DirectValueSpec(ev.spec)
             return jax_cost.direct_scan_compile_job(
                 ev, B, k, n_parents, n_elite, genes_per, slots,
-                dspec.length, dspec.n_perm_codes)
+                DirectValueSpec(ev.spec).length, table=table)
         return jax_cost.scan_compile_job(ev, B, k, n_parents, n_elite,
-                                         genes_per, slots, restart=restart)
+                                         genes_per, slots, restart=restart,
+                                         table=table)
 
     def _advance(self, st: _TaskState, out: Dict) -> bool:
         """Send an evaluation to a task's generator; False when done.
@@ -824,9 +828,12 @@ class MultiSearch:
         group reach (every bucket up to its live task count) that the
         fleet has not queued yet, beside the jobs still owed."""
         live = sum(1 for s in self._alive if s.scan_key == st.scan_key)
+        table = jax_cost.table_slots(st.signature, len(
+            {s.ev._table_key for s in self._alive
+             if s.signature == st.signature}))
         have = {job[0] for job in self._ca_jobs}
         new = [job for job in (
-            self._scan_job(st.scan_key, st.ev, slots)
+            self._scan_job(st.scan_key, st.ev, slots, table)
             for slots in sorted({jax_cost.scan_slots(t)
                                  for t in range(1, live + 1)}))
             if job[0] not in have]

@@ -230,7 +230,7 @@ def test_check_aot_jobs_accepts_real_builders():
                                   genes_per=2, T=1, restart=8),
         jax_cost.direct_scan_compile_job(
             m, B=8, k=2, n_parents=2, n_elite=1, genes_per=2, T=2,
-            direct_len=dspec.length, n_perm_codes=dspec.n_perm_codes),
+            direct_len=dspec.length),
     ]
     vs = check_aot_jobs(jobs)
     assert vs == [], [str(v) for v in vs]

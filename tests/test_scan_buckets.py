@@ -4,7 +4,8 @@
   final population, the device carry and the restart state — is
   bit-identical to an unpadded dispatch of the same tasks, on the plain
   scan, the restart scan and the direct scan, fed from host populations
-  and from device carries alike;
+  and from device carries alike, and to the scan dispatched with its
+  inputs and results as separate arrays;
 * a served fleet whose admissions and completions move the task count
   builds no more scan programs than there are buckets, all of them
   ahead of their dispatch (``fleet.scan_builds``);
@@ -21,7 +22,8 @@ import pytest
 from repro.configs.paper_workloads import by_name
 from repro.core import jax_cost, search, trace
 from repro.core.baselines import make_requests
-from repro.core.es_ops import DeviceSegment
+from repro.core.es_ops import DeviceSegment, SegmentResult, \
+    segment_shape_key
 from repro.core.search import FleetConfig, MultiSearch, SearchTask
 from repro.core.workload import spmm
 from repro.launch.sweep_serve import SweepServer, submit
@@ -41,20 +43,24 @@ def test_scan_slots_are_powers_of_two_up_to_the_cap(n, cap, want):
     assert jax_cost.scan_slots(n, cap) == want
 
 
-def _first_segments(kind, T):
-    """The first DeviceSegment of ``T`` searches of mm1 at different
-    seeds, each driven through its host prologue."""
+def _first_segments(kind, T, workloads=None):
+    """The first DeviceSegment of ``T`` searches at different seeds, of
+    the ``workloads`` in turn (mm1 by default), each driven through its
+    host prologue."""
     method, kw = KINDS[kind]
-    spec, ev = search.get_evaluator(by_name("mm1"), "cloud")
-    segs = []
+    workloads = workloads or (by_name("mm1"),)
+    models, segs = [], []
     for seed in range(T):
+        spec, ev = search.get_evaluator(workloads[seed % len(workloads)],
+                                        "cloud")
         gen, _ = make_requests(method, spec, search._platform("cloud"),
                                600, seed, device_rounds=K, **kw)
         req = next(gen)
         while not isinstance(req, DeviceSegment):
             req = gen.send(ev(req))
+        models.append(ev)
         segs.append(req)
-    return [ev] * T, segs
+    return models, segs
 
 
 def _assert_same(a, b):
@@ -70,8 +76,7 @@ def _assert_same(a, b):
                 assert np.array_equal(oa[name], ob[name]), name
     assert np.array_equal(a.final_pop, b.final_pop)
     assert np.array_equal(a.final_edp, b.final_edp)
-    for ca, cb in zip(a.carry, b.carry):
-        assert np.array_equal(np.asarray(ca), np.asarray(cb))
+    assert np.array_equal(np.asarray(a.carry), np.asarray(b.carry))
     assert a.state == b.state
 
 
@@ -91,6 +96,111 @@ def test_bucketed_scan_matches_the_unpadded_dispatch(kind, T):
     for a, b in zip(jax_cost.run_segments(models, nxt),
                     jax_cost.run_segments(models, nxt, cap=T)):
         _assert_same(a, b)
+
+
+def _per_array_reference(models, segs):
+    """Each task's (gens, final_pop, final_edp, state) as the scan was
+    dispatched before its inputs and results were packed: the kernel
+    over the tasks, unpadded, fed each input as its own stacked array and
+    the nine constants per task, every output fetched array by array and
+    canonicalized generation by generation."""
+    import jax
+    import jax.numpy as jnp
+    B, k, n_parents, n_elite, genes_per, kind, restart = \
+        segment_shape_key(segs[0])
+    d, n_pad, _, dens = models[0].signature
+    topo = models[0].arch.topology
+    ins = []
+    for m, s in zip(models, segs):
+        lay = jax_cost._padded_layout(m)
+        dr = {kk: np.asarray(v) for kk, v in s.draws.items()}
+        if kind == "direct":
+            ins.append((np.asarray(s.pop, np.int32),
+                        np.asarray(s.edp, np.float32), s.aux["scramble"],
+                        s.aux["dim_sizes"], dr, m._np_consts))
+            continue
+        fm = np.zeros(lay.Lp, dtype=bool)
+        fv = np.zeros(lay.Lp, dtype=np.int32)
+        if s.fixed_genes:
+            idx = lay.pad_index(np.asarray(list(s.fixed_genes)))
+            fm[idx] = True
+            fv[idx] = list(s.fixed_genes.values())
+        dr["gene"] = lay.pad_index(dr["gene"]).astype(np.int32)
+        dr["cuts"] = lay.pad_cut(dr["cuts"]).astype(np.int32)
+        if restart:
+            dr["fresh"] = lay.pad_rows(dr["fresh"].astype(np.int32))
+            dr["best0"] = np.asarray([s.state[0]], dtype=np.float32)
+            dr["since0"] = np.asarray([s.state[1]], dtype=np.int32)
+        ins.append((lay.pad_rows(np.asarray(s.pop, np.int32)),
+                    np.asarray(s.edp, np.float32),
+                    lay.pad_vector(m.spec.gene_ub.astype(np.int32), 1),
+                    fm, fv, dr, m._np_consts))
+    if kind == "direct":
+        vfn = jax_cost._direct_scan_task_fn(d, n_pad, topo, dens,
+                                            n_parents, n_elite, genes_per)
+    else:
+        vfn = jax_cost._scan_task_fn(d, n_pad, topo, dens, n_parents,
+                                     n_elite, genes_per, restart)
+    args = jax.tree_util.tree_map(lambda *xs: jnp.asarray(np.stack(xs)),
+                                  *ins)
+    pop_f, edp_f, ys = jax.tree_util.tree_map(np.asarray,
+                                              jax.jit(vfn)(*args))
+    out = []
+    for t, m in enumerate(models):
+        lay = jax_cost._padded_layout(m)
+        gens = []
+        for g in range(k):
+            kids = ys["canon" if kind == "direct" else "kids"][t, g]
+            kout = jax_cost._canonical(dict(
+                valid=ys["valid"][t, g], energy_pj=ys["energy_pj"][t, g],
+                cycles=ys["cycles"][t, g]))
+            if restart:
+                kout["fresh"] = jax_cost._canonical(dict(
+                    valid=ys["f_valid"][t, g],
+                    energy_pj=ys["f_energy_pj"][t, g],
+                    cycles=ys["f_cycles"][t, g]))
+                kout["restarted"] = bool(ys["restarted"][t, g])
+            gens.append((lay.unpad_rows(kids).astype(np.int64), kout))
+        pop = pop_f[t] if kind == "direct" else lay.unpad_rows(pop_f[t])
+        state = (float(ys["best"][t, 0]), int(ys["since"][t, 0])) \
+            if restart else None
+        out.append((gens, pop.astype(np.int64), edp_f[t], state))
+    return out
+
+
+UNIT = tuple(spmm(f"unit_{i}", 1, 1, 1, a, b)
+             for i, (a, b) in enumerate(((0.5, 0.5), (0.3, 0.6), (0.6, 0.4))))
+
+
+@pytest.mark.parametrize("T", [3, 5])
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_packed_scan_matches_the_per_array_dispatch(kind, T):
+    """The packed dispatch (one rows buffer up, constants from the
+    per-task table, one result buffer back, filler slots on device
+    carries) gives every task of T = 3 in 4 slots and T = 5 in 8, three
+    workloads of one signature, what the per-array dispatch gives it
+    unpadded, bit for bit: a first
+    segment from host populations, then a second from the packed
+    dispatch's device carries (the reference from its host copies)."""
+    # direct genomes of unit dimensions always translate, so their rows
+    # are priced with each task's own constants rather than all invalid
+    wls = UNIT if kind == "direct" else tuple(
+        by_name(w) for w in ("mm1", "mm2", "mm3"))
+    models, segs = _first_segments(kind, T, wls)
+    assert len({m.signature for m in models}) == 1
+    assert jax_cost.scan_slots(T) == {3: 4, 5: 8}[T]
+    for _ in range(2):
+        got = jax_cost.run_segments(models, segs)
+        want = _per_array_reference(
+            models, [dataclasses.replace(s, carry=None) for s in segs])
+        for r, (gens, pop, edp, state) in zip(got, want):
+            _assert_same(r, SegmentResult(gens=gens, final_pop=pop,
+                                          final_edp=edp, carry=r.carry,
+                                          state=state))
+        assert any(o["valid"].any() for r in got for _, o in r.gens)
+        segs = [dataclasses.replace(s, carry=r.carry, state=r.state,
+                                    pop=r.final_pop, edp=r.final_edp)
+                for s, r in zip(segs, got)]
 
 
 def _scan_events(t0):

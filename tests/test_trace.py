@@ -315,7 +315,7 @@ def test_kernel_module_names_match_the_benchmark():
         "scan": _module_name(jax_cost.scan_compile_job(ev, 20, 2, 8, 2,
                                                        2, 2)),
         "dscan": _module_name(jax_cost.direct_scan_compile_job(
-            ev, 20, 2, 8, 2, 2, 2, dspec.length, dspec.n_perm_codes)),
+            ev, 20, 2, 8, 2, 2, 2, dspec.length)),
     }
     assert got["stacked"] == f"jit_{jax_cost.EVAL_PROGRAM}"
     assert got["scan"] == got["dscan"] == f"jit_{jax_cost.SCAN_PROGRAM}"
